@@ -1,7 +1,7 @@
 """MFU diagnosis sweep for the BERT-base seq-512 train step (TPU).
 
 Isolates the suspected non-matmul costs one at a time and prints one JSON
-line per variant so the MFU gap (BENCH_r02 estimated ~24% on v5e) can be
+line per variant so the MFU gap (estimated ~24% on v5e in round 2) can be
 attributed instead of guessed at:
 
 - batch size (16 / 32 / 64 / 128): MXU utilization rises with larger
@@ -30,9 +30,6 @@ QUICK = "--quick" in sys.argv
 
 
 def main():
-    from sparkflow_tpu.utils.hw import ensure_live_backend
-    ensure_live_backend()
-
     import jax
     import jax.numpy as jnp
     import optax
@@ -43,7 +40,7 @@ def main():
                                            transformer_train_step_flops)
 
     on_tpu = jax.default_backend() == "tpu"
-    if QUICK or not on_tpu:
+    if QUICK:
         cfg = dict(vocab_size=1000, hidden=128, num_layers=2, num_heads=4,
                    mlp_dim=256, max_len=128)
     else:

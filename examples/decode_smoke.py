@@ -35,10 +35,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 import jax
 
 from sparkflow_tpu.models.registry import build_registry_spec, model_from_json
@@ -203,27 +199,6 @@ def main() -> None:
         assert kv["prefix_hits"] > 0, \
             f"shared-prefix burst produced no prefix hits: {kv}"
 
-        # greedy parity with sharing disabled: the same deterministic
-        # engine rebuilt locally with prefix_cache off and no chunking
-        # must emit identical tokens for every shared-prefix request
-        spec = build_registry_spec("transformer_lm", vocab_size=VOCAB,
-                                   hidden=32, num_layers=2, num_heads=4,
-                                   mlp_dim=64, max_len=64, dropout=0.0)
-        ref_model = model_from_json(spec)
-        ref_params = ref_model.init(jax.random.PRNGKey(0))
-        ref_cb = ContinuousBatcher(
-            DecodeEngine(ref_model, ref_params, num_slots=4, page_size=8,
-                         seed=0, prefix_cache=False), max_queue=64)
-        try:
-            for sp, want_toks in shared_results.items():
-                r = ref_cb.generate(list(sp), max_new_tokens=6, timeout=120)
-                assert r["tokens"] == want_toks, \
-                    (sp[-4:], r["tokens"], want_toks)
-        finally:
-            ref_cb.close()
-        toks = sum(3 + (5 * k + j) % 15 for k in range(WORKERS)
-                   for j in range(REQUESTS_PER_WORKER))
-
         # clean SIGTERM drain: start a slow request, signal mid-flight,
         # and require BOTH a completed in-flight generation and 503s for
         # latecomers, then exit code 0
@@ -267,6 +242,29 @@ def main() -> None:
         proc.wait(timeout=60)
         assert proc.returncode == 0, \
             f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend: a chip belongs to one process at a time.
+        # greedy parity with sharing disabled: the same deterministic
+        # engine rebuilt locally with prefix_cache off and no chunking
+        # must emit identical tokens for every shared-prefix request
+        spec = build_registry_spec("transformer_lm", vocab_size=VOCAB,
+                                   hidden=32, num_layers=2, num_heads=4,
+                                   mlp_dim=64, max_len=64, dropout=0.0)
+        ref_model = model_from_json(spec)
+        ref_params = ref_model.init(jax.random.PRNGKey(0))
+        ref_cb = ContinuousBatcher(
+            DecodeEngine(ref_model, ref_params, num_slots=4, page_size=8,
+                         seed=0, prefix_cache=False), max_queue=64)
+        try:
+            for sp, want_toks in shared_results.items():
+                r = ref_cb.generate(list(sp), max_new_tokens=6, timeout=120)
+                assert r["tokens"] == want_toks, \
+                    (sp[-4:], r["tokens"], want_toks)
+        finally:
+            ref_cb.close()
+        toks = sum(3 + (5 * k + j) % 15 for k in range(WORKERS)
+                   for j in range(REQUESTS_PER_WORKER))
         print(f"decode-smoke OK: {total} mixed-length generations "
               f"({toks} tokens in {elapsed:.1f}s), every X-Request-Id "
               f"echoed, {len(shared_results)} shared-prefix generations "

@@ -36,13 +36,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
 
 import jax
 
@@ -191,23 +185,6 @@ def main() -> None:
         assert hits > 0, f"replayed prompts produced no prefix hits: {eng_stats}"
         assert eng_stats["kv"]["kv_dtype"] == "int8"
 
-        # token-identical parity vs the plainest possible engine: no
-        # quantization, no spec, no sharing, no chunking — shrinking the
-        # pool bytes must not change the text
-        model, params = build_lm()
-        ref_cb = ContinuousBatcher(
-            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
-                         prefix_cache=False), max_queue=64)
-        try:
-            ref_bpp = ref_cb.engine.stats()["kv"]["kv_bytes_per_page"]
-            assert ref_bpp >= 1.9 * bpp, (ref_bpp, bpp)
-            for (prompt, budget), want in results.items():
-                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
-                                    timeout=120)
-                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
-        finally:
-            ref_cb.close()
-
         # clean SIGTERM drain: in-flight request survives, process exits 0
         late = {}
 
@@ -232,6 +209,25 @@ def main() -> None:
         proc.wait(timeout=60)
         assert proc.returncode == 0, \
             f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend: a chip belongs to one process at a time.
+        # token-identical parity vs the plainest possible engine: no
+        # quantization, no spec, no sharing, no chunking — shrinking the
+        # pool bytes must not change the text
+        model, params = build_lm()
+        ref_cb = ContinuousBatcher(
+            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
+                         prefix_cache=False), max_queue=64)
+        try:
+            ref_bpp = ref_cb.engine.stats()["kv"]["kv_bytes_per_page"]
+            assert ref_bpp >= 1.9 * bpp, (ref_bpp, bpp)
+            for (prompt, budget), want in results.items():
+                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
+                                    timeout=120)
+                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
+        finally:
+            ref_cb.close()
         total = WORKERS * REQUESTS_PER_WORKER
         ratio = ref_bpp / bpp
         print(f"kvquant-smoke OK: {total} mixed-length generations in "

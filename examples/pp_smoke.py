@@ -45,13 +45,8 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=2")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
 
 import jax
 
@@ -205,6 +200,33 @@ def main() -> None:
         assert par["pp"] == PP and par["stages"] == PP, par
         kvb = par["kv_bytes_per_device"]
 
+        # clean SIGTERM drain: in-flight request survives, process exits 0
+        late = {}
+
+        def slow_request() -> None:
+            c = ServingClient(url, timeout=120, retries=0)
+            try:
+                late["result"] = c.generate([1, 2, 3], max_new_tokens=30,
+                                            request_id="drain-rider")
+            except Exception as exc:  # noqa: BLE001
+                late["error"] = exc
+            c.close()
+
+        rider = threading.Thread(target=slow_request)
+        rider.start()
+        time.sleep(0.3)  # let it get admitted
+        proc.send_signal(signal.SIGTERM)
+        rider.join(timeout=120)
+        client.close()
+        assert "result" in late, f"in-flight generation died: {late}"
+        assert late["result"]["num_tokens"] == 30
+
+        proc.wait(timeout=60)
+        assert proc.returncode == 0, \
+            f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend: a chip belongs to one process at a time.
         # token-identical parity vs the plainest possible engine: no mesh,
         # spec off, sharing off, chunking off — staging the depth must not
         # change the text
@@ -242,31 +264,6 @@ def main() -> None:
             assert wave_ticks > 0, wave_ticks
         finally:
             wave_cb.close()
-
-        # clean SIGTERM drain: in-flight request survives, process exits 0
-        late = {}
-
-        def slow_request() -> None:
-            c = ServingClient(url, timeout=120, retries=0)
-            try:
-                late["result"] = c.generate([1, 2, 3], max_new_tokens=30,
-                                            request_id="drain-rider")
-            except Exception as exc:  # noqa: BLE001
-                late["error"] = exc
-            c.close()
-
-        rider = threading.Thread(target=slow_request)
-        rider.start()
-        time.sleep(0.3)  # let it get admitted
-        proc.send_signal(signal.SIGTERM)
-        rider.join(timeout=120)
-        client.close()
-        assert "result" in late, f"in-flight generation died: {late}"
-        assert late["result"]["num_tokens"] == 30
-
-        proc.wait(timeout=60)
-        assert proc.returncode == 0, \
-            f"server exited {proc.returncode} on SIGTERM drain"
         total = WORKERS * REQUESTS_PER_WORKER
         print(f"pp-smoke OK: {total} mixed-length generations in "
               f"{elapsed:.1f}s on a pp={PP} mesh (spec k={SPEC_K} over "

@@ -45,8 +45,6 @@ def model():
 
 
 def main():
-    from sparkflow_tpu.utils.hw import ensure_live_backend
-    ensure_live_backend()
     spark = SparkSession.builder.appName('quantized-serving').getOrCreate()
     rs = np.random.RandomState(0)
     rows = []

@@ -29,10 +29,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 import jax
 
 from sparkflow_tpu.analysis import racecheck, restrack

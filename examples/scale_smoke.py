@@ -41,10 +41,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 import sparkflow_tpu.nn as nn
 from sparkflow_tpu.graph_utils import build_graph
 from sparkflow_tpu.serving import (Autoscaler, InferenceEngine,

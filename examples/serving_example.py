@@ -43,8 +43,6 @@ def model():
 
 
 def main():
-    from sparkflow_tpu.utils.hw import ensure_live_backend
-    ensure_live_backend()
     smoke = bool(os.environ.get('SPARKFLOW_TPU_SMOKE'))
 
     spark = SparkSession.builder.appName('serving-example').getOrCreate()

@@ -34,10 +34,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 import jax
 
 from sparkflow_tpu.models.registry import build_registry_spec, model_from_json
@@ -174,20 +170,6 @@ def main() -> None:
         hits = eng_stats["kv"]["prefix_hits"]
         assert hits > 0, f"replayed prompts produced no prefix hits: {eng_stats}"
 
-        # token-identical parity vs the plainest possible engine: spec off,
-        # sharing off, chunking off — speculation must not change the text
-        model, params = build_lm()
-        ref_cb = ContinuousBatcher(
-            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
-                         prefix_cache=False), max_queue=64)
-        try:
-            for (prompt, budget), want in results.items():
-                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
-                                    timeout=120)
-                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
-        finally:
-            ref_cb.close()
-
         # clean SIGTERM drain: in-flight request survives, process exits 0
         late = {}
 
@@ -212,6 +194,22 @@ def main() -> None:
         proc.wait(timeout=60)
         assert proc.returncode == 0, \
             f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend: a chip belongs to one process at a time.
+        # token-identical parity vs the plainest possible engine: spec off,
+        # sharing off, chunking off — speculation must not change the text
+        model, params = build_lm()
+        ref_cb = ContinuousBatcher(
+            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
+                         prefix_cache=False), max_queue=64)
+        try:
+            for (prompt, budget), want in results.items():
+                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
+                                    timeout=120)
+                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
+        finally:
+            ref_cb.close()
         total = WORKERS * REQUESTS_PER_WORKER
         print(f"spec-smoke OK: {total} mixed-length speculative generations "
               f"in {elapsed:.1f}s (k={SPEC_K}, accept_rate="

@@ -35,11 +35,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 import jax
+import numpy as np
 
 from sparkflow_tpu.models.registry import build_registry_spec, model_from_json
 from sparkflow_tpu.resilience import faults
@@ -57,6 +54,17 @@ def make_model():
                                num_layers=2, num_heads=4, mlp_dim=64,
                                max_len=64, dropout=0.0)
     return model_from_json(spec)
+
+
+def host_params(model, seed: int):
+    """A weight tree made with numpy alone: the publishing side of this
+    smoke stands for a trainer on another machine and must not touch the
+    backend its server child is using (a chip belongs to one process)."""
+    rs = np.random.RandomState(seed)
+    return {layer: {name: (0.05 * rs.standard_normal(shape)
+                           ).astype(np.float32)
+                    for name, (shape, _init) in leaves.items()}
+            for layer, leaves in model.param_specs().items()}
 
 
 class _EchoEngine:
@@ -118,7 +126,7 @@ def main() -> None:
     store_dir = tempfile.mkdtemp(prefix="swap_smoke_store_")
     store = WeightStore(store_dir)
     model = make_model()
-    good_params = model.init(jax.random.PRNGKey(1))
+    good_params = host_params(model, 1)
     proc = subprocess.Popen([sys.executable, __file__, "--server",
                              str(port), "--store", store_dir])
     errors = []
@@ -190,7 +198,7 @@ def main() -> None:
         # publish a SECOND version, then corrupt it on disk the way a
         # crash or bit-rot would — the replica must reject it on checksum,
         # keep serving v1, and never surface an error to clients
-        v_bad = store.publish(model.init(jax.random.PRNGKey(2)))
+        v_bad = store.publish(host_params(model, 2))
         assert v_bad == 2, v_bad
         faults.corrupt_latest_weights(store_dir, mode="flip")
         deadline = time.time() + 60
@@ -224,19 +232,9 @@ def main() -> None:
             f"serving_version flipped {flips} times: {versions_seen}"
         assert set(versions_seen) == {0, v_good}, versions_seen
 
-        # post-swap greedy parity: the server must emit the same tokens as
-        # a local engine cold-started on the published good weights
-        ref = ContinuousBatcher(
-            DecodeEngine(model, good_params, num_slots=4, page_size=8,
-                         seed=0), max_queue=64)
-        try:
-            prompt = [3, 1, 4, 1, 5]
-            want = ref.generate(prompt, max_new_tokens=8, timeout=120)
-            got = client.generate(prompt, max_new_tokens=8, temperature=0.0)
-            assert got["tokens"] == want["tokens"], \
-                (got["tokens"], want["tokens"])
-        finally:
-            ref.close()
+        # post-swap greedy tokens, compared below with a cold engine
+        prompt = [3, 1, 4, 1, 5]
+        got = client.generate(prompt, max_new_tokens=8, temperature=0.0)
 
         # clean SIGTERM drain with a generation in flight
         late = {}
@@ -262,6 +260,20 @@ def main() -> None:
         proc.wait(timeout=60)
         assert proc.returncode == 0, \
             f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend. Post-swap greedy parity: the server must have emitted the
+        # same tokens as a local engine cold-started on the published good
+        # weights
+        ref = ContinuousBatcher(
+            DecodeEngine(model, good_params, num_slots=4, page_size=8,
+                         seed=0), max_queue=64)
+        try:
+            want = ref.generate(prompt, max_new_tokens=8, timeout=120)
+            assert got["tokens"] == want["tokens"], \
+                (got["tokens"], want["tokens"])
+        finally:
+            ref.close()
         print(f"swap-smoke OK: {total} generations with 0 failures across "
               f"a live publish (v0 -> v{v_good}, exactly 1 healthz flip), "
               f"corrupt v{v_bad} rejected on checksum with last-good kept "

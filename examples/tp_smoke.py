@@ -40,13 +40,8 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=2")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
 
 import jax
 
@@ -190,23 +185,6 @@ def main() -> None:
         assert hits > 0, f"replayed prompts produced no prefix hits: {eng_stats}"
         kvb = eng_stats["parallel"]["kv_bytes_per_device"]
 
-        # token-identical parity vs the plainest possible engine: no mesh,
-        # spec off, sharing off, chunking off — sharding must not change
-        # the text
-        model, params = build_lm()
-        ref_cb = ContinuousBatcher(
-            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
-                         prefix_cache=False), max_queue=64)
-        try:
-            ref_kvb = ref_cb.engine.stats()["parallel"]["kv_bytes_per_device"]
-            assert kvb * 2 <= ref_kvb * 1.1, (kvb, ref_kvb)
-            for (prompt, budget), want in results.items():
-                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
-                                    timeout=120)
-                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
-        finally:
-            ref_cb.close()
-
         # clean SIGTERM drain: in-flight request survives, process exits 0
         late = {}
 
@@ -231,6 +209,25 @@ def main() -> None:
         proc.wait(timeout=60)
         assert proc.returncode == 0, \
             f"server exited {proc.returncode} on SIGTERM drain"
+
+        # Only now, with the server child gone, does this process touch a
+        # backend: a chip belongs to one process at a time.
+        # token-identical parity vs the plainest possible engine: no mesh,
+        # spec off, sharing off, chunking off — sharding must not change
+        # the text
+        model, params = build_lm()
+        ref_cb = ContinuousBatcher(
+            DecodeEngine(model, params, num_slots=4, page_size=8, seed=0,
+                         prefix_cache=False), max_queue=64)
+        try:
+            ref_kvb = ref_cb.engine.stats()["parallel"]["kv_bytes_per_device"]
+            assert kvb * 2 <= ref_kvb * 1.1, (kvb, ref_kvb)
+            for (prompt, budget), want in results.items():
+                r = ref_cb.generate(list(prompt), max_new_tokens=budget,
+                                    timeout=120)
+                assert r["tokens"] == want, (prompt[:4], r["tokens"], want)
+        finally:
+            ref_cb.close()
         total = WORKERS * REQUESTS_PER_WORKER
         print(f"tp-smoke OK: {total} mixed-length generations in "
               f"{elapsed:.1f}s on a tp={TP} mesh (spec k={SPEC_K}, {hits} "

@@ -34,10 +34,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
-
 from sparkflow_tpu.obs import TraceCollector, harvest_flight
 from sparkflow_tpu.obs.spans import TraceContext
 from sparkflow_tpu.serving import RouterServer, ServingClient

@@ -23,16 +23,11 @@ import shutil
 import sys
 import tempfile
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from sparkflow_tpu.utils.hw import ensure_live_backend
-
-ensure_live_backend()
 
 import jax
 import jax.numpy as jnp

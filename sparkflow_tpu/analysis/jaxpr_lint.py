@@ -85,6 +85,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 
 import jax
 import numpy as np
+from jax.extend import core as jex_core
 from jax.sharding import PartitionSpec as P
 
 from .findings import Finding
@@ -127,9 +128,9 @@ def _norm_spec(spec) -> Tuple:
 
 
 def _sub_jaxprs(value) -> Iterable:
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -196,7 +197,7 @@ def lint_fn(fn: Callable, args: Sequence, *,
         a >1-device mesh.
     donate_argnums : argument indices the caller's jit donates — consumed
         by the GC-J105 check, exactly jit's convention.
-    check_x64 : re-trace under ``jax.experimental.enable_x64`` for the
+    check_x64 : re-trace under ``jax.enable_x64`` for the
         GC-J103 promotion check (skipped automatically if any input is
         already 64-bit).
     """
@@ -262,8 +263,7 @@ def lint_fn(fn: Callable, args: Sequence, *,
                     for _, _, leaf in flat_leaves)
     if "GC-J103" not in ignore and check_x64 and not input_f64:
         try:
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with jax.enable_x64(True):
                 closed64 = jax.make_jaxpr(fn)(*args)
         except Exception:
             closed64 = None  # fn untraceable under x64: nothing to report
@@ -386,8 +386,7 @@ def lint_collective_divergence(fn: Callable, args: Sequence, *,
     label = name or getattr(fn, "__name__", "fn")
     args = tuple(jax.tree.map(_struct_like, a) for a in args)
     if mesh is not None and in_specs is not None:
-        from ..jax_compat import shard_map
-        fn = shard_map(fn, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     closed = jax.make_jaxpr(fn)(*args)
     return _divergence_findings(closed.jaxpr, label)
@@ -633,8 +632,7 @@ def lint_decode_collectives(fn: Callable, args: Sequence, *,
     label = name or getattr(fn, "__name__", "decode_step")
     args = tuple(jax.tree.map(_struct_like, a) for a in args)
     if mesh is not None and in_specs is not None:
-        from ..jax_compat import shard_map
-        fn = shard_map(fn, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     closed = jax.make_jaxpr(fn)(*args)
     divergence: List[Finding] = []

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import math
 from contextvars import ContextVar
 from typing import Optional
@@ -33,13 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..jax_compat import axis_size
-
-try:  # pallas TPU backend (absent in some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 
@@ -116,7 +113,6 @@ def _try_shardmap_flash(q, k, v, kv_mask, causal, scale, interpret,
     b, h = q.shape[0], q.shape[1]
     if bsz * hsz <= 1 or b % bsz or h % hsz:
         return None
-    from ..jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     bspec = ba if bsz > 1 else None
@@ -142,8 +138,8 @@ def _try_shardmap_flash(q, k, v, kv_mask, causal, scale, interpret,
     if kv_mask is not None:
         in_specs += (P(bspec),)
         args += (kv_mask,)
-    return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                     out_specs=qkv_spec, check_vma=False)(*args)
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=qkv_spec, check_vma=False)(*args)
 
 
 # Which path the most recent flash_attention TRACE took ('pallas',
@@ -163,6 +159,49 @@ def last_attention_path():
     time for jitted callers) in this thread/context: 'pallas' | 'blockwise'
     | 'reference' | None."""
     return _LAST_PATH.get()
+
+
+# Every kernel entry point's decision inside the active
+# record_attention_paths() block, as "<entry point>:<path>" strings — one
+# program often traces several attention calls, and last_attention_path()
+# keeps only the final one.
+_PATH_LOG: ContextVar = ContextVar("sparkflow_attention_path_log",
+                                   default=None)
+
+
+@contextlib.contextmanager
+def record_attention_paths():
+    """Yields a list that collects ``"<entry point>:<path>"`` for every
+    attention entry point traced inside the block (e.g.
+    ``"paged_attention:pallas"``, ``"flash_attention:reference"``)."""
+    log: list = []
+    tok = _PATH_LOG.set(log)
+    try:
+        yield log
+    finally:
+        _PATH_LOG.reset(tok)
+
+
+def _note_path(kernel: str, path: str) -> None:
+    _LAST_PATH.set(path)
+    log = _PATH_LOG.get()
+    if log is not None:
+        log.append(f"{kernel}:{path}")
+
+
+_WARNED: set = set()
+
+
+def _warn_reference(kernel: str, shape, dtype, rule: str) -> None:
+    """On a TPU backend, a kernel entry point that leaves its pallas kernel
+    says so: once per (kernel, shape, rule), at warning level. Off the TPU
+    the jnp paths are the expected ones and stay quiet."""
+    key = (kernel, tuple(shape), str(dtype), rule)
+    if jax.default_backend() != "tpu" or key in _WARNED:
+        return
+    _WARNED.add(key)
+    logger.warning("%s: shape %s %s takes the XLA reference path, not the "
+                   "pallas kernel: %s", kernel, tuple(shape), dtype, rule)
 
 
 # ---------------------------------------------------------------------------
@@ -619,9 +658,8 @@ def flash_attention(q, k, v, causal: bool = False,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     _user_block_q, _user_block_k = block_q, block_k  # pre-auto-derivation
 
-    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = not on_tpu
+        interpret = jax.default_backend() != "tpu"
     # p-tile is block_q*block_k f32: cap the product at 2^20 (4 MB VMEM)
     cap = 1024 if d <= 128 else 512
     bwd_block_q = min(block_q, s) if block_q is not None else _auto_block(s, 512)
@@ -635,7 +673,7 @@ def flash_attention(q, k, v, causal: bool = False,
     if _FORCE_XLA.get():
         # explicit override (tests, callers that need the GSPMD-partitionable
         # form): blockwise unconditionally
-        _LAST_PATH.set("blockwise")
+        _note_path("flash_attention", "blockwise")
         return _blockwise_attention(q, k, v, kv_mask, causal, scale,
                                     block_k=xla_block_k)
     wrapped = _try_shardmap_flash(q, k, v, kv_mask, causal, scale, interpret,
@@ -647,27 +685,31 @@ def flash_attention(q, k, v, causal: bool = False,
         # batch/heads axes (or the mesh has neither): the plain pallas call
         # would hand GSPMD an unpartitionable custom call — blockwise is the
         # partitionable form
-        _LAST_PATH.set("blockwise")
+        _note_path("flash_attention", "blockwise")
         return _blockwise_attention(q, k, v, kv_mask, causal, scale,
                                     block_k=xla_block_k)
     # TPU tiling: q-rows multiple of 8 (sublanes), k-cols multiple of 128
     # (lanes); sequences must tile exactly (pad upstream otherwise)
-    tiles_ok = (pltpu is not None
-                and s % block_q == 0 and sk % block_k == 0
+    tiles_ok = (s % block_q == 0 and sk % block_k == 0
                 and s % bwd_block_q == 0 and sk % bwd_block_k == 0
                 and block_q % 8 == 0 and block_k % 128 == 0
                 and bwd_block_q % 8 == 0 and bwd_block_k % 128 == 0
                 and d % 8 == 0)
     if not tiles_ok:
+        _warn_reference(
+            "flash_attention", q.shape, q.dtype,
+            f"Sq={s} and Sk={sk} must tile into q-blocks {block_q}/"
+            f"{bwd_block_q} (multiples of 8) and k-blocks {block_k}/"
+            f"{bwd_block_k} (multiples of 128), and D={d} be a multiple of 8")
         if kv_mask is None:
-            _LAST_PATH.set("reference")
+            _note_path("flash_attention", "reference")
             return attention_reference(q, k, v, causal, scale)
         # blockwise keeps memory bounded when it tiles; its own fallback is
         # the dense reference path with the mask honored
-        _LAST_PATH.set("blockwise")
+        _note_path("flash_attention", "blockwise")
         return _blockwise_attention(q, k, v, kv_mask, causal, scale,
                                     block_k=xla_block_k)
-    _LAST_PATH.set("pallas")
+    _note_path("flash_attention", "pallas")
     return _flash(q, k, v, kv_mask, causal, scale, block_q, block_k,
                   bwd_block_q, bwd_block_k, interpret)
 
@@ -731,13 +773,65 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
     return out.astype(q.dtype)
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size: int, sm_scale: float):
+# One K or V page block, padded to f32 (8, 128) tiles, may hold this many
+# elements (1 MiB as f32). It is the only layout rule the v5e compiler has:
+# any H, D, page size, pool dtype and verify width S under the bound lowers.
+# Past it the kernels' f32 working set nears the 16 MiB scoped VMEM — the
+# verify kernel over an f32 pool is refused at 2x this bound, every variant
+# by 8x (RESOURCE_EXHAUSTED). tests/test_tpu_compile.py compiles both sides.
+PAGED_BLOCK_LIMIT = 1 << 18
+
+
+def _paged_block_rule(page: int, h: int, d: int) -> Optional[str]:
+    """The rule a compiled paged kernel's layout breaks, or None."""
+    padded = page * (-(-h // 8) * 8) * (-(-d // 128) * 128)
+    if padded > PAGED_BLOCK_LIMIT:
+        return (f"one [page={page}, H={h}, D={d}] block pads to {padded} "
+                f"elements > PAGED_BLOCK_LIMIT={PAGED_BLOCK_LIMIT}")
+    return None
+
+
+def _paged_takes_reference(kernel: str, q, k_pages, interpret: bool) -> bool:
+    """Decide and report one paged entry point's path: the reference under
+    ``force_xla_attention()`` or past the block limit (interpret mode has no
+    VMEM to overflow), the pallas kernel otherwise."""
+    page, h, d = k_pages.shape[1:]
+    rule = None if interpret else _paged_block_rule(page, h, d)
+    if rule:
+        _warn_reference(kernel, q.shape, k_pages.dtype, rule)
+    reference = bool(rule) or _FORCE_XLA.get()
+    _note_path(kernel, "reference" if reference else "pallas")
+    return reference
+
+
+def _scale_column(scales):
+    """``[num_pages, H]`` scales as ``[num_pages, H, 1]``: a ``(1, H, 1)``
+    block's last two dimensions equal the array's (any H is a legal block)
+    and arrive with H on sublanes, the layout of the K/V page they scale."""
+    return scales.astype(jnp.float32)[:, :, None]
+
+
+def _load_page(ref, scale_ref):
+    """One K or V page block as f32 ``[page, H, D]``. An int8/fp8 pool's
+    ``[H, 1]`` per-page-per-head scale dequantizes it right here in VMEM,
+    so no full-precision page exists beyond this one block."""
+    x = ref[0].astype(jnp.float32)
+    if scale_ref is not None:
+        x = x * scale_ref[0]
+    return x
+
+
+def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                  page_size: int, sm_scale: float):
     """Grid ``(B, max_pages)``; scalar-prefetched page table drives the
     K/V BlockSpec index maps, so program ``(b, p)`` sees slot b's p-th
     logical page already staged in VMEM. Online-softmax state (m, l, acc)
     folds across the slot's pages; pages at or past ``lengths[b]`` are
-    skipped outright (no flops, state untouched)."""
+    skipped outright (no flops, state untouched). Over an int8/fp8 pool
+    ``rest`` leads with the K and V scale refs: the page's ``[H, 1]`` scales
+    ride the same index map (:func:`_load_page`)."""
+    *scale_refs, o_ref, acc_ref, m_ref, l_ref = rest
+    ks_ref, vs_ref = scale_refs or (None, None)
     b = pl.program_id(0)
     p = pl.program_id(1)
     np_ = pl.num_programs(1)
@@ -753,78 +847,27 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page_size < length)
     def _compute():
         q = q_ref[0].astype(jnp.float32)                  # [H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [page, H, D]
-        v = v_ref[0].astype(jnp.float32)
-        # s[h, t] = q[h, :] . k[t, h, :]  (batch over H, contract D)
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                                preferred_element_type=jnp.float32) * sm_scale
-        tpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k = _load_page(k_ref, ks_ref)                     # [page, H, D]
+        v = _load_page(v_ref, vs_ref)
+        # s[t, h] = q[h, :] . k[t, h, :]. One query row per head leaves the
+        # left operand no free dimension, which Mosaic's matmul refuses —
+        # and an M=1 matmul would idle the MXU anyway — so multiply and
+        # reduce over the lanes; H stays on sublanes throughout.
+        s = jnp.sum(k * q[None], axis=2, keepdims=True) * sm_scale
+        tpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(tpos < length, s, NEG_INF)          # ragged last page
         m_prev = m_ref[:]                                 # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pexp = jnp.exp(s - m_new)                         # [H, page]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        pexp = jnp.exp(s - m_new[None])                   # [page, H, 1]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(pexp, axis=1, keepdims=True)
-        # acc[h, d] += sum_t pexp[h, t] * v[t, h, d]
-        acc_ref[:] = alpha * acc_ref[:] + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(pexp, axis=0)
+        # acc[h, d] += sum_t pexp[t, h] * v[t, h, d]
+        acc_ref[:] = alpha * acc_ref[:] + jnp.sum(pexp * v, axis=0)
         m_ref[:] = m_new
 
     @pl.when(p == np_ - 1)
     def _finalize():
         # empty slot: init state (acc 0, l 0) divides to exact zeros
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-                    ).astype(o_ref.dtype)
-
-
-def _paged_kernel_quant(table_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                        page_size: int, sm_scale: float):
-    """:func:`_paged_kernel` over an int8/fp8 pool: the page's K/V block
-    arrives quantized and its ``[H]`` per-page-per-head scales ride the
-    same scalar-prefetched index map. Dequantization happens INSIDE the
-    accumulations in f32 — the K scale folds into the QK^T scores and the
-    V scale into the PV update — so no full-precision page is ever
-    materialized beyond the one block in VMEM."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    np_ = pl.num_programs(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-
-    @pl.when(p * page_size < length)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # [H, D]
-        k = k_ref[0].astype(jnp.float32)                  # [page, H, D] quant
-        v = v_ref[0].astype(jnp.float32)
-        ks = ks_ref[0]                                    # [H] f32
-        vs = vs_ref[0]
-        # s[h, t] = (q[h, :] . k_q[t, h, :]) * k_scale[h] * sm_scale
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                                preferred_element_type=jnp.float32
-                                ) * (ks[:, None] * sm_scale)
-        tpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(tpos < length, s, NEG_INF)          # ragged last page
-        m_prev = m_ref[:]                                 # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pexp = jnp.exp(s - m_new)                         # [H, page]
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(pexp, axis=1, keepdims=True)
-        # acc[h, d] += (sum_t pexp[h, t] * v_q[t, h, d]) * v_scale[h]
-        acc_ref[:] = alpha * acc_ref[:] + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * vs[:, None]
-        m_ref[:] = m_new
-
-    @pl.when(p == np_ - 1)
-    def _finalize():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
                     ).astype(o_ref.dtype)
 
@@ -842,39 +885,28 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths,
     reads ``page_table[b, p]``, so the gather over scattered pages happens
     in the pipeline's DMA stage, not as a materialized ``[B, maxp*page]``
     cache copy the way the reference does it. Pages wholly past a slot's
-    length cost no flops. Falls back to the reference (with the same
-    ``last_attention_path`` reporting) when the head layout violates the
-    TPU tile rules.
+    length cost no flops. A compiled kernel takes any head layout whose
+    page block fits ``PAGED_BLOCK_LIMIT`` (GPT-2's 12 heads of 64 included);
+    past it the reference runs instead, reported through
+    ``last_attention_path`` and, on a TPU, a warning.
 
     With ``k_scales``/``v_scales`` (``[num_pages, H]`` f32) the pool is
     int8/fp8 and the kernel dequantizes inside the gather: the scale
-    blocks ride the same scalar-prefetched page-table index map and fold
-    into the QK^T / PV accumulations in f32 — the full-precision pool is
-    never materialized.
+    blocks ride the same scalar-prefetched page-table index map and scale
+    the page block in VMEM — the full-precision pool is never materialized.
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales or neither")
-    quantized = k_scales is not None
     b, h, d = q.shape
     page = k_pages.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = not on_tpu
-    # compiled blocks are [page, H, D]: sublane dim H, lane dim D % 128.
-    # The sublane tile depends on the pool dtype — 8 for f32/bf16, 32 for
-    # int8/fp8. (interpret mode has no tile constraint — CPU parity tests
-    # run any shape)
-    sub = 32 if quantized else 8
-    tiles_ok = (pltpu is not None
-                and (interpret or (h % sub == 0 and d % 128 == 0)))
-    if not tiles_ok or _FORCE_XLA.get():
-        _LAST_PATH.set("reference")
+        interpret = jax.default_backend() != "tpu"
+    if _paged_takes_reference("paged_attention", q, k_pages, interpret):
         return paged_attention_reference(q, k_pages, v_pages, page_table,
                                          lengths, sm_scale=scale,
                                          k_scales=k_scales,
                                          v_scales=v_scales)
-    _LAST_PATH.set("pallas")
     maxp = page_table.shape[1]
     page_spec = pl.BlockSpec((1, page, h, d),
                              lambda bb, p, t, l: (t[bb, p], 0, 0, 0))
@@ -884,16 +916,12 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths,
         page_spec,
     ]
     operands = [q, k_pages, v_pages]
-    if quantized:
-        kernel = functools.partial(_paged_kernel_quant, page_size=page,
-                                   sm_scale=scale)
-        scale_spec = pl.BlockSpec((1, h), lambda bb, p, t, l: (t[bb, p], 0))
+    if k_scales is not None:
+        scale_spec = pl.BlockSpec((1, h, 1),
+                                  lambda bb, p, t, l: (t[bb, p], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-    else:
-        kernel = functools.partial(_paged_kernel, page_size=page,
-                                   sm_scale=scale)
+        operands += [_scale_column(k_scales), _scale_column(v_scales)]
+    kernel = functools.partial(_paged_kernel, page_size=page, sm_scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, maxp),
@@ -964,12 +992,13 @@ def paged_attention_verify_reference(q, k_pages, v_pages, page_table, start,
     return out.astype(q.dtype)
 
 
-def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_size: int,
-                         num_q: int, sm_scale: float):
+def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
+                         page_size: int, num_q: int, sm_scale: float):
     """Grid ``(B, max_pages)`` exactly like :func:`_paged_kernel`, but the
     online-softmax state carries ``num_q`` query rows per head and the
     validity mask is per-query causal (``tpos <= start[b] + s``)."""
+    *scale_refs, o_ref, acc_ref, m_ref, l_ref = rest
+    ks_ref, vs_ref = scale_refs or (None, None)
     b = pl.program_id(0)
     p = pl.program_id(1)
     np_ = pl.num_programs(1)
@@ -985,8 +1014,8 @@ def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page_size < start + num_q)
     def _compute():
         q = q_ref[0].astype(jnp.float32)                  # [H, S, D]
-        k = k_ref[0].astype(jnp.float32)                  # [page, H, D]
-        v = v_ref[0].astype(jnp.float32)
+        k = _load_page(k_ref, ks_ref)                     # [page, H, D]
+        v = _load_page(v_ref, vs_ref)
         # att[h, s, t] = q[h, s, :] . k[t, h, :] (batch H, contract D)
         att = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
                                   preferred_element_type=jnp.float32
@@ -1012,57 +1041,6 @@ def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
-def _paged_verify_kernel_quant(table_ref, start_ref, q_ref, k_ref, v_ref,
-                               ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
-                               *, page_size: int, num_q: int, sm_scale: float):
-    """:func:`_paged_verify_kernel` over an int8/fp8 pool: like
-    :func:`_paged_kernel_quant`, the page's ``[H]`` scales ride the
-    scalar-prefetched index map and fold into the QK^T / PV accumulations
-    in f32 (broadcast over the S query rows)."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    np_ = pl.num_programs(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    start = start_ref[b]
-
-    @pl.when(p * page_size < start + num_q)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # [H, S, D]
-        k = k_ref[0].astype(jnp.float32)                  # [page, H, D] quant
-        v = v_ref[0].astype(jnp.float32)
-        ks = ks_ref[0]                                    # [H] f32
-        vs = vs_ref[0]
-        # att[h, s, t] = (q[h, s, :] . k_q[t, h, :]) * k_scale[h] * sm_scale
-        att = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
-                                  preferred_element_type=jnp.float32
-                                  ) * (ks[:, None, None] * sm_scale)
-        tpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32,
-                                                        att.shape, 2)
-        qpos = start + jax.lax.broadcasted_iota(jnp.int32, att.shape, 1)
-        att = jnp.where(tpos <= qpos, att, NEG_INF)
-        m_prev = m_ref[:]                                 # [H, S, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(att, axis=2, keepdims=True))
-        pexp = jnp.exp(att - m_new)                       # [H, S, page]
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(pexp, axis=2, keepdims=True)
-        # acc[h, s, d] += (sum_t pexp[h, s, t] * v_q[t, h, d]) * v_scale[h]
-        acc_ref[:] = alpha * acc_ref[:] + jax.lax.dot_general(
-            pexp, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * vs[:, None, None]
-        m_ref[:] = m_new
-
-    @pl.when(p == np_ - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-                    ).astype(o_ref.dtype)
-
-
 def paged_attention_verify(q, k_pages, v_pages, page_table, start,
                            sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
@@ -1073,8 +1051,9 @@ def paged_attention_verify(q, k_pages, v_pages, page_table, start,
     (its parity ground truth); same scalar-prefetch page-gather structure as
     :func:`paged_attention` — the grid just carries S query rows of
     online-softmax state instead of one. Pages wholly past ``start[b] + S``
-    cost no flops. Falls back to the reference (reported via
-    ``last_attention_path``) when the tile rules are violated.
+    cost no flops. Any ``S`` compiles (``spec_k + 1`` as it comes); the
+    reference runs only past ``PAGED_BLOCK_LIMIT``, reported via
+    ``last_attention_path`` and, on a TPU, a warning.
 
     ``k_scales``/``v_scales`` (``[num_pages, H]`` f32) select the
     dequant-on-read kernel for an int8/fp8 pool, exactly like
@@ -1082,28 +1061,18 @@ def paged_attention_verify(q, k_pages, v_pages, page_table, start,
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales or neither")
-    quantized = k_scales is not None
     b, h, s, d = q.shape
     page = k_pages.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = not on_tpu
-    # compiled q/acc blocks are [H, S, D]: sublane dim S % 8, lane D % 128;
-    # k/v blocks [page, H, D] need H % 8 like the single-query kernel —
-    # % 32 when the pool is int8/fp8 (dtype-dependent sublane tile)
-    sub = 32 if quantized else 8
-    tiles_ok = (pltpu is not None
-                and (interpret or (h % sub == 0 and d % 128 == 0
-                                   and s % 8 == 0)))
-    if not tiles_ok or _FORCE_XLA.get():
-        _LAST_PATH.set("reference")
+        interpret = jax.default_backend() != "tpu"
+    if _paged_takes_reference("paged_attention_verify", q, k_pages,
+                              interpret):
         return paged_attention_verify_reference(q, k_pages, v_pages,
                                                 page_table, start,
                                                 sm_scale=scale,
                                                 k_scales=k_scales,
                                                 v_scales=v_scales)
-    _LAST_PATH.set("pallas")
     maxp = page_table.shape[1]
     page_spec = pl.BlockSpec((1, page, h, d),
                              lambda bb, p, t, st: (t[bb, p], 0, 0, 0))
@@ -1113,16 +1082,13 @@ def paged_attention_verify(q, k_pages, v_pages, page_table, start,
         page_spec,
     ]
     operands = [q, k_pages, v_pages]
-    if quantized:
-        kernel = functools.partial(_paged_verify_kernel_quant,
-                                   page_size=page, num_q=s, sm_scale=scale)
-        scale_spec = pl.BlockSpec((1, h), lambda bb, p, t, st: (t[bb, p], 0))
+    if k_scales is not None:
+        scale_spec = pl.BlockSpec((1, h, 1),
+                                  lambda bb, p, t, st: (t[bb, p], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-    else:
-        kernel = functools.partial(_paged_verify_kernel, page_size=page,
-                                   num_q=s, sm_scale=scale)
+        operands += [_scale_column(k_scales), _scale_column(v_scales)]
+    kernel = functools.partial(_paged_verify_kernel, page_size=page, num_q=s,
+                               sm_scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, maxp),
@@ -1189,7 +1155,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[2]
     q_offset = idx * s_local
@@ -1253,14 +1219,18 @@ def ring_flash_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     b, h, sl, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = not on_tpu
+        interpret = jax.default_backend() != "tpu"
     bq = min(block_q, sl)
     bk = min(block_k, sl)
-    tiles_ok = (pltpu is not None and sl % bq == 0 and sl % bk == 0
+    tiles_ok = (sl % bq == 0 and sl % bk == 0
                 and bq % 8 == 0 and bk % 128 == 0 and d % 8 == 0)
     if not tiles_ok:
+        _warn_reference(
+            "ring_flash_attention", q.shape, q.dtype,
+            f"the local sequence {sl} must tile into q-blocks {bq} "
+            f"(multiple of 8) and k-blocks {bk} (multiple of 128), and "
+            f"D={d} be a multiple of 8")
         return ring_attention(q, k, v, axis_name, causal=causal,
                               sm_scale=sm_scale, kv_mask=kv_mask)
 
@@ -1271,7 +1241,7 @@ def ring_flash_attention(q, k, v, axis_name: str, causal: bool = False,
 def _ring_flash_forward(q, k, v, kv_mask, axis_name, causal, scale, bq, bk,
                         interpret, with_lse=False):
     b, h, sl, d = q.shape
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     have_mask = kv_mask is not None
@@ -1331,7 +1301,7 @@ def _ring_flash_backward(q, k, v, kv_mask, out, lse, g, axis_name, causal,
     Same three-case causal structure as the forward (strictly-ahead sources
     contribute zero and skip the kernels entirely)."""
     b, h, sl, d = q.shape
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     have_mask = kv_mask is not None

@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import axis_size
 
 
 def all_to_all_moe_ffn(x, router_w, experts_fc1, experts_b1, experts_fc2,
@@ -56,7 +55,7 @@ def all_to_all_moe_ffn(x, router_w, experts_fc1, experts_b1, experts_fc2,
     normalizes the gradient.
     """
     try:
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
     except NameError as e:
         raise NameError(
             f"mesh axis {axis_name!r} is not bound: an ep_axis MoE model "

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import axis_size
 
 
 def psum_mean(tree, axis_name: str):
@@ -40,7 +39,7 @@ def reduce_scatter(x, axis_name: str, axis: int = 0):
 def ppermute_ring(x, axis_name: str, shift: int = 1):
     """Rotate shards around the mesh-axis ring (building block of ring
     attention and pipeline schedules)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -65,8 +64,8 @@ def hierarchical_psum_mean(tree, ici_axis: str, dcn_axis: str):
     does not divide ``n_ici`` are flat-padded for the scatter and unpadded
     after the gather (exactness unaffected: padding reduces to zeros).
     """
-    n_ici = axis_size(ici_axis)
-    total = n_ici * axis_size(dcn_axis)
+    n_ici = jax.lax.axis_size(ici_axis)
+    total = n_ici * jax.lax.axis_size(dcn_axis)
 
     def leaf(x):
         flat = jnp.ravel(x)

@@ -40,7 +40,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import optax
-from ..jax_compat import shard_map
 from ..sharding import ShardingConfig, as_sharding_config
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -219,7 +218,7 @@ def make_dp_train_step(model, optimizer, mesh: Mesh,
         o_spec = opt_spec_of(opt_state)
         p_spec = (zero3_param_specs(params, n_shards, dp_axis)
                   if stage >= 3 else param_spec)
-        sm = shard_map(
+        sm = jax.shard_map(
             step, mesh=mesh,
             in_specs=(p_spec, o_spec, data_spec, data_spec, data_spec, P()),
             out_specs=(p_spec, o_spec, P()),

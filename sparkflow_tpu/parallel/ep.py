@@ -23,7 +23,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import optax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .tp import filter_pspec, shard_params
@@ -54,7 +53,7 @@ def make_moe_shardmap_train_step(model, optimizer, mesh: Mesh,
                           is_leaf=lambda x: isinstance(x, P))
     data_spec = P(ep_axis)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(pspecs, data_spec, data_spec, P()),
              out_specs=(pspecs, P()),
              check_vma=False)

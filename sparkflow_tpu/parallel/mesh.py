@@ -6,7 +6,7 @@ from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
 def make_mesh(axes: Dict[str, int], devices: Optional[Sequence] = None) -> Mesh:
@@ -29,6 +29,14 @@ def make_mesh(axes: Dict[str, int], devices: Optional[Sequence] = None) -> Mesh:
         raise ValueError(f"mesh {dict(zip(axes, sizes))} needs "
                          f"{int(np.prod(sizes))} devices, have {devs.size}")
     return Mesh(devs.reshape(sizes), tuple(axes.keys()))
+
+
+def replicate_on_mesh(tree, mesh: Mesh):
+    """Commit every leaf to ``mesh``, replicated — the placement a dp step's
+    outputs have, so its first call sees the same argument types as every
+    later one (an argument's type carries its mesh; a fresh, unplaced init
+    would make the second call trace again)."""
+    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
 
 
 def default_mesh(axis: str = "dp") -> Optional[Mesh]:

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -456,7 +455,7 @@ def make_pp_train_step(model, optimizer, mesh: Mesh, n_microbatches: int = 1,
     param_specs = {"stages": P(pp_axis), "shared": P()}  # pytree prefixes
     data_spec = P(dp_axis) if has_dp else P()
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, data_spec, data_spec, P()),
              out_specs=(param_specs, P()),
              check_vma=False)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..jax_compat import shard_map
 
 
 def make_sp_train_step(model, optimizer, mesh: Mesh, dp_axis: Optional[str] = "dp",
@@ -56,7 +55,7 @@ def make_sp_train_step(model, optimizer, mesh: Mesh, dp_axis: Optional[str] = "d
             jnp.full((ids.shape[0],), ids.shape[1] - 1, jnp.float32))
         return jnp.sum(lv * w), jnp.sum(w)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(), P(dp_axis, sp_axis), P(dp_axis, sp_axis), P()),
              out_specs=(P(), P(), P()),
              check_vma=False)

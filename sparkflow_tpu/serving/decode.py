@@ -107,6 +107,7 @@ from ..analysis.runtime_guards import RecompileGuard
 from ..obs.spans import span as obs_span
 from ..resilience import faults
 from ..ops import paged_attention, paged_attention_verify
+from ..ops.attention import record_attention_paths
 from ..utils import metrics as metrics_mod
 from ..utils import quant
 from ..utils.tracing import annotate
@@ -561,6 +562,7 @@ class DecodeEngine:
                  for s in jax.tree.leaves(self._weights_template)]))
             sig = hashlib.sha256(desc.encode()).hexdigest()[:12]
             self._exec_prefix = f"decode/{sig}"
+        self._attention_paths: Dict[str, List[str]] = {}
         self._prefill_exes: Dict[int, Any] = {}
         self._decode_exe: Any = None
         self._sample_exe: Any = None
@@ -1336,16 +1338,21 @@ class DecodeEngine:
                 return exe
         guard = self.recompile_guard
         if not (self._sharded and specs is not None):
-            exe = jax.jit(guard.wrap(fn), donate_argnums=donate).lower(
-                *arg_structs).compile()
+            jitted = jax.jit(guard.wrap(fn), donate_argnums=donate)
         else:
-            from ..jax_compat import shard_map
-            body = shard_map(fn, mesh=self.mesh, in_specs=specs,
-                             out_specs=out_specs, check_vma=False)
+            body = jax.shard_map(fn, mesh=self.mesh, in_specs=specs,
+                                 out_specs=out_specs, check_vma=False)
             in_sh = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
                                  specs, is_leaf=lambda x: isinstance(x, P))
-            exe = jax.jit(guard.wrap(body), in_shardings=in_sh,
-                          donate_argnums=donate).lower(*arg_structs).compile()
+            jitted = jax.jit(guard.wrap(body), in_shardings=in_sh,
+                             donate_argnums=donate)
+        with record_attention_paths() as paths:
+            exe = jitted.lower(*arg_structs).compile()
+        if key is not None:
+            # which kernel (or reference) each attention call of this
+            # executable traced — a deserialized executable traces nothing
+            # and so reports nothing
+            self._attention_paths[key.rsplit("/", 1)[-1]] = sorted(set(paths))
         if key is not None and self.exec_store is not None:
             self._pending_exec_saves.append((key, exe))
         return exe
@@ -2211,6 +2218,7 @@ class DecodeEngine:
                      "serialized_saves": self.serialized_saves}),
                 "traces": self.recompile_guard.traces,
                 "steady_traces": self.recompile_guard.steady_traces,
+                "attention_paths": dict(self._attention_paths),
                 "steps": self._steps,
                 "tokens_out": self._tokens_out,
                 "prefills": self._prefills,

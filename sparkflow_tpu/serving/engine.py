@@ -212,6 +212,8 @@ class InferenceEngine:
         # bucket compiles hit cached executables from earlier processes
         # instead of re-running XLA — the restart-latency knob. hits/misses
         # are estimated from cache-entry deltas around our own compiles.
+        # The one helper decides the directory: JAX_COMPILATION_CACHE_DIR,
+        # where set, wins over the argument.
         self.compile_cache_dir: Optional[str] = None
         self.compile_cache_hits = 0
         self.compile_cache_misses = 0

@@ -49,6 +49,7 @@ from .core import (make_epoch_fn, make_loss_fn, make_multi_epoch_fn,
                    make_predict_fn, pad_to_batches)
 from .graphdef import GraphDef, GraphModel, params_to_list
 from .optimizers import build_optimizer
+from .parallel.mesh import replicate_on_mesh
 from .sharding import ShardingConfig, as_sharding_config
 
 logger = logging.getLogger("sparkflow_tpu")
@@ -989,6 +990,13 @@ class Trainer:
             # tp/fsdp: place params per their PartitionSpecs BEFORE the
             # optimizer init so mu/nu/etc inherit the same placement
             params = self._place_params(params, pspecs)
+        elif self.mesh is not None:
+            # pure dp/sp: replicated on the mesh from the start. A fresh
+            # init lives uncommitted on one device while every step's output
+            # is mesh-placed, and an argument's type carries its mesh — left
+            # as it was, the second call of the same program traced (and on
+            # a chip compiled) again with identical shapes
+            params = replicate_on_mesh(params, self.mesh)
         self._zero_stage = self._resolve_zero_stage(strategy, pspecs, params)
         self._zero1_active = self._zero_stage >= 1
         self._zero3_template = None
@@ -1026,6 +1034,9 @@ class Trainer:
                 params = jax.tree.map(jax.device_put, params, param_shardings)
         else:
             opt_state = self.optimizer.init(params)
+            if pspecs is None and self.mesh is not None:
+                # optax builds its step count fresh and unplaced
+                opt_state = replicate_on_mesh(opt_state, self.mesh)
 
         ckpt_mgr = None
         start_epoch = 0

@@ -526,7 +526,6 @@ def test_j107_scan_is_static_and_clean(one_mesh):
 
 
 def test_j107_ignore_and_lint_fn_integration(one_mesh):
-    from sparkflow_tpu.jax_compat import shard_map
 
     def bad(v):
         return lax.cond(v.sum() > 0,
@@ -538,7 +537,7 @@ def test_j107_ignore_and_lint_fn_integration(one_mesh):
         out_specs=P("dp"), ignore=("GC-J107",))
     assert fs == []
     # the generic lint_fn entry point sees it too (shard_map'd by hand)
-    wrapped = shard_map(bad, mesh=one_mesh, in_specs=(P("dp"),),
+    wrapped = jax.shard_map(bad, mesh=one_mesh, in_specs=(P("dp"),),
                         out_specs=P("dp"), check_vma=False)
     fs2 = jaxpr_lint.lint_fn(wrapped, (jnp.ones((4, 2)),),
                              ignore=("GC-J103", "GC-J104"))

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from sparkflow_tpu.analysis import jaxpr_lint, locks
-from sparkflow_tpu.jax_compat import shard_map
 from sparkflow_tpu.models import presets
 from sparkflow_tpu.models.registry import build_registry_spec, model_from_json
 from sparkflow_tpu.parallel.mesh import make_mesh
@@ -1082,7 +1081,7 @@ def test_tp_kernel_heads_sharded_parity(tp_mesh):
     q, k, v, table, lens = _rand_paged(rs, b, h, d, page_size, max_pages,
                                        [5, 11])
     full = np.asarray(paged_attention(q, k, v, table, lens, interpret=True))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, t, l: paged_attention(q, k, v, t, l, interpret=True),
         mesh=tp_mesh,
         in_specs=(P(None, "tp", None), P(None, None, "tp", None),
@@ -1097,7 +1096,7 @@ def test_tp_kernel_heads_sharded_parity(tp_mesh):
         rs, b, h, s, d, page_size, 4, [0, 5])
     fullv = np.asarray(paged_attention_verify(qv, kv_, vv, tablev, starts,
                                               interpret=True))
-    fnv = shard_map(
+    fnv = jax.shard_map(
         lambda q, k, v, t, st: paged_attention_verify(q, k, v, t, st,
                                                       interpret=True),
         mesh=tp_mesh,

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sparkflow_tpu.utils.flops import (attention_flops, device_peak_flops,
                                        jit_flops, mfu,
@@ -71,3 +72,19 @@ def test_train_step_flops_on_graph_model():
     # so the floor is fwd + (2x fwd - dx1) ~ 2.1x forward matmul flops
     fwd_mm = 2 * 128 * (32 * 64 + 64 * 4)
     assert fl >= 2.0 * fwd_mm
+
+
+def test_unknown_tpu_kind_is_an_error(monkeypatch):
+    """A TPU missing from the peak table raises; it is never handed the
+    v5e's peak (a guessed peak reports a wrong MFU with no indication)."""
+    class _Dev:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v5 lite")])
+    assert device_peak_flops() == 197e12
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v9 mystery")])
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        device_peak_flops()

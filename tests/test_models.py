@@ -110,7 +110,6 @@ def test_sp_forward_matches_single_device_logits():
     """Regression: under sp, shard i must use GLOBAL positions i*S_local..;
     amplified pos table + trained-scale comparison catches local-offset bugs."""
     import copy
-    from sparkflow_tpu.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh({"sp": 8})
@@ -123,7 +122,7 @@ def test_sp_forward_matches_single_device_logits():
 
     lm_sp = copy.copy(lm)
     lm_sp.sp_axis = "sp"
-    fwd = shard_map(
+    fwd = jax.shard_map(
         lambda p, ids: lm_sp.apply(p, {"input_ids": ids}, ["logits"])["logits"],
         mesh=mesh, in_specs=(P(), P(None, "sp")),
         out_specs=P(None, "sp", None), check_vma=False)
@@ -135,7 +134,6 @@ def test_sp_forward_matches_single_device_logits():
 
 
 def test_ring_attention_respects_kv_mask():
-    from sparkflow_tpu.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
     from sparkflow_tpu.ops import attention_reference, ring_attention
     from jax.sharding import Mesh
@@ -146,7 +144,7 @@ def test_ring_attention_respects_kv_mask():
     q = jnp.asarray(rs.randn(B, H, S, D), jnp.float32)
     mask = jnp.asarray((rs.rand(B, S) > 0.3).astype(np.float32))
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v, m: ring_attention(q, k, v, "sp", kv_mask=m),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),

@@ -419,7 +419,6 @@ def test_moe_a2a_overflow_fraction_metric():
     a starved capacity_factor must drop a nonzero fraction of choices."""
     from functools import partial
 
-    from sparkflow_tpu.jax_compat import shard_map
     from sparkflow_tpu.ops.moe_dispatch import all_to_all_moe_ffn
 
     mesh = make_mesh({"ep": 4}, devices=jax.devices()[:4])
@@ -433,7 +432,7 @@ def test_moe_a2a_overflow_fraction_metric():
     b2 = jnp.zeros((e, h), jnp.float32)
 
     def run(cf):
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep"), P("ep")),
                  out_specs=(P("ep"), P("ep"), P("ep")),
                  check_vma=False)
@@ -532,7 +531,6 @@ def test_hierarchical_psum_mean_matches_flat():
     the 1/n_ici shard over DCN -> all_gather) equals a flat psum-mean over
     both axes exactly — incl. leaves whose size does not divide the ICI
     axis (flat-pad path)."""
-    from sparkflow_tpu.jax_compat import shard_map
 
     from sparkflow_tpu.parallel.collectives import hierarchical_psum_mean
 
@@ -552,7 +550,7 @@ def test_hierarchical_psum_mean_matches_flat():
             lambda x: jax.lax.psum(x, ("dcn", "dp")) / 8.0, contrib)
         return hier, flat
 
-    hier, flat = jax.jit(shard_map(
+    hier, flat = jax.jit(jax.shard_map(
         per_device, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
         check_vma=False))(tree)
     for k in tree:

@@ -1,0 +1,159 @@
+"""The main path's kernels, compiled for a TPU v5e that is described, not
+attached (the TPU compiler is installed; nothing runs).
+
+Interpret mode accepts kernels the chip's compiler refuses — a batched dot
+with no free left dimension, a block that breaks the (8, 128) tiling, a
+working set past VMEM — so each kernel entry point is lowered here at real
+widths with ``interpret=False`` and must hold a ``tpu_custom_call``. The
+paged kernels' gate (``PAGED_BLOCK_LIMIT``) is held from both sides: every
+layout family below it compiles, and the compiler refuses what lies past it.
+
+One file, and the topology is described inside a fixture: only one process
+may load the TPU library, and pytest-xdist workers each import every file.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sparkflow_tpu.ops import attention as A
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep it out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+def _flash_structs(sh, b, h, s, d, dtype, mask):
+    q = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=sh)
+    m = jax.ShapeDtypeStruct((b, s), jnp.float32, sharding=sh)
+    return (q, q, q) + ((m,) if mask else ())
+
+
+# GPT-2 small's training shape (B8 H12 S1024 D64, bf16) and the BERT-ish f32
+# shape that once failed the (8, 128) tile check on the row statistics
+FLASH_SHAPES = [(8, 12, 1024, 64, jnp.bfloat16), (4, 12, 512, 64, jnp.float32)]
+
+
+@pytest.mark.parametrize("mask", [False, True], ids=["nomask", "kv_mask"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("b,h,s,d,dtype", FLASH_SHAPES,
+                         ids=["gpt2-small", "bert-512-f32"])
+def test_flash_lowers_on_tpu(one_chip, b, h, s, d, dtype, causal, mask):
+    """Forward, and forward+backward, through the pallas kernels."""
+    structs = _flash_structs(one_chip, b, h, s, d, dtype, mask)
+
+    def fwd(q, k, v, *m):
+        return A.flash_attention(q, k, v, causal=causal, interpret=False,
+                                 kv_mask=m[0] if m else None)
+
+    def fwd_bwd(q, k, v, *m):
+        return jax.grad(lambda q_, k_, v_: fwd(q_, k_, v_, *m)
+                        .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    assert "tpu_custom_call" in _compile(fwd, *structs)
+    assert A.last_attention_path() == "pallas"
+    # dq and dk/dv kernels, plus the recomputed forward
+    assert _compile(fwd_bwd, *structs).count("tpu_custom_call") >= 3
+
+
+def _paged_structs(sh, h, d, page, pool_dtype, num_q, q_dtype=jnp.bfloat16,
+                   slots=4, max_pages=8, num_pages=16):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    q = s((slots, h, d) if num_q is None else (slots, h, num_q, d), q_dtype)
+    pool = s((num_pages, page, h, d), pool_dtype)
+    out = [q, pool, pool, s((slots, max_pages), jnp.int32),
+           s((slots,), jnp.int32)]
+    if jnp.dtype(pool_dtype).itemsize == 1:      # int8 / fp8: scaled pool
+        scales = s((num_pages, h), jnp.float32)
+        out += [scales, scales]
+    return out
+
+
+def _paged_fn(num_q):
+    kernel = A.paged_attention if num_q is None else A.paged_attention_verify
+
+    def fn(q, k, v, table, n, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+        return kernel(q, k, v, table, n, interpret=False, **kw)
+
+    return fn
+
+
+# (heads, head_dim, page): every GPT-2 size (12/16/20/25 heads of 64),
+# Cerebras-GPT 1.3B (16 x 128), the layout the old gate admitted (8k x 128),
+# a tp-split remainder (3 heads), the toy layouts of the smokes, and the
+# largest blocks the gate admits (both pad to exactly PAGED_BLOCK_LIMIT)
+PAGED_LAYOUTS = [(12, 64, 16), (16, 64, 16), (20, 64, 16), (25, 64, 16),
+                 (16, 128, 16), (32, 128, 16), (3, 64, 16), (4, 8, 8),
+                 (12, 64, 128), (32, 128, 64)]
+POOL_DTYPES = [jnp.bfloat16, jnp.float32, jnp.int8, jnp.float8_e4m3fn]
+# None = the single-token decode kernel; 5 = spec_k 4 as it comes (no S % 8
+# rule), 8 = the old gate's width, 1 = the degenerate verify
+NUM_Q = [None, 1, 5, 8]
+
+
+@pytest.mark.parametrize("num_q", NUM_Q,
+                         ids=lambda n: "decode" if n is None else f"verify{n}")
+@pytest.mark.parametrize("pool_dtype", POOL_DTYPES,
+                         ids=lambda d: jnp.dtype(d).name)
+def test_paged_kernels_lower_on_tpu(one_chip, pool_dtype, num_q):
+    """Every layout family the gate admits compiles to the Mosaic kernel."""
+    for h, d, page in PAGED_LAYOUTS:
+        assert A._paged_block_rule(page, h, d) is None, (h, d, page)
+        text = _compile(_paged_fn(num_q),
+                        *_paged_structs(one_chip, h, d, page, pool_dtype,
+                                        num_q))
+        assert "tpu_custom_call" in text, (h, d, page)
+        assert A.last_attention_path() == "pallas", (h, d, page)
+
+
+@pytest.mark.parametrize("num_q", [None, 5],
+                         ids=["decode", "verify5"])
+def test_paged_gate_is_the_compilers(one_chip, num_q):
+    """Past ``PAGED_BLOCK_LIMIT`` the gate sends the layout to the reference
+    (no custom call in the program), and it is not being timid: handed the
+    same layout with the gate lifted, the compiler runs out of VMEM."""
+    h, d, page = 64, 128, 256            # 8x the limit
+    assert A._paged_block_rule(page, h, d) is not None
+    structs = _paged_structs(one_chip, h, d, page, jnp.bfloat16, num_q)
+    assert "tpu_custom_call" not in _compile(_paged_fn(num_q), *structs)
+    assert A.last_attention_path() == "reference"
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(A, "PAGED_BLOCK_LIMIT", 1 << 30)
+        with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
+            _compile(_paged_fn(num_q), *structs)

@@ -32,7 +32,7 @@ from sparkflow_tpu.optimizers_sharded import (gather_zero3_params,
                                               zero3_param_shardings,
                                               zero_memory_report)
 from sparkflow_tpu.parallel.dp import make_dp_train_step
-from sparkflow_tpu.parallel.mesh import make_mesh
+from sparkflow_tpu.parallel.mesh import make_mesh, replicate_on_mesh
 from sparkflow_tpu.sharding import ShardingConfig, as_sharding_config
 from sparkflow_tpu.trainer import Trainer
 
@@ -67,7 +67,9 @@ def _init_for_stage(m, opt, mesh, stage, p0):
         p = shard_zero3_params(p0, 8)
         p = jax.tree.map(jax.device_put, p, zero3_param_shardings(p, mesh, 8))
         return p, state
-    return jax.tree.map(jnp.array, p0), state
+    # mesh-placed like the Trainer places them: the step's outputs are, and
+    # an unplaced first call would trace a second time
+    return replicate_on_mesh(jax.tree.map(jnp.array, p0), mesh), state
 
 
 def _run_stage(m, opt, mesh, stage, p0, steps=2):
