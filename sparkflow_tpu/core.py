@@ -92,12 +92,19 @@ def make_feeds_builder(input_name, label_name: Optional[str]) -> Callable:
 
 
 def _step_body(loss_fn: Callable, optimizer: optax.GradientTransformation) -> Callable:
-    """The one optimizer step shared by make_train_step and make_epoch_fn."""
+    """The one optimizer step shared by make_train_step and make_epoch_fn.
+
+    Two ``jax.named_scope``s put every device op of a step under a phase in
+    a profile (``docs/observability.md``): ``loss`` holds the forward pass,
+    and the backward pass too, which JAX marks with a ``transpose(`` component
+    of its own inside the path; ``optimizer`` holds the update."""
 
     def step(params, opt_state, x, y, mask, rng):
-        loss, grads = jax.value_and_grad(loss_fn)(params, x, y, mask, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("loss"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, x, y, mask, rng)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step

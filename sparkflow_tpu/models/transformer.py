@@ -169,32 +169,37 @@ class _TransformerBase(RegistryModel):
         dense blocks have no expert bank."""
         del ep_axis
         b, s, h = x.shape
-        y = _layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
-        qkv = self._proj(bp, "qkv_", y)
-        heads = qkv.shape[-1] // (3 * self.head_dim)
-        qkv = qkv.reshape(b, s, 3, heads, self.head_dim)
-        # ONE relayout for all three tensors ([B,S,3,h,d] -> [3,B,h,S,d]),
-        # not three sliced transposes — TPU relayouts are real copies and
-        # this is on the per-block hot path (same math, layout only)
-        qkv = jnp.transpose(qkv, (2, 0, 3, 1, 4))
-        q, k, v = qkv[0], qkv[1], qkv[2]
-        att = self._attention(q, k, v, mask, causal)
-        att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
-        att, rng = self._dropout(self._proj(bp, "o_", att), train, rng)
-        if tp_axis is not None:
-            att = jax.lax.psum(att, tp_axis)
-        x = x + att
-        y = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
-        y = jax.nn.gelu(self._proj(bp, "fc1_", y))
-        y, rng = self._dropout(self._proj(bp, "fc2_", y), train, rng)
-        if tp_axis is not None:
-            y = jax.lax.psum(y, tp_axis)
+        # one scope per block kind, not per layer: a profile sums the 2 x L
+        # halves of the stack under two names (docs/observability.md)
+        with jax.named_scope("attention"):
+            y = _layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
+            qkv = self._proj(bp, "qkv_", y)
+            heads = qkv.shape[-1] // (3 * self.head_dim)
+            qkv = qkv.reshape(b, s, 3, heads, self.head_dim)
+            # ONE relayout for all three tensors ([B,S,3,h,d] -> [3,B,h,S,d]),
+            # not three sliced transposes — TPU relayouts are real copies and
+            # this is on the per-block hot path (same math, layout only)
+            qkv = jnp.transpose(qkv, (2, 0, 3, 1, 4))
+            q, k, v = qkv[0], qkv[1], qkv[2]
+            att = self._attention(q, k, v, mask, causal)
+            att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
+            att, rng = self._dropout(self._proj(bp, "o_", att), train, rng)
+            if tp_axis is not None:
+                att = jax.lax.psum(att, tp_axis)
+            x = x + att
+        with jax.named_scope("mlp"):
+            y = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
+            y = jax.nn.gelu(self._proj(bp, "fc1_", y))
+            y, rng = self._dropout(self._proj(bp, "fc2_", y), train, rng)
+            if tp_axis is not None:
+                y = jax.lax.psum(y, tp_axis)
+            x = x + y
         if with_kv:
             # prefill path: the block's keys/values ([B,heads,S,d], local
             # heads under tp) feed the decode KV cache — same tensors
             # attention just consumed
-            return x + y, rng, k, v
-        return x + y, rng
+            return x, rng, k, v
+        return x, rng
 
     def _block_decode(self, bp, x, layer, cache, pos, attend,
                       tp_axis: Optional[str] = None,
@@ -269,17 +274,18 @@ class _TransformerBase(RegistryModel):
         ids = feeds["input_ids"].astype(jnp.int32)
         mask = feeds.get("attention_mask")
         b, s = ids.shape
-        x = jnp.take(params["embed"]["tok"], ids, axis=0)
-        if self.sp_axis is not None:
-            # inside shard_map each device holds a sequence SHARD: use global
-            # positions, not local 0..s-1
-            offset = jax.lax.axis_index(self.sp_axis) * s
-            pos = jax.lax.dynamic_slice(params["embed"]["pos"], (offset, 0),
-                                        (s, self.hidden))
-        else:
-            pos = params["embed"]["pos"][:s]
-        x = x + pos[None, :, :]
-        x = self.cast(x)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"]["tok"], ids, axis=0)
+            if self.sp_axis is not None:
+                # inside shard_map each device holds a sequence SHARD: use
+                # global positions, not local 0..s-1
+                offset = jax.lax.axis_index(self.sp_axis) * s
+                pos = jax.lax.dynamic_slice(params["embed"]["pos"],
+                                            (offset, 0), (s, self.hidden))
+            else:
+                pos = params["embed"]["pos"][:s]
+            x = x + pos[None, :, :]
+            x = self.cast(x)
         if rng is None:
             rng = jax.random.PRNGKey(0)
         block = self._block_aux
@@ -352,8 +358,9 @@ class TransformerLM(_TransformerBase):
 
     def _forward(self, params, feeds, train, rng):
         x, _, _ = self._encode(params, feeds, causal=True, train=train, rng=rng)
-        logits = jnp.matmul(x.astype(jnp.float32),
-                            params["embed"]["tok"].T.astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            logits = jnp.matmul(x.astype(jnp.float32),
+                                params["embed"]["tok"].T.astype(jnp.float32))
         return {"logits": logits,
                 "pred": jnp.argmax(logits, axis=-1).astype(jnp.float32)}
 

@@ -21,7 +21,7 @@ See ``docs/observability.md`` for the end-to-end walkthrough.
 """
 
 from .spans import (Span, TraceContext, Tracer, current_tracer,
-                    default_tracer, span)
+                    default_tracer, phases, span)
 from .stepstats import StepStats
 from .collector import TraceCollector, trace_spans
 from .flight import FlightRecorder, harvest_flight
@@ -29,7 +29,7 @@ from .exporters import MemoryWatcher, prometheus_name, prometheus_text
 
 __all__ = [
     "Span", "TraceContext", "Tracer", "current_tracer", "default_tracer",
-    "span",
+    "span", "phases",
     "StepStats",
     "TraceCollector", "trace_spans",
     "FlightRecorder", "harvest_flight",

@@ -56,7 +56,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Union
 
 __all__ = ["Span", "TraceContext", "Tracer", "default_tracer", "span",
-           "current_tracer"]
+           "phases", "current_tracer"]
 
 _span_ids = itertools.count(1)
 _now = time.perf_counter
@@ -468,3 +468,40 @@ def span(name: str, args: Optional[Dict[str, Any]] = None,
     entry point for cross-cutting sites (checkpoint, retry, serving engine)
     that should not care which tracer is collecting."""
     return current_tracer().span(name, args, parent, jax_annotation)
+
+
+class phases:
+    """A root span with one child open at a time: ``with phases(root) as ph``
+    opens the root, ``ph.enter(name)`` closes the open child and opens the
+    next, ``ph.leave()`` closes it without a successor, and leaving the
+    ``with`` block closes both. A long function's phases so come out as
+    children of one root, disjoint and in order, without a ``with`` block
+    (and its indentation) for each. Every span goes through the
+    module-level :func:`span`, so they land on the active tracer, and with
+    ``jax_annotation`` in the profiler's host plane too (how
+    ``Trainer.fit`` shows its ``train/...`` phases on both of its paths)."""
+
+    __slots__ = ("_root", "_child", "_jax_annotation")
+
+    def __init__(self, root: str, jax_annotation: bool = False):
+        self._root = span(root, jax_annotation=jax_annotation)
+        self._child = None
+        self._jax_annotation = jax_annotation
+
+    def __enter__(self) -> "phases":
+        self._root.__enter__()
+        return self
+
+    def enter(self, name: str) -> None:
+        self.leave()
+        self._child = span(name, jax_annotation=self._jax_annotation)
+        self._child.__enter__()
+
+    def leave(self) -> None:
+        child, self._child = self._child, None
+        if child is not None:
+            child.__exit__(None, None, None)
+
+    def __exit__(self, *exc):
+        self.leave()
+        return self._root.__exit__(*exc)

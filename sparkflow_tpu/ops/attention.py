@@ -317,6 +317,7 @@ def _flash_pallas_forward(q, k, v, kv_mask, causal, scale, block_q, block_k,
         args.append(kv_mask.astype(jnp.float32)[:, None, :])
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * h, s // block_q, sk // block_k),
         in_specs=in_specs,
         out_specs=(pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -556,6 +557,7 @@ def _flash_pallas_backward_flat(qf, kf, vf, gf, lsef, delta, maskf, h,
         args_dq.append(maskf)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common),
+        name="flash_bwd_dq",
         grid=(bh, s // block_q, sk // block_k),
         in_specs=in_specs_dq,
         out_specs=qspec,
@@ -581,6 +583,7 @@ def _flash_pallas_backward_flat(qf, kf, vf, gf, lsef, delta, maskf, h,
         args_kv.append(maskf)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
+        name="flash_bwd_dkv",
         grid=(bh, sk // block_k, s // block_q),
         in_specs=in_specs_kv,
         out_specs=(kspec, kspec),
@@ -935,6 +938,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         # the page axis folds one slot's online-softmax state — sequential
@@ -1103,6 +1107,7 @@ def paged_attention_verify(q, k_pages, v_pages, page_table, start,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_verify",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(

@@ -829,7 +829,14 @@ class Trainer:
         ``self.last_trace_path``). Phase totals and throughput/MFU gauges
         land in ``self.last_step_stats`` and the metrics registry. Tracing
         forces the per-epoch loop path (the fused multi-epoch program has
-        no host-visible step boundaries to time)."""
+        no host-visible step boundaries to time).
+
+        Whether or not ``trace_spans`` is set, and on the fused path too,
+        every fit records its host phases as spans on the active tracer and
+        as annotations in a running JAX profile: the root ``train/fit`` and
+        under it, disjoint and in this order, ``train/plan``,
+        ``train/init_state``, ``train/transfer``, ``train/launch``,
+        ``train/wait``, ``train/finish`` (docs/observability.md)."""
         with self._recompile_scope():
             if not trace_spans:
                 return self._fit_impl(features, labels, init_params)
@@ -852,7 +859,6 @@ class Trainer:
                     if _current_tracker() is None:
                         es.enter_context(track_recompiles(warn_after=10**9))
                     es.enter_context(tracer.activate())
-                    es.enter_context(tracer.span("train/fit"))
                     result = self._fit_impl(features, labels, init_params)
             finally:
                 self._tracer = None
@@ -871,6 +877,14 @@ class Trainer:
 
     def _fit_impl(self, features, labels: Optional[np.ndarray] = None,
                   init_params=None) -> TrainResult:
+        from .obs import phases
+        with phases("train/fit", jax_annotation=True) as ph:
+            return self._fit_phases(ph, features, labels, init_params)
+
+    def _fit_phases(self, ph, features, labels, init_params) -> TrainResult:
+        """The fit itself; ``ph`` opens each host phase's span as it begins
+        (the names are in :meth:`fit`'s docstring)."""
+        ph.enter("train/plan")
         # multi-input features travel as a TUPLE of arrays; a plain list is
         # row data (np.asarray coercible), exactly as in single-input fits
         multi = isinstance(features, tuple)
@@ -968,6 +982,7 @@ class Trainer:
         else:
             y_pad = np.zeros((total, 1), np.float32)  # dummy; loss ignores it
 
+        ph.enter("train/init_state")
         rng = self._make_rng()
         init_rng, rng = jax.random.split(rng)
         if init_params is not None:
@@ -1074,15 +1089,16 @@ class Trainer:
             # everything up to here (validation, plan, init, restore) is
             # one-time setup; charging it keeps phase sums ≈ wall time
             stats.add("setup", stats.elapsed_s())
+        ph.enter("train/transfer")
+        t_stage = time.perf_counter()
+        device_args = (jax.tree.map(jnp.asarray, x_pad),
+                       jnp.asarray(y_pad), jnp.asarray(mask))
+        if stats is not None:
             # sync inside the phase so host->device transfer is charged
             # here and not to the first step
-            with stats.phase("transfer"):
-                device_args = (jax.tree.map(jnp.asarray, x_pad),
-                               jnp.asarray(y_pad), jnp.asarray(mask))
-                jax.block_until_ready(device_args)
-        else:
-            device_args = (jax.tree.map(jnp.asarray, x_pad),
-                           jnp.asarray(y_pad), jnp.asarray(mask))
+            jax.block_until_ready(device_args)
+            stats.add("transfer", time.perf_counter() - t_stage)
+        ph.leave()
 
         loss_by_it = {}  # device scalars; converted lazily to keep async dispatch
         t0 = time.perf_counter()
@@ -1129,13 +1145,16 @@ class Trainer:
                     opt_shardings=opt_shardings,
                     param_shardings=param_shardings,
                     sharding=self.sharding)
+            ph.enter("train/launch")
             erngs = []
             for _ in range(k):
                 rng, erng = jax.random.split(rng)
                 erngs.append(erng)
             params, opt_state, losses = self._epoch_cache[fkey](
                 params, opt_state, *device_args, jnp.stack(erngs))
+            ph.enter("train/wait")
             params = jax.block_until_ready(params)
+            ph.enter("train/finish")
             wall = time.perf_counter() - t0
             per_epoch = num_batches * batch if mode == "stochastic" else n
             if strategy == "pp":
@@ -1215,16 +1234,19 @@ class Trainer:
                             # left off
                             continue
                         te = time.perf_counter()
+                        ph.enter("train/launch")
                         rng, erng = jax.random.split(rng)
                         if stats is None:
                             params, opt_state, losses = epoch_fn(
                                 params, opt_state, *device_args, erng)
+                            ph.leave()
                         else:
                             stats.begin_step()
                             probes_before = _probe_count()
                             ts0 = time.perf_counter()
                             params, opt_state, losses = epoch_fn(
                                 params, opt_state, *device_args, erng)
+                            ph.leave()
                             # sync so the step phase owns its real device
                             # time (async dispatch would smear it into the
                             # metrics/checkpoint phases)
@@ -1328,7 +1350,9 @@ class Trainer:
                     "from checkpoint epoch %d (%d retries left)", it,
                     type(e).__name__, e, start_epoch, retries_left)
         # block until the last step is done for honest timing
+        ph.enter("train/wait")
         params = jax.block_until_ready(params)
+        ph.enter("train/finish")
         wall = time.perf_counter() - t0
         if stats is not None:
             # FLOPs per "step" (= one epoch_fn call = num_batches optimizer

@@ -13,6 +13,7 @@ may load the TPU library, and pytest-xdist workers each import every file.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -157,3 +158,37 @@ def test_paged_gate_is_the_compilers(one_chip, num_q):
         mp.setattr(A, "PAGED_BLOCK_LIMIT", 1 << 30)
         with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
             _compile(_paged_fn(num_q), *structs)
+
+
+# -- the kernels' names -------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def kernel_texts(one_chip):
+    """Compiled text of flash forward+backward and of both paged kernels."""
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *qkv: A.flash_attention(
+            *qkv, causal=True, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    flash = _compile(fwd_bwd, *_flash_structs(one_chip, 8, 12, 1024, 64,
+                                              jnp.bfloat16, False))
+    paged = [_compile(_paged_fn(num_q), *_paged_structs(
+        one_chip, 12, 64, 16, jnp.bfloat16, num_q)) for num_q in (None, 5)]
+    return {"flash_fwd": flash, "flash_bwd_dq": flash, "flash_bwd_dkv": flash,
+            "paged_decode": paged[0], "paged_verify": paged[1]}
+
+
+@pytest.mark.parametrize("name", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                                  "paged_decode", "paged_verify"])
+def test_kernels_keep_their_names(kernel_texts, name):
+    """Each ``pallas_call`` carries a ``name=``, and the compiler keeps it
+    inside the custom call's instruction name, wrapped in the transforms
+    around it (``%jvp_flash_fwd_.1``): a trace's reader matches by
+    *contains* (``chipbench/trace_reads.py``). Unnamed, the three flash
+    kernels were ``jvp__.N`` / ``transpose_jvp___.N``."""
+    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"",
+                       kernel_texts[name], re.M)
+    assert calls and all(re.search(r"flash_|paged_", c) for c in calls), calls
+    assert any(name in c for c in calls), calls
