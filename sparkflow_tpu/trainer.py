@@ -255,6 +255,7 @@ class Trainer:
         self.params = None
         self._last_opt_state = None
         self._epoch_cache = {}  # (batch, num_batches, mode, shuffle) -> compiled epoch
+        self._state_programs = {}  # (with_opt, replicate) -> jitted _fresh_state
         # step-level checkpoint/resume — a capability upgrade over the
         # reference's save-at-end-only persistence (SURVEY.md §5)
         self.checkpoint_dir = checkpoint_dir
@@ -345,6 +346,40 @@ class Trainer:
                 f"moe families); use an 'fsdp' axis instead — ZeRO specs "
                 f"derive from param_specs() for any model")
         return pspecs
+
+    def _fresh_state(self, tree, *, with_opt: bool, replicate: bool = False):
+        """What a fit starts from, made by ONE device program over the whole
+        tree and not by a handful of eager ones a leaf: the fit's own copy of
+        ``tree`` and, ``with_opt``, the optimizer's fresh state for that copy
+        (returned as ``(copy, state)``). The copy is a real one, ``jnp.copy``
+        of every leaf *inside* the jit: a jitted identity may forward its
+        input buffers, and the epoch program donates its params and state, so
+        it must never be handed arrays a caller still holds.
+
+        ``replicate`` pins both outputs on the trainer's mesh, replicated, as
+        :func:`replicate_on_mesh` leaves them (pure dp/sp): under jit a
+        ``zeros_like`` has no data dependence on its parameter, so the
+        placement is stated, never left to the compiler. The programs are
+        built once and kept; ``jax.jit`` caches by tree structure and avals."""
+        prog = self._state_programs.get((with_opt, replicate))
+        if prog is None:
+            optimizer = self.optimizer  # the kept program holds no trainer
+
+            def init_state(src):
+                params = jax.tree.map(jnp.copy, src)
+                if not with_opt:
+                    return params
+                return params, optimizer.init(params)
+
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            prog = self._state_programs[(with_opt, replicate)] = jax.jit(
+                init_state, out_shardings=(NamedSharding(self.mesh, P())
+                                           if replicate else None))
+        if replicate:
+            # arrays committed elsewhere (another trainer's mesh) move first;
+            # what is already replicated here passes through untouched
+            tree = replicate_on_mesh(tree, self.mesh)
+        return prog(tree)
 
     def _place_params(self, params, pspecs):
         from .parallel.tp import shard_params
@@ -836,7 +871,14 @@ class Trainer:
         as annotations in a running JAX profile: the root ``train/fit`` and
         under it, disjoint and in this order, ``train/plan``,
         ``train/init_state``, ``train/transfer``, ``train/launch``,
-        ``train/wait``, ``train/finish`` (docs/observability.md)."""
+        ``train/wait``, ``train/finish`` (docs/observability.md).
+
+        ``train/init_state`` holds one device program however many leaves
+        the model has (:meth:`_fresh_state`: the fit's own copy of
+        ``init_params``, which stay the caller's, alive and unchanged, and
+        the optimizer's fresh state), after the trainer has let go of the fit
+        before's optimizer state. Sharded layouts (tp/fsdp, ZeRO, pp) take
+        the one-program copy and build their state as they place it."""
         with self._recompile_scope():
             if not trace_spans:
                 return self._fit_impl(features, labels, init_params)
@@ -983,41 +1025,56 @@ class Trainer:
             y_pad = np.zeros((total, 1), np.float32)  # dummy; loss ignores it
 
         ph.enter("train/init_state")
+        # the last fit's optimizer state goes before the new one is made: two
+        # fits' states never stand on the device together (ema_weights() is
+        # None from here until this fit ends)
+        self._last_opt_state = None
         rng = self._make_rng()
         init_rng, rng = jax.random.split(rng)
-        if init_params is not None:
-            # copy: the epoch program donates its params buffers, which would
-            # invalidate the caller's arrays on TPU
-            params = jax.tree.map(lambda a: jnp.array(a), init_params)
-        else:
-            params = self.model.init(init_rng)
+        # the caller's arrays until _fresh_state below has made this fit's
+        # own copy of them (the epoch program donates its params buffers,
+        # which would delete the caller's arrays)
+        params = (init_params if init_params is not None
+                  else self.model.init(init_rng))
         if strategy == "pp":
             # repack into the stage-stacked pipeline layout, sharded over
             # 'pp' (merged back to the standard layout at the end of fit,
             # so serving/weights export never see pipeline internals)
             from .parallel.pp import pp_pspecs, split_stage_params
-            params = split_stage_params(self.model, params,
-                                        self.mesh.shape["pp"])
+            params = split_stage_params(
+                self.model, self._fresh_state(params, with_opt=False),
+                self.mesh.shape["pp"])
             pspecs = pp_pspecs(params)
         else:
             pspecs = self._resolve_pspecs()
-        if pspecs is not None:
-            # tp/fsdp: place params per their PartitionSpecs BEFORE the
-            # optimizer init so mu/nu/etc inherit the same placement
-            params = self._place_params(params, pspecs)
-        elif self.mesh is not None:
-            # pure dp/sp: replicated on the mesh from the start. A fresh
-            # init lives uncommitted on one device while every step's output
-            # is mesh-placed, and an argument's type carries its mesh — left
-            # as it was, the second call of the same program traced (and on
-            # a chip compiled) again with identical shapes
-            params = replicate_on_mesh(params, self.mesh)
         self._zero_stage = self._resolve_zero_stage(strategy, pspecs, params)
         self._zero1_active = self._zero_stage >= 1
         self._zero3_template = None
         self._offload_active = bool(self.sharding is not None
                                     and self.sharding.offload_opt_state
                                     and self.mesh is not None)
+        opt_state = None
+        if pspecs is not None:
+            # tp/fsdp/pp: place params per their PartitionSpecs BEFORE the
+            # optimizer init so mu/nu/etc inherit the same placement. That
+            # init stays eager: under jit a zeros_like of a sharded parameter
+            # has no data dependence on it and may come out replicated
+            if strategy != "pp":
+                params = self._fresh_state(params, with_opt=False)
+            params = self._place_params(params, pspecs)
+        elif self._zero1_active:
+            # ZeRO builds its state below in its own flat layout, from
+            # params replicated on the mesh
+            params = self._fresh_state(params, with_opt=False, replicate=True)
+        else:
+            # default, and pure dp/sp: params and state from one program.
+            # With a mesh both come out replicated on it from the start, as
+            # every step's output is: an argument's type carries its mesh,
+            # and state left unplaced made the second call of the same
+            # program trace (and on a chip compile) again with identical
+            # shapes
+            params, opt_state = self._fresh_state(
+                params, with_opt=True, replicate=self.mesh is not None)
         opt_shardings = None
         param_shardings = None
         if self._zero1_active:
@@ -1047,11 +1104,8 @@ class Trainer:
                 param_shardings = zero3_param_shardings(params, self.mesh,
                                                         dp_n, dp_ax)
                 params = jax.tree.map(jax.device_put, params, param_shardings)
-        else:
+        elif opt_state is None:
             opt_state = self.optimizer.init(params)
-            if pspecs is None and self.mesh is not None:
-                # optax builds its step count fresh and unplaced
-                opt_state = replicate_on_mesh(opt_state, self.mesh)
 
         ckpt_mgr = None
         start_epoch = 0
@@ -1440,7 +1494,7 @@ class Trainer:
         rng = self._make_rng()
         init_rng, _rng = jax.random.split(rng)
         if init_params is not None:
-            params = jax.tree.map(lambda a: jnp.array(a), init_params)
+            params = self._fresh_state(init_params, with_opt=False)
         else:
             params = self.model.init(init_rng)
 
@@ -1489,7 +1543,10 @@ class Trainer:
         """The debiased Polyak-averaged weight tree from the last fit, when
         the optimizer was built with the ``ema_decay`` config key; None
         otherwise. Serve these instead of the raw final weights for the
-        usual EMA quality bump."""
+        usual EMA quality bump. A fit lets go of the fit before's optimizer
+        state as it begins (two states never stand on the device together),
+        so during a fit, and after one that raised, this is None and not the
+        earlier fit's average."""
         if self._last_opt_state is None:
             return None
         from .optimizers import extract_ema_params
@@ -1577,7 +1634,7 @@ class Trainer:
 
         if init_params is not None:
             # copy: the train step donates its params buffers
-            params = jax.tree.map(lambda a: jnp.array(a), init_params)
+            params = self._fresh_state(init_params, with_opt=False)
         else:
             params = self.model.init(init_rng)
         pspecs = self._resolve_pspecs()
