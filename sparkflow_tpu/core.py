@@ -52,8 +52,15 @@ def _rows(x) -> int:
 
 
 def make_loss_fn(model: GraphModel, input_name,
-                 label_name: Optional[str]) -> Callable:
+                 label_name: Optional[str],
+                 with_metrics: bool = False) -> Callable:
     """Build ``loss_fn(params, x, y, mask, rng) -> scalar`` from a GraphModel.
+
+    ``with_metrics`` asks for the step's counters as well: where the model
+    has a ``loss_and_metrics`` (a registry model with counters of its own,
+    ``models/sparse_moe_lm.py``), ``loss_fn`` returns ``(scalar, metrics)``
+    and carries ``has_metrics = True``, which :func:`_step_body` reads; any
+    other model gives the plain ``loss_fn``.
 
     ``input_name`` is one tensor name or a sequence of names — with a
     sequence, ``x`` is a matching tuple of arrays (multi-input models, e.g. a
@@ -66,6 +73,15 @@ def make_loss_fn(model: GraphModel, input_name,
     (``sparkflow/ml_util.py:109-118``) and the dropout feed exists only on the
     predict path (``sparkflow/ml_util.py:70-71``)."""
     build_feeds = make_feeds_builder(input_name, label_name)
+
+    if with_metrics and hasattr(model, "loss_and_metrics"):
+        def loss_fn(params, x, y, mask, rng):
+            lv, metrics = model.loss_and_metrics(
+                params, build_feeds(x, y), train=True, rng=rng)
+            return _masked_mean(lv, mask), metrics
+
+        loss_fn.has_metrics = True
+        return loss_fn
 
     def loss_fn(params, x, y, mask, rng):
         lv = model.loss_vector(params, build_feeds(x, y), train=True, rng=rng)
@@ -97,11 +113,17 @@ def _step_body(loss_fn: Callable, optimizer: optax.GradientTransformation) -> Ca
     Two ``jax.named_scope``s put every device op of a step under a phase in
     a profile (``docs/observability.md``): ``loss`` holds the forward pass,
     and the backward pass too, which JAX marks with a ``transpose(`` component
-    of its own inside the path; ``optimizer`` holds the update."""
+    of its own inside the path; ``optimizer`` holds the update.
+
+    A ``loss_fn`` with ``has_metrics`` (:func:`make_loss_fn`) returns the
+    step's counters beside the loss; the step then gives ``(loss, metrics)``
+    in the loss's place, and the scans over steps and epochs stack both."""
+    has_metrics = getattr(loss_fn, "has_metrics", False)
 
     def step(params, opt_state, x, y, mask, rng):
         with jax.named_scope("loss"):
-            loss, grads = jax.value_and_grad(loss_fn)(params, x, y, mask, rng)
+            loss, grads = jax.value_and_grad(loss_fn, has_aux=has_metrics)(
+                params, x, y, mask, rng)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

@@ -16,18 +16,22 @@ Families: ``mlp``, ``cnn``, ``autoencoder`` (graph-DSL preset builders mirroring
 the reference examples), ``transformer_classifier`` / ``transformer_lm`` (BERT
 -class encoder, flash/ring attention, TP/SP shardings), ``resnet50`` (CIFAR/
 ImageNet residual network, stateless norm), ``rnn_classifier`` / ``rnn_lm``
-(LSTM/GRU via lax.scan, fused gate matmuls).
+(LSTM/GRU via lax.scan, fused gate matmuls), ``sparse_moe_lm`` (RMSNorm,
+rotary, grouped query heads, a learned top-k key selection and dropless
+SiLU-gated experts of which a share may be held; training path only).
 """
 
 from .registry import model_from_json, register_model, build_registry_spec
 from . import presets
 from .transformer import TransformerClassifier, TransformerLM
 from .moe import MoETransformerLM
+from .sparse_moe_lm import SparseMoELM
 from .resnet import ResNet
 from .rnn import RNNClassifier, RNNLM
 
 __all__ = [
     "model_from_json", "register_model", "build_registry_spec", "presets",
-    "TransformerClassifier", "TransformerLM", "MoETransformerLM", "ResNet",
+    "TransformerClassifier", "TransformerLM", "MoETransformerLM",
+    "SparseMoELM", "ResNet",
     "RNNClassifier", "RNNLM",
 ]

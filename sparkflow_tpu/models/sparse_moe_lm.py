@@ -1,0 +1,310 @@
+"""A causal LM whose block is not GPT-2's: RMSNorm, rotary positions, grouped
+query heads with a per-head q/k norm, a learned selection of keys (an
+indexer that scores every (query, key) pair and keeps each query's ``topk``)
+and SiLU-gated experts that drop no token, of which this model may hold a
+share. No bias anywhere, an untied head. The block is written once
+(:meth:`SparseMoELM._block`).
+
+A layer, for a row of tokens (pre-norm, residual):
+
+1. ``h = RMSNorm(x)``; ``q, k, v`` projections (``num_heads`` query heads
+   over ``num_kv_heads`` key/value heads of ``head_dim``), RMSNorm over each
+   head of ``q`` and ``k``, rotary positions over the whole head.
+2. The indexer reads ``stop_gradient(h)``: ``qI`` (``indexer_heads`` of
+   ``indexer_dim``), one key head ``kI``, weights ``w``; the scores and the
+   selection are :func:`~sparkflow_tpu.ops.sparse_attention.index_select`'s.
+3. Attention over the selected keys
+   (:func:`~sparkflow_tpu.ops.sparse_attention.selected_attention`).
+4. ``h' = RMSNorm(x)``; the router over all ``num_experts`` in float32, the
+   token's top ``experts_per_token``; the experts ``experts_held`` add their
+   part (:func:`~sparkflow_tpu.ops.grouped_matmul.dropless_experts`). What
+   the experts not held would add is left out: under expert parallelism it
+   is the other chips' part of the sum.
+
+The loss of a row is its next-token cross-entropy over the vocabulary held
+(``vocab_held``: the ids, embedding rows and head columns of one slice), plus
+``indexer_loss_weight`` times each layer's indexer loss (the KL from the
+attention's head-summed probabilities to the indexer's softmax over the
+selection; it moves the indexer's three matrices and nothing else, and the
+cross-entropy never moves them), plus ``router_aux_weight`` times each
+layer's balance loss over all experts.
+
+The decode plane does not run this model: its cache would have to hold the
+indexer's keys and its kernels select pages per query (ROADMAP).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import grouped_matmul as gm
+from ..ops import sparse_attention as sa
+from .base import RegistryModel, _Names
+from .registry import register_model
+
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps) * scale
+    return y.astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotary positions over the whole last axis of ``x [B, S, ..., D]``
+    (rotate-half), position = index along axis 1; computed in float32."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = ang.reshape((1, s) + (1,) * (x.ndim - 3) + (d // 2,))
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
+    return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+            ).astype(x.dtype)
+
+
+def _dense(x, kernel):
+    return jnp.matmul(x, kernel.astype(x.dtype))
+
+
+@register_model("sparse_moe_lm")
+class SparseMoELM(RegistryModel):
+    """See the module's text. ``experts_held = (first, stop)`` and
+    ``vocab_held = (first, stop)`` give the share this model holds; the
+    default is everything."""
+
+    TENSORS = ("input_ids", "logits", "pred")
+    decode_unsupported = (
+        "sparse_moe_lm trains only: the decode plane has no cache for the "
+        "indexer's keys and no per-query selection in its paged kernels")
+
+    def __init__(self, vocab_size: int, hidden: int = 2048,
+                 num_layers: int = 4, num_heads: int = 32,
+                 num_kv_heads: int = 4, head_dim: int = 128,
+                 num_experts: int = 128, experts_per_token: int = 8,
+                 expert_dim: int = 768,
+                 experts_held: Optional[Sequence[int]] = None,
+                 vocab_held: Optional[Sequence[int]] = None,
+                 indexer_heads: int = 16, indexer_dim: int = 64,
+                 indexer_topk: int = 2048, indexer_block: int = 256,
+                 rope_theta: float = 1e7, rms_eps: float = 1e-6,
+                 norm_topk_prob: bool = True,
+                 router_aux_weight: float = 0.001,
+                 indexer_loss_weight: float = 1.0, max_len: int = 8192,
+                 head_block: int = 2048,
+                 dropout: float = 0.0, remat: bool = True,
+                 compute_dtype=None):
+        if dropout:
+            raise ValueError("sparse_moe_lm has no dropout")
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads do not divide over "
+                             f"{num_kv_heads} key/value heads")
+        self.vocab_size, self.hidden = vocab_size, hidden
+        self.num_layers, self.num_heads = num_layers, num_heads
+        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+        self.num_experts, self.experts_per_token = num_experts, experts_per_token
+        self.expert_dim = expert_dim
+        self.experts_held = tuple(experts_held or (0, num_experts))
+        self.vocab_held = tuple(vocab_held or (0, vocab_size))
+        for name, (lo, hi), whole in (
+                ("experts_held", self.experts_held, num_experts),
+                ("vocab_held", self.vocab_held, vocab_size)):
+            if not 0 <= lo < hi <= whole:
+                raise ValueError(f"{name}={lo, hi} is no range of {whole}")
+        self.indexer_heads, self.indexer_dim = indexer_heads, indexer_dim
+        self.indexer_topk, self.indexer_block = indexer_topk, indexer_block
+        self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
+        self.norm_topk_prob = norm_topk_prob
+        self.router_aux_weight = router_aux_weight
+        self.indexer_loss_weight = indexer_loss_weight
+        self.max_len, self.remat = max_len, remat
+        self.head_block = head_block
+        super().__init__(compute_dtype)
+        self.graphdef = _Names(self.TENSORS)
+
+    # -- specs ---------------------------------------------------------------
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def vocab_here(self) -> int:
+        return self.vocab_held[1] - self.vocab_held[0]
+
+    def input_specs(self):
+        return {"input_ids": ((None, self.max_len), "int32")}
+
+    def _block_specs(self):
+        h, d, m = self.hidden, self.head_dim, self.expert_dim
+        n = "normal(0.02)"
+        return {
+            "ln1_scale": ((h,), "ones"),
+            "q_kernel": ((h, self.num_heads * d), n),
+            "k_kernel": ((h, self.num_kv_heads * d), n),
+            "v_kernel": ((h, self.num_kv_heads * d), n),
+            "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"),
+            "o_kernel": ((self.num_heads * d, h), n),
+            "idx_q_kernel": ((h, self.indexer_heads * self.indexer_dim), n),
+            "idx_k_kernel": ((h, self.indexer_dim), n),
+            "idx_w_kernel": ((h, self.indexer_heads), n),
+            "ln2_scale": ((h,), "ones"),
+            "router": ((h, self.num_experts), n),
+            "experts_w1": ((self.held, h, m), n),
+            "experts_w3": ((self.held, h, m), n),
+            "experts_w2": ((self.held, m, h), n),
+        }
+
+    def param_specs(self):
+        h = self.hidden
+        specs = {"embed": {"tok": ((self.vocab_here, h), "normal(0.02)")}}
+        for i in range(self.num_layers):
+            specs[f"block_{i}"] = self._block_specs()
+        specs["final_ln"] = {"scale": ((h,), "ones")}
+        specs["lm_head"] = {"kernel": ((h, self.vocab_here), "normal(0.02)")}
+        return specs
+
+    # -- the block, once -----------------------------------------------------
+
+    def _attend(self, bp, y):
+        """Steps 1-3 on ``y = RMSNorm(x) [B, S, h]``: the attention's output
+        before ``W_o``, each row's indexer loss ``[B]`` and the keys a query
+        selected (mean)."""
+        b, s, _ = y.shape
+        nq, nkv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
+        q = heads(_dense(y, bp["q_kernel"]), nq)
+        k = heads(_dense(y, bp["k_kernel"]), nkv)
+        v = heads(_dense(y, bp["v_kernel"]), nkv)
+        q = rope(rms_norm(q, bp["q_norm"], self.rms_eps), self.rope_theta)
+        k = rope(rms_norm(k, bp["k_norm"], self.rms_eps), self.rope_theta)
+        q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+
+        with jax.named_scope("indexer"):
+            ys = jax.lax.stop_gradient(y)
+            qi = rope(heads(_dense(ys, bp["idx_q_kernel"]),
+                            self.indexer_heads), self.rope_theta)
+            ki = rope(_dense(ys, bp["idx_k_kernel"]), self.rope_theta)
+            w = _dense(ys, bp["idx_w_kernel"])
+            mask = sa.index_select(qi, ki, w, self.indexer_topk,
+                                   self.indexer_block)
+        with jax.named_scope("sparse_attention"):
+            att, lse = sa.selected_attention(q, k, v, mask)
+        with jax.named_scope("indexer"):
+            target = sa.selected_probs(q, k, lse, mask)
+            kl = sa.indexer_loss(qi, ki, w, mask, target, self.indexer_block)
+            picked = jnp.mean(jnp.sum(mask.astype(jnp.float32), axis=-1))
+        att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, nq * d)
+        return att, kl, picked
+
+    def _experts(self, bp, y):
+        """Step 4 on ``y = RMSNorm(x) [B, S, h]``: the held experts' part of
+        the layer's output, each row's balance loss ``[B]`` and each held
+        expert's load ``[held]``."""
+        b, s, h = y.shape
+        with jax.named_scope("router"):
+            logits = jnp.matmul(y.reshape(b * s, h).astype(jnp.float32),
+                                bp["router"],
+                                precision=jax.lax.Precision.HIGHEST)
+            probs, gates, experts = gm.route_top_k(
+                logits, self.experts_per_token, self.norm_topk_prob)
+            balance = jax.vmap(gm.balance_loss)(
+                probs.reshape(b, s, -1), experts.reshape(b, s, -1))
+        with jax.named_scope("experts"):
+            out, load = gm.dropless_experts(
+                y.reshape(b * s, h), gates, experts, bp["experts_w1"],
+                bp["experts_w3"], bp["experts_w2"], self.experts_held[0])
+        return out.reshape(b, s, h), balance, load
+
+    def _block(self, bp, x):
+        """One layer on ``x [B, S, h]`` -> ``(x, aux)``; ``aux`` holds each
+        row's indexer and balance loss and the layer's counters."""
+        att, kl, picked = self._attend(
+            bp, rms_norm(x, bp["ln1_scale"], self.rms_eps))
+        x = x + _dense(att, bp["o_kernel"])
+        out, balance, load = self._experts(
+            bp, rms_norm(x, bp["ln2_scale"], self.rms_eps))
+        return x + out, dict(indexer=kl, balance=balance,
+                             selected_keys=picked, expert_load=load)
+
+    # -- forward and loss ----------------------------------------------------
+
+    def _head(self, params, x):
+        """Final norm and the head over the vocabulary held: float32
+        logits."""
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_ln"]["scale"], self.rms_eps)
+            return jnp.matmul(x, params["lm_head"]["kernel"].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
+
+    def _encode(self, params, ids):
+        with jax.named_scope("embed"):
+            x = self.cast(jnp.take(params["embed"]["tok"],
+                                   ids - self.vocab_held[0], axis=0))
+        block = jax.checkpoint(self._block) if self.remat else self._block
+        aux = []
+        for i in range(self.num_layers):
+            x, a = block(params[f"block_{i}"], x)
+            aux.append(a)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *aux)
+
+    def _forward(self, params, feeds, train, rng):
+        ids = feeds["input_ids"].astype(jnp.int32)
+        logits = self._head(params, self._encode(params, ids)[0])
+        return {"logits": logits,
+                "pred": (jnp.argmax(logits, axis=-1)
+                         + self.vocab_held[0]).astype(jnp.float32)}
+
+    def _row_nll(self, params, x, ids):
+        """Mean next-token cross-entropy of one row: ``x [S, h]`` (before the
+        final norm), ``ids [S]``. The head's float32 logits are made and
+        reduced a stretch of the row at a time (and again in the backward
+        pass): a whole row's are ``S x vocab`` floats, three times over."""
+        s = ids.shape[0]
+        c = self.head_block if s % self.head_block == 0 else s
+        tgt = jnp.concatenate([ids[1:], ids[:1]]) - self.vocab_held[0]
+        live = (jnp.arange(s) < s - 1).astype(jnp.float32)
+
+        @jax.checkpoint
+        def stretch(a):
+            xs, t, w = a
+            logits = self._head(params, xs)
+            picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+            return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * w)
+
+        split = lambda a: a.reshape((s // c, c) + a.shape[1:])
+        return jnp.sum(jax.lax.map(
+            stretch, (split(x), split(tgt), split(live)))) / (s - 1)
+
+    def loss_and_metrics(self, params, feeds, train=True, rng=None):
+        """Each row's loss ``[B]`` and the step's counters: per layer the
+        pairs each held expert got (``expert_load [L, held]``) and the keys
+        a query selected (``selected_keys [L]``), and the (token, expert)
+        pairs the step routed in all (``pairs_routed``). Rows go through the
+        model one after another: every part of the loss is a row's own, a
+        row of 8k tokens fills the chip's matrix unit, and the buffers of a
+        dropless layer are sized for the worst routing of the tokens they
+        serve."""
+        feeds = {k.split(":")[0]: v for k, v in feeds.items()}
+        ids = feeds["input_ids"].astype(jnp.int32)
+
+        def row(r):
+            x, aux = self._encode(params, r[None])
+            loss = (self._row_nll(params, x[0], r)
+                    + self.indexer_loss_weight * jnp.sum(aux["indexer"])
+                    + self.router_aux_weight * jnp.sum(aux["balance"]))
+            return loss, (aux["expert_load"], aux["selected_keys"])
+
+        loss, (load, picked) = jax.lax.map(row, ids)
+        pairs = ids.shape[0] * ids.shape[1] * self.experts_per_token
+        return loss, dict(expert_load=jnp.sum(load, axis=0),
+                          selected_keys=jnp.mean(picked, axis=0),
+                          pairs_routed=jnp.full((), pairs, jnp.int32))
+
+    def _loss(self, params, feeds, train, rng):
+        return self.loss_and_metrics(params, feeds, train, rng)[0]

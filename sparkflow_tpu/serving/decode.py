@@ -233,6 +233,9 @@ class DecodeEngine:
         if isinstance(model, str):
             from ..models import model_from_json
             model = model_from_json(model)
+        if getattr(model, "decode_unsupported", None):
+            raise TypeError(f"DecodeEngine cannot serve this model: "
+                            f"{model.decode_unsupported}")
         for need in ("prefill", "decode_step"):
             if not hasattr(model, need):
                 raise TypeError(f"model has no {need}(); DecodeEngine needs "

@@ -84,15 +84,19 @@ class TrainResult:
     """
 
     __slots__ = ("params", "losses", "examples_per_sec", "wall_time_s",
-                 "stop_reason")
+                 "stop_reason", "metrics")
 
     def __init__(self, params, losses, examples_per_sec, wall_time_s,
-                 stop_reason: str = "completed"):
+                 stop_reason: str = "completed", metrics=None):
         self.params = params
         self.losses = losses
         self.examples_per_sec = examples_per_sec
         self.wall_time_s = wall_time_s
         self.stop_reason = stop_reason
+        # the model's own counters of every step of a fused fit, as numpy
+        # arrays ``[epochs, steps, ...]`` read back once after the fit
+        # (``loss_and_metrics``: models/sparse_moe_lm.py); None otherwise
+        self.metrics = metrics
 
     @property
     def completed(self) -> bool:
@@ -1191,7 +1195,7 @@ class Trainer:
                     self._zero_stage)
             if fkey not in self._epoch_cache:
                 loss_fn = make_loss_fn(self.model, self.input_name,
-                                       self.label_name)
+                                       self.label_name, with_metrics=True)
                 self._epoch_cache[fkey] = make_multi_epoch_fn(
                     loss_fn, self.optimizer, batch, num_batches, mode,
                     self.shuffle_per_iter, k, self.mesh, n_real=n,
@@ -1217,12 +1221,17 @@ class Trainer:
             params = self._params_to_ckpt(params)
             self.params = params
             self._last_opt_state = opt_state
+            metrics = None
+            if isinstance(losses, tuple):    # a model with counters
+                losses, metrics = losses
+                metrics = jax.device_get(metrics)
             epoch_losses = [float(l) for l in jnp.mean(losses, axis=1)]
             self._warn_non_finite(epoch_losses)
             if self._publish_store is not None:
                 self._publish_weights(params)
             return TrainResult(params, epoch_losses,
-                               per_epoch * k / max(wall, 1e-9), wall)
+                               per_epoch * k / max(wall, 1e-9), wall,
+                               metrics=metrics)
 
         cache_key = (batch, num_batches, mode, self.shuffle_per_iter,
                      n if mode == "stochastic" else None, pspecs is not None,

@@ -1,0 +1,146 @@
+"""The functions of ``ops/sparse_attention.py`` and ``ops/grouped_matmul.py``
+against the plain ``jnp`` references beside them, forward and backward, at
+toy sizes (the pallas kernels in interpret mode)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparkflow_tpu.ops import grouped_matmul as gm
+from sparkflow_tpu.ops import sparse_attention as sa
+
+S = 32
+
+
+def _close_grads(f, g, args, atol):
+    for a, b in zip(jax.grad(f, argnums=tuple(range(len(args))))(*args),
+                    jax.grad(g, argnums=tuple(range(len(args))))(*args)):
+        np.testing.assert_allclose(a, b, atol=atol)
+
+
+@pytest.mark.parametrize("topk", [4, 8, 31])
+def test_index_select_keeps_each_querys_topk(topk):
+    r = np.random.default_rng(topk)
+    qi = jnp.asarray(r.normal(size=(2, S, 2, 8)), jnp.float32)
+    ki = jnp.asarray(r.normal(size=(2, S, 8)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(2, S, 2)), jnp.float32)
+    # two heads' relu leaves many scores at exactly 0: ties, kept alike
+    np.testing.assert_array_equal(
+        sa.index_select(qi, ki, w, topk, block=8),
+        sa.index_select_reference(qi, ki, w, topk))
+    qi, ki = jnp.abs(qi), jnp.abs(ki)              # no two scores alike
+    got = sa.index_select(qi, ki, w, topk, block=8)
+    np.testing.assert_array_equal(
+        got, sa.index_select_reference(qi, ki, w, topk))
+    counts = np.asarray(got).sum(-1)
+    np.testing.assert_array_equal(
+        counts, np.broadcast_to(np.minimum(np.arange(S) + 1, topk),
+                                counts.shape))
+
+
+@pytest.mark.parametrize("k", [1, 5, 32])
+def test_kth_largest_by_bisection_is_the_sorted_rows(k):
+    x = np.random.default_rng(k).normal(size=(6, 32)).astype(np.float32)
+    x[0, :4] = 0.0
+    x[1, :3] = -0.0
+    keys = sa._sortable(jnp.asarray(x))
+    want = np.sort(np.asarray(keys), axis=-1)[:, -k]
+    np.testing.assert_array_equal(sa.kth_largest_key(keys, k), want)
+
+
+def _attention_inputs(seed, hq=4, hkv=2, seq=S, d=8, keep=0.4):
+    r = np.random.default_rng(seed)
+    q = jnp.asarray(r.normal(size=(2, hq, seq, d)), jnp.float32)
+    k = jnp.asarray(r.normal(size=(2, hkv, seq, d)), jnp.float32)
+    v = jnp.asarray(r.normal(size=(2, hkv, seq, d)), jnp.float32)
+    mask = np.tril(r.random((2, seq, seq)) < keep)
+    mask[:, np.arange(seq), np.arange(seq)] = True     # a key for every query
+    return q, k, v, jnp.asarray(mask, jnp.int8)
+
+
+@pytest.mark.parametrize("hq,hkv,block", [(4, 2, None), (4, 4, 16), (8, 1, 8)])
+def test_selected_attention_matches_its_reference(hq, hkv, block):
+    q, k, v, mask = _attention_inputs(hq * 10 + hkv, hq, hkv)
+    kernel = lambda q, k, v: sa.selected_attention(
+        q, k, v, mask, block_q=block, block_k=block)
+    plain = lambda q, k, v: sa.selected_attention_reference(q, k, v, mask)
+    (out, lse), (want, want_lse) = kernel(q, k, v), plain(q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-6)
+    tilt = jnp.asarray(np.random.default_rng(0).normal(size=out.shape),
+                       jnp.float32)
+    _close_grads(lambda *a: jnp.sum(kernel(*a)[0] * tilt),
+                 lambda *a: jnp.sum(plain(*a)[0] * tilt), (q, k, v), 5e-6)
+
+
+@pytest.mark.parametrize("block", [None, 16])
+def test_selected_probs_sum_to_one_over_each_selection(block):
+    q, k, v, mask = _attention_inputs(5)
+    _, lse = sa.selected_attention(q, k, v, mask)
+    got = sa.selected_probs(q, k, lse, mask, block_q=block, block_k=block)
+    np.testing.assert_allclose(
+        got, sa.selected_probs_reference(q, k, lse, mask), atol=1e-6)
+    np.testing.assert_allclose(jnp.sum(got, axis=-1), 1.0, atol=1e-5)
+    assert float(jnp.max(jnp.where(mask != 0, 0.0, got))) == 0.0
+
+
+def test_indexer_loss_matches_its_reference_forward_and_backward():
+    r = np.random.default_rng(8)
+    qi = jnp.asarray(r.normal(size=(2, S, 2, 8)), jnp.float32)
+    ki = jnp.asarray(r.normal(size=(2, S, 8)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(2, S, 2)), jnp.float32)
+    q, k, v, mask = _attention_inputs(9)
+    _, lse = sa.selected_attention(q, k, v, mask)
+    target = sa.selected_probs(q, k, lse, mask)
+    blocked = lambda *a: jnp.sum(sa.indexer_loss(*a, mask, target, block=8))
+    plain = lambda *a: jnp.sum(sa.indexer_loss_reference(*a, mask, target))
+    np.testing.assert_allclose(blocked(qi, ki, w), plain(qi, ki, w),
+                               rtol=1e-6)
+    assert float(blocked(qi, ki, w)) > 0
+    _close_grads(blocked, plain, (qi, ki, w), 1e-6)
+
+
+def _routed(seed, n=24, e=8, k=2, first=2, held=4, h=16, m=8):
+    r = np.random.default_rng(seed)
+    logits = jnp.asarray(r.normal(size=(n, e)), jnp.float32)
+    _, gates, experts = gm.route_top_k(logits, k)
+    x = jnp.asarray(r.normal(size=(n, h)), jnp.float32)
+    w = [jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+         for s in ((held, h, m), (held, h, m), (held, m, h))]
+    return x, gates, experts, w, first
+
+
+@pytest.mark.parametrize("tile", [8, 16])
+def test_grouped_matmul_matches_its_reference(tile):
+    x, gates, experts, (w1, _, _), first = _routed(tile)
+    lay = gm.group_rows(experts, first, w1.shape[0], tile)
+    xe = gm.dispatch(x, lay.token_of_row, lay.row_of_pair)
+    live = gm.live_rows(xe.shape[0], lay.tiles_used, tile)
+    args = (lay.tile_expert, lay.tiles_used, tile)
+    kernel = lambda a, b: jnp.where(live, gm.grouped_matmul(a, b, *args), 0)
+    plain = lambda a, b: gm.grouped_matmul_reference(a, b, *args)
+    np.testing.assert_allclose(kernel(xe, w1), plain(xe, w1), atol=1e-6)
+    assert int(lay.tiles_used[0]) < xe.shape[0] // tile   # tiles are skipped
+    # a skipped tile's rows of the input's gradient are undefined too
+    grads = lambda f: jax.grad(lambda a, b: jnp.sum(jnp.sin(f(a, b))),
+                               argnums=(0, 1))(xe, w1)
+    (dx, dw), (dx_want, dw_want) = grads(kernel), grads(plain)
+    np.testing.assert_allclose(jnp.where(live, dx, 0), dx_want, atol=2e-6)
+    np.testing.assert_allclose(dw, dw_want, atol=2e-6)
+
+
+@pytest.mark.parametrize("first,held", [(0, 8), (2, 4), (6, 2)])
+def test_dropless_experts_match_their_reference(first, held):
+    x, gates, experts, w, _ = _routed(first + held, first=first, held=held)
+    kernel = lambda x, g, *w: gm.dropless_experts(x, g, experts, *w, first,
+                                                  tile=8)[0]
+    plain = lambda x, g, *w: gm.dropless_experts_reference(x, g, experts, *w,
+                                                           first)
+    np.testing.assert_allclose(kernel(x, gates, *w), plain(x, gates, *w),
+                               atol=2e-6)
+    load = gm.dropless_experts(x, gates, experts, *w, first, tile=8)[1]
+    want = [(np.asarray(experts) == first + e).sum() for e in range(held)]
+    np.testing.assert_array_equal(load, want)
+    _close_grads(lambda *a: jnp.sum(jnp.sin(kernel(*a))),
+                 lambda *a: jnp.sum(jnp.sin(plain(*a))), (x, gates, *w), 5e-6)

@@ -192,3 +192,62 @@ def test_kernels_keep_their_names(kernel_texts, name):
                        kernel_texts[name], re.M)
     assert calls and all(re.search(r"flash_|paged_", c) for c in calls), calls
     assert any(name in c for c in calls), calls
+
+
+# -- the selected-key attention and the grouped expert product ----------------
+
+
+@pytest.fixture(scope="module")
+def sparse_texts(one_chip):
+    """Compiled text of the kernels ``sparse_moe_lm`` runs, at the widths of
+    ``chipbench/configs/keye-vl2-30b-a3b-ep8.json``: one row of 8192 tokens,
+    32 query heads over 4 KV heads of 128; 16 experts of 2048 x 768 over the
+    worst case's rows."""
+    from sparkflow_tpu.ops import grouped_matmul as G
+    from sparkflow_tpu.ops import sparse_attention as S
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    s = 8192
+    q = sd((1, 32, s, 128), jnp.bfloat16)
+    kv = sd((1, 4, s, 128), jnp.bfloat16)
+    mask = sd((1, s, s), jnp.int8)
+
+    def attend(q, k, v, mask):
+        def loss(q, k, v):
+            out, lse = S.selected_attention(q, k, v, mask, interpret=False)
+            return out.astype(jnp.float32).sum(), lse
+        (_, lse), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                             has_aux=True)(q, k, v)
+        return grads, S.selected_probs(q, k, lse, mask, interpret=False)
+
+    attention = _compile(attend, q, kv, kv, mask)
+    rows = G.rows_bound(s, 8, 16)
+    x = sd((rows, 2048), jnp.bfloat16)
+    w = sd((16, 2048, 768), jnp.bfloat16)
+    tiles = sd((rows // G.TILE,), jnp.int32)
+    used = sd((1,), jnp.int32)
+
+    def experts(x, w, tiles, used):
+        return jax.grad(lambda x, w: G.grouped_matmul(
+            x, w, tiles, used, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1))(x, w)
+
+    product = _compile(experts, x, w, tiles, used)
+    return {"sparse_attn_fwd": attention, "sparse_attn_bwd_dq": attention,
+            "sparse_attn_bwd_dkv": attention, "sparse_attn_probs": attention,
+            "expert_gmm": product, "expert_tgmm": product}
+
+
+@pytest.mark.parametrize("name", ["sparse_attn_fwd", "sparse_attn_bwd_dq",
+                                  "sparse_attn_bwd_dkv", "sparse_attn_probs",
+                                  "expert_gmm", "expert_tgmm"])
+def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
+    """Each kernel of ``ops/sparse_attention.py`` and ``ops/grouped_matmul.py``
+    compiles for the chip at the configuration's widths, and its ``name=`` is
+    inside the custom call's instruction name, where the benchmark's readers
+    look for it (``chipbench/trace_reads.py``)."""
+    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"",
+                       sparse_texts[name], re.M)
+    assert any(name in c for c in calls), calls
