@@ -2,12 +2,16 @@
 (query, key) pair, each query keeps its ``topk`` best keys, and the main
 attention runs over those keys only.
 
-- :func:`index_scores` / :func:`index_select`: the indexer's scores
-  ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) / sqrt(heads * dim)`` and
-  each query's ``topk`` largest among ``s <= t``, as a mask ``[B, S, S]``.
-  Computed a block of queries at a time (all scores of one row of tokens in
-  float32 are ``heads x S x S``); the ``topk``-th largest score of a query is
-  found by bisection on the scores' bits, exactly, with no sort.
+- :func:`index_select`: the indexer's scores ``I[t, s] = sum_j w[t, j]
+  relu(qI[t, j] . kI[s]) / sqrt(heads * dim)`` and each query's ``topk``
+  largest among ``s <= t``, as a mask ``[B, S, S]``: kernel ``index_select``.
+  The scores never leave VMEM: a block of queries makes its visible tiles
+  (:func:`_index_tile`; all scores of one row of tokens in float32 are
+  ``heads x S x S``) into sortable int32 keys in a scratch ``[block, S]``,
+  and at its last visible tile finds each query's ``topk``-th largest key
+  by bisection on the keys' bits, exactly, with no sort, then the earliest
+  keys among those equal to it (``lax.top_k``'s order), and writes the
+  selection once. Tiles above the diagonal are neither fetched nor computed.
 - :func:`selected_attention`: softmax attention of grouped query heads
   (``Hq`` query heads over ``Hkv`` key/value heads) under that mask: pallas
   kernels ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv``
@@ -19,9 +23,14 @@ attention runs over those keys only.
   heads and normalised to one (kernel ``sparse_attn_probs``), the target of
   the indexer's loss.
 - :func:`indexer_loss`: ``mean_t KL(P_t || softmax_{s in S_t} I[t, s])``,
-  differentiable in the indexer's ``qI``, ``kI`` and ``w``.
+  differentiable in the indexer's ``qI``, ``kI`` and ``w``: kernels
+  ``index_kl_fwd`` (an online logsumexp of the selected scores beside ``sum
+  p (log p - I)``), ``index_kl_bwd_dq`` and ``index_kl_bwd_dk`` (the same
+  tile made again, ``dI = g (softmax_sel(I) - p)``), in the shape of the
+  attention kernels.
 
-Each stands beside a plain ``jnp`` reference (``*_reference``).
+Each stands beside a plain ``jnp`` reference (``*_reference``;
+:func:`index_scores` is theirs); on a CPU the kernels run interpreted.
 """
 
 from __future__ import annotations
@@ -61,18 +70,50 @@ def _sortable(x):
     return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
-def kth_largest_key(keys, k: int):
-    """The ``k``-th largest of each row of ``keys [T, S]`` (int32), built
-    bit by bit from the top: 32 counts of a row, no sort."""
+def _score_keys(scores, causal):
+    """The scores as sortable keys, ``_INT_MIN`` off ``causal``; ``-0.0``
+    and ``0.0`` are one score."""
+    return jnp.where(causal, _sortable(scores + 0.0), _INT_MIN)
+
+
+def kth_largest_key(at_least, k: int, rows: int):
+    """The ``k``-th largest key (int32) of each of ``rows`` rows, as ``[rows,
+    1]``, built bit by bit from the top: 32 counts of a row, no sort.
+    ``at_least(c)`` counts each row's keys ``>= c [rows, 1]``. A row with
+    fewer than ``k`` keys gives ``_INT_MIN``."""
     def body(i, c):
         bit = 31 - i
         cand = jnp.where(bit == 31, jnp.zeros_like(c),
                          c | jnp.left_shift(jnp.int32(1), bit))
-        enough = jnp.sum(keys >= cand[:, None], axis=-1) >= k
-        return jnp.where(enough, cand, c)
+        return jnp.where(at_least(cand) >= k, cand, c)
 
-    start = jnp.full((keys.shape[0],), _INT_MIN, jnp.int32)
-    return jax.lax.fori_loop(0, 32, body, start)
+    return jax.lax.fori_loop(0, 32, body,
+                             jnp.full((rows, 1), _INT_MIN, jnp.int32))
+
+
+def _first_beyond(before, room, width: int):
+    """For each row the column ``J [rows, 1]`` such that exactly ``min(room,
+    flags in the row)`` flags lie before it, built bit by bit (a count a
+    bit: a cumulative sum along 8192 lanes costs more). ``before(c)`` counts
+    each row's flags in the columns ``< c [rows, 1]``, of ``width``."""
+    bits = int(width).bit_length()
+
+    def body(i, j):
+        cand = j | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        return jnp.where(before(cand) <= room, cand, j)
+
+    return jax.lax.fori_loop(0, bits, body, jnp.zeros_like(room))
+
+
+def _room(kth, above, topk: int):
+    """How many of the keys equal to its ``kth`` a query keeps, ``above``
+    being those beyond it. A query with fewer than ``topk`` keys has ``kth =
+    _INT_MIN``, which only the keys off its causal part equal: none."""
+    return jnp.where(kth == _INT_MIN, 0, topk - above)
+
+
+def _count(flags):
+    return jnp.sum(flags, axis=-1, keepdims=True, dtype=jnp.int32)
 
 
 def select_block(scores, first_row, topk: int):
@@ -80,46 +121,18 @@ def select_block(scores, first_row, topk: int):
     each query's ``topk`` largest among ``s <= t`` (all of them where there
     are no more than ``topk``). Of the scores equal to the last one kept,
     the earliest keys are kept, as ``lax.top_k`` orders them: a relu leaves
-    whole stretches of scores at exactly 0."""
-    t = first_row + jnp.arange(scores.shape[0], dtype=jnp.int32)[:, None]
-    causal = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :] <= t
-    if topk >= scores.shape[1]:
-        return causal
-    # -0.0 and 0.0 are one score
-    keys = jnp.where(causal, _sortable(scores + 0.0), _INT_MIN)
-    kth = kth_largest_key(keys, topk)[:, None]
-    above = keys > kth
-    tied = causal & (keys == kth)
-    room = topk - jnp.sum(above, axis=-1)
-    return above | (tied & (_cols(scores) < _first_beyond(tied, room)[:, None]))
-
-
-def _cols(a):
-    return jnp.arange(a.shape[1], dtype=jnp.int32)[None, :]
-
-
-def _first_beyond(flags, room):
-    """For each row of ``flags [T, S]`` the column ``J`` such that exactly
-    ``min(room, flags in the row)`` flags lie before it, built bit by bit (a
-    count a bit: a cumulative sum along 8192 lanes costs more)."""
-    cols = _cols(flags)
-    bits = int(flags.shape[1]).bit_length()
-
-    def body(i, j):
-        cand = j | jnp.left_shift(jnp.int32(1), bits - 1 - i)
-        fits = jnp.sum(flags & (cols < cand[:, None]), axis=-1) <= room
-        return jnp.where(fits, cand, j)
-
-    return jax.lax.fori_loop(0, bits, body,
-                             jnp.zeros((flags.shape[0],), jnp.int32))
-
-
-def _blocks_of(a, block: int):
-    """``a [S, ...]`` as ``[S / block, block, ...]``; one block where
-    ``block`` does not divide ``S``."""
-    s = a.shape[0]
-    qb = block if block and s % block == 0 else s
-    return a.reshape((s // qb, qb) + a.shape[1:])
+    whole stretches of scores at exactly 0. Plain ``jnp`` over whole rows;
+    the kernel ``index_select`` runs the same functions over its tiles."""
+    rows, width = scores.shape
+    t = first_row + jnp.arange(rows, dtype=jnp.int32)[:, None]
+    cols = jnp.arange(width, dtype=jnp.int32)[None, :]
+    causal = cols <= t
+    keys = _score_keys(scores, causal)
+    kth = kth_largest_key(lambda c: _count(keys >= c), topk, rows)
+    tied = keys == kth
+    beyond = _first_beyond(lambda c: _count(tied & (cols < c)),
+                           _room(kth, _count(keys > kth), topk), width)
+    return (keys > kth) | (tied & (cols < beyond))
 
 
 def _kl_rows(scores, sel, p):
@@ -131,20 +144,13 @@ def _kl_rows(scores, sel, p):
 
 def index_select(qi, ki, w, topk: int, block: int = 256):
     """``qi [B, S, nh, d]``, ``ki [B, S, d]``, ``w [B, S, nh]`` -> the
-    selection as ``int8 [B, S, S]`` (1 = query ``t`` attends key ``s``).
-    No gradient: the selection is discrete."""
+    selection as ``int8 [B, S, S]`` (1 = query ``t`` attends key ``s``):
+    kernel ``index_select``, ``block`` queries a grid step (all of them
+    where ``block`` does not divide ``S``). No gradient: the selection is
+    discrete."""
     qi, ki, w = jax.lax.stop_gradient((qi, ki, w))
-
-    def row(qi_r, ki_r, w_r):
-        s = qi_r.shape[0]
-        qs, ws = _blocks_of(qi_r, block), _blocks_of(w_r, block)
-        firsts = jnp.arange(0, s, qs.shape[1], dtype=jnp.int32)
-        out = jax.lax.map(
-            lambda a: select_block(index_scores(a[0], ki_r, a[1]), a[2],
-                                   topk).astype(jnp.int8), (qs, ws, firsts))
-        return out.reshape(s, s)
-
-    return jax.vmap(row)(qi, ki, w)
+    return _select(*_index_layout(qi, ki, w), topk,
+                   *_index_blocks(qi.shape[1], block))
 
 
 def index_select_reference(qi, ki, w, topk: int):
@@ -165,21 +171,14 @@ def index_select_reference(qi, ki, w, topk: int):
 def indexer_loss(qi, ki, w, mask, target, block: int = 256):
     """``mean_t KL(target[t] || softmax_{s: mask[t, s]} I[t, s])`` of each
     row of tokens ``[B]``. ``target [B, S, S]`` (rows summing to one over the
-    selection) carries no gradient; ``qi``, ``ki``, ``w`` do. A block of
-    queries at a time, recomputed in the backward pass."""
-    target = jax.lax.stop_gradient(target)
-
-    def row(qi_r, ki_r, w_r, mask_r, target_r):
-        @jax.checkpoint
-        def blk(ki_r, a):
-            q, ww, m, p = a
-            return _kl_rows(index_scores(q, ki_r, ww), m != 0, p)
-
-        kl = jax.lax.map(functools.partial(blk, ki_r), tuple(
-            _blocks_of(a, block) for a in (qi_r, w_r, mask_r, target_r)))
-        return jnp.mean(kl)
-
-    return jax.vmap(row)(qi, ki, w, mask, target)
+    selection) carries no gradient; ``qi``, ``ki``, ``w`` do: kernel
+    ``index_kl_fwd`` and, backward, ``index_kl_bwd_dq`` and
+    ``index_kl_bwd_dk``, which make the scores' tiles again. ``block``
+    queries a grid step, as in :func:`index_select`."""
+    kl = _index_kl(*_index_layout(qi, ki, w), mask.astype(jnp.int8),
+                   jax.lax.stop_gradient(target).astype(jnp.float32),
+                   *_index_blocks(qi.shape[1], block))
+    return jnp.mean(kl, axis=-1)
 
 
 def indexer_loss_reference(qi, ki, w, mask, target):
@@ -544,3 +543,313 @@ def selected_probs(q, k, lse, mask, sm_scale: Optional[float] = None,
                                 "arbitrary"),
         interpret=interpret,
     )(q, k, lse[..., None], mask.astype(jnp.int8))
+
+
+# ---------------------------------------------------------------------------
+# the indexer's kernels: a tile of scores, made and consumed in VMEM
+# ---------------------------------------------------------------------------
+
+# queries whose bisections run together: their counts stay in registers
+_SELECT_ROWS = 128
+
+
+def _index_blocks(s: int, block: int):
+    """``(block_q, block_k, interpret)`` of the indexer's kernels: ``block``
+    queries a grid step, all of them where it does not divide ``s``."""
+    return (block if block and s % block == 0 else s, _block(s),
+            jax.default_backend() != "tpu")
+
+
+def _index_specs(nh, d, block_q, block_k, order="qk"):
+    """:func:`_specs` for the indexer's one key head: ``qI [B, nh, S, d]``,
+    ``kI [B, S, d]``, ``w [B, nh, S, 1]``, a tile of ``[B, S, S]`` and a
+    statistic of a query ``[B, 1, S, 1]``."""
+    return _specs(nh, block_q, block_k, d, 1, order) + (
+        _specs(1, block_q, block_k, d, 1, order)[2],)
+
+
+def _index_layout(qi, ki, w):
+    """``qi``, ``ki``, ``w`` as the kernels take them: ``[B, nh, S, d]``,
+    ``[B, S, d]`` and float32 ``[B, nh, S, 1]``."""
+    return (jnp.transpose(qi, (0, 2, 1, 3)), ki,
+            jnp.transpose(w.astype(jnp.float32), (0, 2, 1))[..., None])
+
+
+def _index_tile(q_ref, k, w_ref, relus_ref=None):
+    """One tile of the index scores, ``I [bq, bk]`` float32, from the blocks
+    ``q_ref [1, nh, bq, d]`` and ``w_ref [1, nh, bq, 1]`` and ``k [bk, d]``:
+    each head's dots on the MXU with float32 accumulation; relu, weights and
+    the sum over the heads, in their order, in float32. ``relus_ref [nh, bq,
+    bk]`` keeps each head's relu for a backward pass."""
+    nh, _, d = q_ref.shape[1:]
+    total = None
+    for j in range(nh):
+        r = jnp.maximum(jax.lax.dot_general(
+            q_ref[0, j], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), 0.0)
+        if relus_ref is not None:
+            relus_ref[j] = r
+        term = r * w_ref[0, j]
+        total = term if total is None else total + term
+    return total / math.sqrt(nh * d)
+
+
+def _chunk(c, block_k):
+    return pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+
+
+def _select_kernel(q_ref, k_ref, w_ref, sel_ref, keys_ref, *, topk, block_q,
+                   block_k, rows):
+    qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    last = _last_tile(qi, block_q, block_k)
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    @pl.when(ki <= last)
+    def _scores():
+        t = qi * block_q + iota((block_q, block_k), 0)
+        cols = ki * block_k + iota((block_q, block_k), 1)
+        scores = _index_tile(q_ref, k_ref[0], w_ref)
+        keys_ref[:, _chunk(ki, block_k)] = _score_keys(scores, cols <= t)
+
+    @pl.when(ki == last)
+    def _select():
+        def unseen(c, _):
+            sel_ref[0, :, _chunk(c, block_k)] = jnp.zeros(
+                (block_q, block_k), jnp.int8)
+            return 0
+
+        jax.lax.fori_loop(last + 1, nk, unseen, 0)
+        # a count adds 128 lanes at a time and crosses them once, at its end
+        lanes = 128 if block_k % 128 == 0 else block_k
+
+        def group(g, _):
+            mine = pl.ds(pl.multiple_of(g * rows, rows), rows)
+            lane = iota((rows, block_k), 1)
+
+            def count(flags):
+                """``flags(keys, cols)`` counted over the visible keys."""
+                def body(c, acc):
+                    f = flags(keys_ref[mine, _chunk(c, block_k)],
+                              c * block_k + lane).astype(jnp.int32)
+                    return acc + sum(f[:, i:i + lanes]
+                                     for i in range(0, block_k, lanes))
+
+                return _count(jax.lax.fori_loop(
+                    0, last + 1, body, jnp.zeros((rows, lanes), jnp.int32)))
+
+            kth = kth_largest_key(
+                lambda c: count(lambda keys, cols: keys >= c), topk, rows)
+            beyond = _first_beyond(
+                lambda c: count(lambda keys, cols:
+                                (keys == kth) & (cols < c)),
+                _room(kth, count(lambda keys, cols: keys > kth), topk),
+                sel_ref.shape[2])
+
+            def write(c, _):
+                keys = keys_ref[mine, _chunk(c, block_k)]
+                cols = c * block_k + lane
+                kept = (keys > kth) | ((keys == kth) & (cols < beyond))
+                sel_ref[0, mine, _chunk(c, block_k)] = kept.astype(jnp.int8)
+                return 0
+
+            return jax.lax.fori_loop(0, last + 1, write, 0)
+
+        jax.lax.fori_loop(0, block_q // rows, group, 0)
+
+
+def _select(q, k, w, topk, block_q, block_k, interpret):
+    b, nh, s, d = q.shape
+    qspec, kspec, wspec, _, _ = _index_specs(nh, d, block_q, block_k)
+    rows = _SELECT_ROWS if block_q % _SELECT_ROWS == 0 else block_q
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, block_q=block_q,
+                          block_k=block_k, rows=rows),
+        name="index_select",
+        grid=(b, s // block_q, s // block_k),
+        in_specs=[qspec, kspec, wspec],
+        out_specs=pl.BlockSpec((1, block_q, s), lambda bi, qi, ki: (bi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((block_q, s), jnp.int32)],
+        compiler_params=_params(interpret, "parallel", "parallel",
+                                "arbitrary"),
+        interpret=interpret,
+    )(q, k, w)
+
+
+def _kl_fwd_kernel(q_ref, k_ref, w_ref, mask_ref, p_ref, kl_ref, lse_ref,
+                   mass_ref, m_ref, l_ref, a_ref, *, block_q, block_k):
+    qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        a_ref[...] = jnp.zeros_like(a_ref)
+        mass_ref[...] = jnp.zeros_like(mass_ref)
+
+    @pl.when(_visible(qi, ki, block_q, block_k))
+    def _compute():
+        scores = _index_tile(q_ref, k_ref[0], w_ref)
+        sel, p = mask_ref[0] != 0, p_ref[0]
+        s = jnp.where(sel, scores, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        l_ref[...] = jnp.exp(m_prev - m_new) * l_ref[...] + rowsum(
+            jnp.where(sel, jnp.exp(s - m_new), 0.0))
+        m_ref[...] = m_new
+        logp = jnp.log(jnp.where(p > 0, p, 1.0))
+        a_ref[...] += rowsum(jnp.where(sel, p * (logp - scores), 0.0))
+        mass_ref[0, 0] += rowsum(jnp.where(sel, p, 0.0))
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        # sum p (log p - I + lse), the logsumexp known only now
+        lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0, 0] = lse
+        kl_ref[0, 0] = a_ref[...] + lse * mass_ref[0, 0]
+
+
+def _kl_dtile(q_ref, k, w_ref, mask_ref, p_ref, lse_ref, mass_ref, g_ref,
+              relus_ref):
+    """The scores' tile again and ``g dKL / d(sum over the heads)`` of it:
+    ``g (mass softmax_sel(I) - p)`` over the scale :func:`_index_tile`
+    divides by. Leaves each head's relu in ``relus_ref``."""
+    nh, d = q_ref.shape[1], q_ref.shape[3]
+    scores = _index_tile(q_ref, k, w_ref, relus_ref)
+    sel = mask_ref[0] != 0
+    soft = jnp.exp(jnp.where(sel, scores, NEG_INF) - lse_ref[0, 0])
+    return jnp.where(sel, g_ref[0, 0] * (mass_ref[0, 0] * soft - p_ref[0]),
+                     0.0) / math.sqrt(nh * d)
+
+
+def _kl_bwd_dq_kernel(q_ref, k_ref, w_ref, mask_ref, p_ref, lse_ref, mass_ref,
+                      g_ref, dq_ref, dw_ref, dq_acc, dw_acc, relus_ref, *,
+                      block_q, block_k):
+    qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(_visible(qi, ki, block_q, block_k))
+    def _compute():
+        k = k_ref[0]
+        di = _kl_dtile(q_ref, k, w_ref, mask_ref, p_ref, lse_ref, mass_ref,
+                       g_ref, relus_ref)
+        for j in range(relus_ref.shape[0]):
+            r = relus_ref[j]
+            dw_acc[j] += jnp.sum(di * r, axis=1, keepdims=True)
+            ddots = jnp.where(r > 0, di * w_ref[0, j], 0.0)
+            dq_acc[j] += jax.lax.dot_general(
+                ddots.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[0] = dw_acc[...]
+
+
+def _kl_bwd_dk_kernel(q_ref, k_ref, w_ref, mask_ref, p_ref, lse_ref, mass_ref,
+                      g_ref, dk_ref, dk_acc, relus_ref, *, block_q, block_k):
+    ki, qi, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+
+    @pl.when(_visible(qi, ki, block_q, block_k))
+    def _compute():
+        di = _kl_dtile(q_ref, k_ref[0], w_ref, mask_ref, p_ref, lse_ref,
+                       mass_ref, g_ref, relus_ref)
+        for j in range(relus_ref.shape[0]):
+            q = q_ref[0, j]
+            ddots = jnp.where(relus_ref[j] > 0, di * w_ref[0, j], 0.0)
+            dk_acc[...] += jax.lax.dot_general(
+                ddots.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+
+
+def _kl_forward(q, k, w, mask, target, block_q, block_k, interpret):
+    b, nh, s, d = q.shape
+    qspec, kspec, wspec, tile, stat = _index_specs(nh, d, block_q, block_k)
+    row = jax.ShapeDtypeStruct((b, 1, s, 1), jnp.float32)
+    kl, lse, mass = pl.pallas_call(
+        functools.partial(_kl_fwd_kernel, block_q=block_q, block_k=block_k),
+        name="index_kl_fwd",
+        grid=(b, s // block_q, s // block_k),
+        in_specs=[qspec, kspec, wspec, tile, tile],
+        out_specs=(stat, stat, stat),
+        out_shape=(row, row, row),
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32)] * 3,
+        compiler_params=_params(interpret, "parallel", "parallel",
+                                "arbitrary"),
+        interpret=interpret,
+    )(q, k, w, mask, target)
+    return kl.reshape(b, s), lse, mass
+
+
+def _kl_backward(q, k, w, mask, target, lse, mass, g, block_q, block_k,
+                 interpret):
+    b, nh, s, d = q.shape
+    args = (q, k, w, mask, target, lse, mass,
+            g.astype(jnp.float32).reshape(b, 1, s, 1))
+    relus = pltpu.VMEM((nh, block_q, block_k), jnp.float32)
+    qspec, kspec, wspec, tile, stat = _index_specs(nh, d, block_q, block_k)
+    dq, dw = pl.pallas_call(
+        functools.partial(_kl_bwd_dq_kernel, block_q=block_q,
+                          block_k=block_k),
+        name="index_kl_bwd_dq",
+        grid=(b, s // block_q, s // block_k),
+        in_specs=[qspec, kspec, wspec, tile, tile, stat, stat, stat],
+        out_specs=(qspec, wspec),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(w.shape, w.dtype)),
+        scratch_shapes=[pltpu.VMEM((nh, block_q, d), jnp.float32),
+                        pltpu.VMEM((nh, block_q, 1), jnp.float32), relus],
+        compiler_params=_params(interpret, "parallel", "parallel",
+                                "arbitrary"),
+        interpret=interpret,
+    )(*args)
+    qspec, kspec, wspec, tile, stat = _index_specs(nh, d, block_q, block_k,
+                                                   "kq")
+    dk = pl.pallas_call(
+        functools.partial(_kl_bwd_dk_kernel, block_q=block_q,
+                          block_k=block_k),
+        name="index_kl_bwd_dk",
+        grid=(b, s // block_k, s // block_q),
+        in_specs=[qspec, kspec, wspec, tile, tile, stat, stat, stat],
+        out_specs=kspec,
+        out_shape=jax.ShapeDtypeStruct(k.shape, k.dtype),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32), relus],
+        compiler_params=_params(interpret, "parallel", "parallel",
+                                "arbitrary"),
+        interpret=interpret,
+    )(*args)
+    return dq, dk, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _index_kl(q, k, w, mask, target, block_q, block_k, interpret):
+    """Each query's ``KL(target || softmax over mask of I)``, ``[B, S]``,
+    in the layout of :func:`_index_layout`."""
+    return _kl_forward(q, k, w, mask, target, block_q, block_k, interpret)[0]
+
+
+def _index_kl_fwd(q, k, w, mask, target, block_q, block_k, interpret):
+    kl, lse, mass = _kl_forward(q, k, w, mask, target, block_q, block_k,
+                                interpret)
+    return kl, (q, k, w, mask, target, lse, mass)
+
+
+def _index_kl_bwd(block_q, block_k, interpret, res, g):
+    return _kl_backward(*res, g, block_q, block_k, interpret) + (None, None)
+
+
+_index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)

@@ -46,7 +46,74 @@ def test_kth_largest_by_bisection_is_the_sorted_rows(k):
     x[1, :3] = -0.0
     keys = sa._sortable(jnp.asarray(x))
     want = np.sort(np.asarray(keys), axis=-1)[:, -k]
-    np.testing.assert_array_equal(sa.kth_largest_key(keys, k), want)
+    got = sa.kth_largest_key(lambda c: sa._count(keys >= c), k, 6)
+    np.testing.assert_array_equal(got[:, 0], want)
+
+
+def _indexer_inputs(seed, rows, seq, scores):
+    """``qi, ki, w`` of ``rows`` rows of ``seq`` tokens. ``scores``:
+    ``relu`` (two heads' relu leaves many scores at exactly 0: ties),
+    ``distinct`` (no two scores alike) or ``zeros`` (a long stretch of keys
+    whose scores are exactly 0 for every query, ``-0.0`` for the odd queries,
+    whose other scores all lie below: the earliest keys win)."""
+    r = np.random.default_rng(seed)
+    qi = r.normal(size=(rows, seq, 2, 8)).astype(np.float32)
+    ki = r.normal(size=(rows, seq, 8)).astype(np.float32)
+    w = r.normal(size=(rows, seq, 2)).astype(np.float32)
+    if scores != "relu":
+        qi, ki = np.abs(qi), np.abs(ki)
+    if scores == "zeros":
+        ki[:, seq // 4:3 * seq // 4] *= -1
+        w[:, 1::2] = -np.abs(w[:, 1::2])
+    return jnp.asarray(qi), jnp.asarray(ki), jnp.asarray(w)
+
+
+# (rows, S, block, topk, scores): block 8 of 32 is four query blocks over one
+# tile of keys; 64 of 256 is four over two tiles of 128
+SELECT_CASES = {
+    "topk-below-S": (1, 32, 8, 5, "relu"),
+    "topk-is-S": (1, 32, 8, 32, "relu"),
+    "topk-above-S": (1, 32, 8, 64, "relu"),
+    "S-not-a-multiple-of-block": (1, 40, 16, 7, "relu"),
+    "two-rows": (2, 32, 8, 6, "distinct"),
+    "block-straddles-topk": (1, 32, 8, 12, "distinct"),
+    "block-ends-at-topk": (1, 32, 8, 16, "distinct"),
+    "zeros-and-minus-zeros": (2, 32, 8, 6, "zeros"),
+    "two-key-tiles": (1, 256, 64, 40, "relu"),
+    "two-key-tiles-zeros": (1, 256, 64, 96, "zeros"),
+    "two-key-tiles-straddled": (2, 256, 64, 100, "distinct"),
+}
+
+
+@pytest.mark.parametrize("case", list(SELECT_CASES))
+def test_index_select_kernel_is_the_references_selection(case):
+    rows, seq, block, topk, scores = SELECT_CASES[case]
+    qi, ki, w = _indexer_inputs(len(case), rows, seq, scores)
+    got = np.asarray(sa.index_select(qi, ki, w, topk, block=block))
+    np.testing.assert_array_equal(
+        got, sa.index_select_reference(qi, ki, w, topk))
+    np.testing.assert_array_equal(
+        got.sum(-1), np.broadcast_to(np.minimum(np.arange(seq) + 1, topk),
+                                     (rows, seq)))
+    if scores == "zeros":
+        # the last query, an odd one, keeps the first of the zeros and
+        # nothing else
+        np.testing.assert_array_equal(
+            got[0, seq - 1].nonzero()[0], seq // 4 + np.arange(topk))
+
+
+def test_select_block_holds_minus_zero_and_zero_for_one_score():
+    """In one row: ``-0.0`` before ``0.0`` before ``-0.0``, all the
+    largest; the earliest are kept whatever their sign."""
+    x = -np.abs(np.random.default_rng(0).normal(size=(4, 16))).astype(
+        np.float32)
+    x[:, 2:5], x[:, 7:9], x[:, 11:14] = -0.0, 0.0, -0.0
+    got = sa.select_block(jnp.asarray(x), 12, 5)
+    want = np.zeros((4, 16), bool)
+    want[:, [2, 3, 4, 7, 8]] = True
+    np.testing.assert_array_equal(got, want)
+    idx = jax.lax.top_k(jnp.asarray(x), 5)[1]
+    np.testing.assert_array_equal(np.sort(idx, axis=-1)[0], [2, 3, 4, 7, 8])
 
 
 def _attention_inputs(seed, hq=4, hkv=2, seq=S, d=8, keep=0.4):
@@ -85,19 +152,38 @@ def test_selected_probs_sum_to_one_over_each_selection(block):
     assert float(jnp.max(jnp.where(mask != 0, 0.0, got))) == 0.0
 
 
-def test_indexer_loss_matches_its_reference_forward_and_backward():
-    r = np.random.default_rng(8)
-    qi = jnp.asarray(r.normal(size=(2, S, 2, 8)), jnp.float32)
-    ki = jnp.asarray(r.normal(size=(2, S, 8)), jnp.float32)
-    w = jnp.asarray(r.normal(size=(2, S, 2)), jnp.float32)
-    q, k, v, mask = _attention_inputs(9)
+# (S, block, topk, the target's keys set to zero)
+LOSS_CASES = {
+    "toy": (32, 8, 0, None),
+    "fewer-than-topk-and-a-target-zero-in-part": (32, 8, 12, slice(4, 20)),
+    "S-not-a-multiple-of-block": (40, 16, 9, None),
+    "two-key-tiles": (256, 64, 48, slice(100, 160)),
+}
+
+
+@pytest.mark.parametrize("case", list(LOSS_CASES))
+def test_indexer_loss_matches_its_reference_forward_and_backward(case):
+    """The selection is the indexer's own where ``topk`` is given (early
+    queries then hold fewer than ``topk`` keys), else a random one."""
+    seq, block, topk, zeroed = LOSS_CASES[case]
+    qi, ki, w = _indexer_inputs(8, 2, seq, "relu")
+    q, k, v, mask = _attention_inputs(9, seq=seq)
+    if topk:
+        mask = sa.index_select(qi, ki, w, topk, block=block)
     _, lse = sa.selected_attention(q, k, v, mask)
     target = sa.selected_probs(q, k, lse, mask)
-    blocked = lambda *a: jnp.sum(sa.indexer_loss(*a, mask, target, block=8))
-    plain = lambda *a: jnp.sum(sa.indexer_loss_reference(*a, mask, target))
-    np.testing.assert_allclose(blocked(qi, ki, w), plain(qi, ki, w),
-                               rtol=1e-6)
-    assert float(blocked(qi, ki, w)) > 0
+    if zeroed is not None:
+        target = target.at[:, :, zeroed].set(0.0)
+    tilt = jnp.asarray([1.0, -0.5])
+    blocked = lambda *a: jnp.sum(
+        tilt * sa.indexer_loss(*a, mask, target, block=block))
+    plain = lambda *a: jnp.sum(
+        tilt * sa.indexer_loss_reference(*a, mask, target))
+    np.testing.assert_allclose(
+        sa.indexer_loss(qi, ki, w, mask, target, block=block),
+        sa.indexer_loss_reference(qi, ki, w, mask, target), rtol=1e-6)
+    assert float(sa.indexer_loss(qi, ki, w, mask, target,
+                                 block=block)[0]) > 0
     _close_grads(blocked, plain, (qi, ki, w), 1e-6)
 
 

@@ -234,14 +234,35 @@ def sparse_texts(one_chip):
             argnums=(0, 1))(x, w)
 
     product = _compile(experts, x, w, tiles, used)
+    qi = sd((1, s, 16, 64), jnp.bfloat16)
+    ki = sd((1, s, 64), jnp.bfloat16)
+    wi = sd((1, s, 16), jnp.bfloat16)
+    target = sd((1, s, s), jnp.float32)
+
+    def index(qi, ki, wi, target):
+        """The model's calls, with the kernels compiled and not interpreted
+        (``index_select`` and ``indexer_loss`` ask the backend, a CPU
+        here)."""
+        layout = S._index_layout(qi, ki, wi)
+        sel = S._select(*layout, 2048, 256, 512, False)
+        grads = jax.grad(lambda *a: S._index_kl(
+            *a, sel, target, 256, 512, False).sum(), argnums=(0, 1, 2))(
+                *layout)
+        return sel, grads
+
+    indexer = _compile(index, qi, ki, wi, target)
     return {"sparse_attn_fwd": attention, "sparse_attn_bwd_dq": attention,
             "sparse_attn_bwd_dkv": attention, "sparse_attn_probs": attention,
-            "expert_gmm": product, "expert_tgmm": product}
+            "expert_gmm": product, "expert_tgmm": product,
+            "index_select": indexer, "index_kl_fwd": indexer,
+            "index_kl_bwd_dq": indexer, "index_kl_bwd_dk": indexer}
 
 
 @pytest.mark.parametrize("name", ["sparse_attn_fwd", "sparse_attn_bwd_dq",
                                   "sparse_attn_bwd_dkv", "sparse_attn_probs",
-                                  "expert_gmm", "expert_tgmm"])
+                                  "expert_gmm", "expert_tgmm", "index_select",
+                                  "index_kl_fwd", "index_kl_bwd_dq",
+                                  "index_kl_bwd_dk"])
 def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
     """Each kernel of ``ops/sparse_attention.py`` and ``ops/grouped_matmul.py``
     compiles for the chip at the configuration's widths, and its ``name=`` is
