@@ -12,7 +12,6 @@ COPY pyproject.toml README.md ./
 COPY sparkflow_tpu ./sparkflow_tpu
 COPY tests ./tests
 COPY examples ./examples
-COPY bench.py bench_baseline.py BASELINE_MEASURED.json ./
 
 RUN pip install --no-cache-dir "jax[cpu]" optax orbax-checkpoint chex dill pytest \
     && pip install --no-cache-dir -e .

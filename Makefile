@@ -1,7 +1,7 @@
 # Developer entry points (role parity with the reference's Makefile:1-17,
 # which ran the examples and tests in Docker).
 
-.PHONY: test test-fast chip-smoke chip-rehearse test-pyspark docker-test-pyspark bench bench-ladder mfu-sweep baseline examples native clean serve-smoke sim-smoke fleet-smoke chaos-smoke lint-graft lint-graft-strict obs-smoke span-overhead elastic-smoke decode-smoke spec-smoke tp-smoke pp-smoke zero-smoke race-smoke swap-smoke kvquant-smoke scale-smoke trace-smoke
+.PHONY: test test-fast chip-smoke chip-rehearse test-pyspark docker-test-pyspark examples native clean serve-smoke sim-smoke fleet-smoke chaos-smoke lint-graft lint-graft-strict obs-smoke elastic-smoke decode-smoke spec-smoke tp-smoke pp-smoke zero-smoke race-smoke swap-smoke kvquant-smoke scale-smoke trace-smoke
 
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q
@@ -23,21 +23,6 @@ chip-rehearse:
 test-pyspark:
 	pip install "pyspark>=3.4"
 	python -m pytest tests/test_pyspark_e2e.py -v
-
-bench:
-	python bench.py
-
-bench-quick:
-	python bench.py --quick
-
-bench-ladder:
-	python benchmarks/run_all.py
-
-mfu-sweep:
-	python benchmarks/mfu_sweep.py
-
-baseline:
-	python bench_baseline.py
 
 examples:
 	cd examples && PYTHONPATH="..:$$PYTHONPATH" SPARKFLOW_TPU_SMOKE=1 python simple_dnn.py && \
@@ -92,32 +77,28 @@ decode-smoke:
 # speculative-decode smoke: the decode test suite, then a real server
 # subprocess with speculation on — a mixed-length greedy burst must be
 # token-identical to spec-off decode, zero steady-state retraces, clean
-# SIGTERM drain; finishes with the spec-on/off benchmark (docs/serving.md)
+# SIGTERM drain (docs/serving.md)
 spec-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_decode.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/spec_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --spec-decode
 
 # tensor-parallel serving smoke: the decode test suite, then a real server
 # subprocess hosting a tp=2 mesh-sharded engine (spec decode + prefix cache
 # on) — a concurrent mixed-length greedy burst must be token-identical to a
-# tp=1 engine, zero steady-state retraces, clean SIGTERM drain; finishes
-# with the tp=1 vs tp=2 decode benchmark (docs/serving.md)
+# tp=1 engine, zero steady-state retraces, clean SIGTERM drain
+# (docs/serving.md)
 tp-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_decode.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/tp_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --tp-decode
 
 # subprocess hosting a pp=2 stage-sharded engine (staged spec decode +
 # prefix cache + chunked prefill on) — a concurrent mixed-length greedy
 # burst must be token-identical to a pp=1 engine on both staged schedules
 # (single-wave and micro-token wave), zero steady-state retraces, clean
-# SIGTERM drain; finishes with the wave-vs-single-wave decode benchmark
-# (docs/serving.md)
+# SIGTERM drain (docs/serving.md)
 pp-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_decode.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/pp_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --pp-decode
 
 # chaos suite: deterministic fault injection against checkpoints, resume,
 # coordinator joins, and serving drain (docs/resilience.md)
@@ -125,18 +106,15 @@ chaos-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_resilience.py -q
 
 # elastic bounded-staleness DP chaos suite (virtual-time stragglers,
-# preemption, lease expiry) plus the sync-vs-elastic straggler benchmark
+# preemption, lease expiry)
 elastic-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_elastic.py -q
-	JAX_PLATFORMS=cpu python bench.py --elastic-straggler
 
 # ZeRO stage sweep: the sharding test suite, then a stage 0->3 parity +
-# checkpoint-interchange sweep and the two zero benches (docs/sharding.md)
+# checkpoint-interchange sweep (docs/sharding.md)
 zero-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_zero_sharding.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/zero_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --dp-zero2
-	JAX_PLATFORMS=cpu python bench.py --dp-zero3
 
 # graftcheck: sharding / tracing / concurrency lint over the repo's own
 # source + the jaxpr self-check over presets x optimizers (docs/analysis.md)
@@ -160,64 +138,51 @@ race-smoke:
 # publish, hot swap, canary gate, lock/race lints), then a real server
 # subprocess hot-swapping weights mid-burst — one good publish (healthz
 # version flips exactly once) and one corrupted publish (invisible to
-# clients, last-good kept) with zero failures and a clean SIGTERM drain;
-# finishes with the hot-swap inter-token latency benchmark (docs/serving.md)
+# clients, last-good kept) with zero failures and a clean SIGTERM drain
+# (docs/serving.md)
 swap-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_weightstore.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/swap_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --hot-swap
 
 # quantized-KV smoke: the int8/fp8 pool battery (kernel dequant parity,
 # running-scale appends, churn neutrality, composition parity), a
 # real-server int8 smoke (16 concurrent mixed-length greedy generations
 # with spec k=3 + prefix cache + chunked prefill, token-identical to
 # full-precision decode, healthz advertising the pool layout, clean
-# SIGTERM drain), then the capacity/parity/overload benchmark
-# (docs/serving.md)
+# SIGTERM drain) (docs/serving.md)
 kvquant-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_kvquant.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/kvquant_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --kv-quant
 
 # elastic autoscaling smoke: the autoscaler test battery (policy units,
 # sim step response, live control loop, real-subprocess supervisor), then
 # a real 1->3->1 fleet: load step up spawns replicas (zero-compile boot
 # from the shared executable store), a SIGKILL mid-burst is reaped and
 # replaced within one tick, the trickle phase drains back to min — zero
-# client-visible failures throughout; finishes with the cold-start
-# boot-to-first-token benchmark (docs/serving.md)
+# client-visible failures throughout (docs/serving.md)
 scale-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_autoscaler.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/scale_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --cold-start
 
 # fleet-simulator smoke: the sim + policy-parity test suites, then the
-# 1000-replica x 1M-request what-if with its capacity report, then the
-# sim bench (scale wall-clock pin + legacy-vs-debit pick rule A/B)
+# 1000-replica x 1M-request what-if with its capacity report
 sim-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_sim.py tests/test_policies.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/sim_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --sim
 
-# observability smoke: the spans/stepstats/prometheus/request-tracing suite,
-# then the span-overhead micro-bench (docs/observability.md)
+# observability smoke: the spans/stepstats/prometheus/request-tracing suite
+# (docs/observability.md)
 obs-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_obs.py -q
-	JAX_PLATFORMS=cpu python bench.py --span-overhead
-
-span-overhead:
-	JAX_PLATFORMS=cpu python bench.py --span-overhead
 
 # distributed-tracing smoke: the tracing test battery (traceparent context,
 # cross-process assembly, tail sampling, flight recorder + harvest), then a
 # real 2-replica fleet: one hedged /v1/generate assembled into a single
 # cross-process waterfall with the hedge loser labeled, and a SIGKILL
-# postmortem naming the in-flight trace ids; finishes with the
-# tracing-overhead benchmark (>= 0.98x tracing-off, docs/observability.md)
+# postmortem naming the in-flight trace ids (docs/observability.md)
 trace-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_tracing.py -q
 	JAX_PLATFORMS=cpu PYTHONPATH=".:$$PYTHONPATH" python examples/trace_smoke.py
-	JAX_PLATFORMS=cpu python bench.py --trace-overhead
 
 # round-2 example additions (text pipeline; TF1 migration needs tensorflow)
 examples-extra:

@@ -1,6 +1,7 @@
 """MNIST CNN via Pipeline.fit — translation of the reference's
-``examples/cnn_example.py``. This is the headline benchmark config
-(BASELINE.md: ≥5x reference throughput on TPU)."""
+``examples/cnn_example.py``, BASELINE.md's primary configuration. No
+benchmark cell runs it: at these widths it fills a thousandth of a chip
+(PERF.md section 3)."""
 
 import os
 import sys

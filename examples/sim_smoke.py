@@ -1,13 +1,13 @@
-"""Fleet-simulator smoke: a 1000-replica x 1M-request what-if, in seconds.
+"""Fleet-simulator smoke: a 1000-replica x 1M-request what-if.
 
 Run via ``make sim-smoke`` (or directly). The script
 
 1. replays a 1,000,000-request synthetic trace (bursty MMPP arrivals,
    heavy-tail Pareto lengths, multi-turn sessions) against a simulated
    1000-replica heterogeneous fleet — 70% bf16 pools, 30% int8 pools
-   with ~3.76x the pages per byte (the measured quantized-KV ratio) —
-   using the REAL serving policies (``serving/policies.py``), real
-   circuit breakers, and bench-fitted cost models;
+   with ~3.76x the pages per byte (an int8 page's bytes against an f32
+   pool's) — using the REAL serving policies (``serving/policies.py``),
+   real circuit breakers, and a CPU rig's cost model;
 2. verifies the run is fully accounted (every request completed or
    rejected), byte-deterministic (stable event-log sha256), and bounded
    in wall-clock;
@@ -36,8 +36,8 @@ REQUESTS = 50_000 if SMOKE else 1_000_000
 
 def build_fleet(n):
     # 70/30 bf16/int8: same device bytes, int8 holds ~3.76x the pages
-    # (BENCH_NOTES kv-quant measurement), so byte-headroom routing has
-    # real heterogeneity to work with
+    # (its bytes a page against an f32 pool's), so byte-headroom routing
+    # has real heterogeneity to work with
     specs = []
     for i in range(n):
         if i % 10 < 7:

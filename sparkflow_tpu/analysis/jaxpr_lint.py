@@ -505,8 +505,8 @@ def lint_sharding_config(fn: Callable, args: Sequence, sharding, *,
     sufficient), and a stage-0 step must NOT — tracing the step abstractly
     and walking every sub-jaxpr (shard_map bodies included) reads it off
     without executing a FLOP. A mismatch means the declared config and the
-    compiled program disagree: memory budgets, checkpoint layouts and bench
-    numbers derived from the config are all wrong for what actually runs.
+    compiled program disagree: memory budgets, checkpoint layouts and byte
+    counts derived from the config are all wrong for what actually runs.
     """
     from ..sharding import as_sharding_config
 

@@ -21,7 +21,7 @@ enters :func:`sparkflow_tpu.utils.tracing.annotate`, so when a JAX profiler
 capture (``utils.tracing.trace``) is active the same named range shows up in
 the device timeline — host spans and device annotations line up by name.
 
-Overhead discipline (pinned by ``python bench.py --span-overhead``): a span
+Overhead discipline: a span
 is two ``perf_counter`` calls, one small allocation, and one locked ring
 append — no formatting, no I/O, no jax import on this module's path. The
 framework's cross-cutting span sites (checkpoint save/restore, retry
@@ -225,8 +225,7 @@ class _SpanCtx:
 
 class _NoopSpanCtx:
     """Shared do-nothing handle returned by a disabled tracer's
-    :meth:`Tracer.span` — the tracing-off baseline ``bench.py
-    --trace-overhead`` compares against."""
+    :meth:`Tracer.span`: what tracing costs when it is off."""
 
     __slots__ = ()
 
@@ -248,8 +247,8 @@ class Tracer:
     inside one thread needs no lock; only the final commit does.
 
     ``enabled=False`` turns the tracer into a no-op (``span()`` returns a
-    shared null context, ``record()`` drops the span) — the off-baseline
-    for overhead benchmarks and a kill switch for span-heavy sites.
+    shared null context, ``record()`` drops the span): a kill switch for
+    span-heavy sites.
 
     :attr:`fingerprint` namespaces this tracer's process-local span-id
     counter at export time (``"<pidhex><random>:<n>"`` via

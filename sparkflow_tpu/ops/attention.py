@@ -143,10 +143,10 @@ def _try_shardmap_flash(q, k, v, kv_mask, causal, scale, interpret,
 
 
 # Which path the most recent flash_attention TRACE took ('pallas',
-# 'blockwise', or 'reference'). Benchmarks assert this is 'pallas' after
-# compiling their TPU step: a kernel edit that breaks the tile rules would
-# otherwise fall back silently and the suite would stay green while the
-# perf path quietly degraded (the round-2 (8,128)-tile regression).
+# 'blockwise', or 'reference'). chip_smoke.py asserts it is 'pallas' after
+# its train step compiled on the chip, tests/test_tpu_compile.py after
+# compiling for a described one: a kernel edit that breaks the tile rules
+# would otherwise fall back silently (the round-2 (8,128)-tile regression).
 # A ContextVar (like _FORCE_XLA/_SHARD_ATTN) so an interleaved trace in
 # another thread cannot clobber the value between a caller's compile and
 # its last_attention_path() check.

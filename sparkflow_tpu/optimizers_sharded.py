@@ -332,8 +332,8 @@ def has_per_param_state(optimizer: optax.GradientTransformation,
 
 
 def state_bytes_per_device(state) -> int:
-    """Per-device bytes of a (possibly sharded) state tree — the honest
-    measurement the zero1 bench reports: each leaf contributes its local
+    """Per-device bytes of a (possibly sharded) state tree, as
+    ``tests/test_zero1.py`` reads them: each leaf contributes its local
     shard size, so a replicated tree counts full and a zero1-placed tree
     counts ~1/dp."""
     total = 0
@@ -366,7 +366,7 @@ def _row_shard_bytes(tree, n_shards: int) -> int:
 def zero_memory_report(inner: optax.GradientTransformation, params,
                        n_shards: int, zero_stage: int) -> dict:
     """Structural (eval_shape-exact) per-device byte accounting for one zero
-    stage — what ``bench.py --dp-zero2`` / ``--dp-zero3`` report, valid on
+    stage (``tests/test_zero_sharding.py`` holds it to the ideal), valid on
     any backend because it measures layouts, not allocator watermarks.
 
     - ``params_at_rest`` — param bytes resident per device between steps.
@@ -378,7 +378,7 @@ def zero_memory_report(inner: optax.GradientTransformation, params,
     - ``apply_temps`` — the transient the apply step materializes: the
       all-gathered full update tree at stages 0-1, shard-sized at 2-3.
     - ``ideal_grad_opt`` — the 1/n_shards share of (full grads + full opt
-      state): the denominator of the bench's 1.3x acceptance ratio
+      state): what ``tests/test_zero_sharding.py`` holds stage 2 within 1.3x of
       (padding and replicated scalars are why measured > ideal).
     """
     if zero_stage not in (0, 1, 2, 3):

@@ -610,7 +610,7 @@ class ElasticResult:
     """Outcome of an elastic run. ``losses`` is the per-epoch mean over
     accepted pushes (epochs a replica never completed contribute what was
     accepted); ``stats`` carries the push/membership accounting the tests
-    and bench pin."""
+    pin."""
     params: Any
     opt_state: Any
     losses: List[float]
@@ -661,7 +661,7 @@ class ElasticDPEngine:
     - :meth:`run_virtual` — a deterministic event-driven simulation on a
       virtual clock: per-replica step costs, joins, mid-step preemptions
       and lease expiries all replay identically every run, with zero
-      sleeping. The chaos tests and the straggler bench run here.
+      sleeping. The chaos tests run here.
     """
 
     def __init__(self, loss_fn: Callable,

@@ -419,7 +419,7 @@ class ContinuousBatcher:
         self._closed = False
         self._draining = False
         # admission accounting: offered vs refused-at-the-door. The
-        # quantized-pool benchmarks read the rejection RATE off these (a
+        # an overload test or cell reads the rejection RATE off these (a
         # roomier pool admits more of the same offered load), and capacity
         # dashboards get them without scraping the metrics registry.
         self._submitted = 0
@@ -560,7 +560,7 @@ class ContinuousBatcher:
 
     def stats(self) -> Dict[str, Any]:
         """Admission accounting: offered load vs refused-at-the-door, plus
-        the engine's pool layout so capacity benchmarks correlate the
+        the engine's pool layout so a capacity reading correlates the
         rejection rate with bytes-per-page in one read."""
         with self._lock:
             submitted, rejected = self._submitted, self._rejected

@@ -24,7 +24,7 @@ Decisions covered
   instead of always the lowest index — the bias the deterministic replay
   exposed) and the **inflight-debited byte-headroom** generate rule that
   predicts KV exhaustion from stale probe reports before the replica
-  sheds (found in sim, confirmed by ``bench.py --sim``).
+  sheds (found in sim: ``tests/test_sim.py``).
 - :func:`classify_outcome` — what one dispatch outcome means: success,
   eject-and-reroute (draining), reroute-without-breaker (overload),
   breaker-feeding failure (5xx/wire error), or authoritative client error.
@@ -149,9 +149,9 @@ def generate_pick_key(view: ReplicaView,
       undebited rule happily piling bursts onto replicas whose pools had
       already paged out, then paying a queue_full reroute storm per
       burst; the debit predicts exhaustion *before* the replica sheds
-      (sim: fewer queue_full reroutes and 30-70% lower p95 across
-      homogeneous and mixed-pool fleets; confirmed real by
-      ``bench.py --sim``).
+      (sim: fewer queue_full reroutes and a lower simulated p95 across
+      homogeneous and mixed-pool fleets, ``tests/test_sim.py``; on a
+      real fleet not measured).
     - ``-eff_bytes`` (debited pages weighted by the replica's
       ``kv_bytes_per_page``) breaks equal-inflight ties toward the pool
       with the most remaining capacity, so heterogeneous bf16/int8

@@ -39,7 +39,7 @@ window), ``weights.pull`` (every store read), and ``engine.swap`` (inside
 each engine's swap) make the whole path fault-injectable;
 ``resilience.faults.corrupt_latest_weights`` damages a published version on
 disk the way real corruption would. See ``docs/serving.md`` ("Live weight
-publication"), ``make swap-smoke``, and ``bench.py --hot-swap``.
+publication"), ``make swap-smoke``, and ``tests/test_weightstore.py``.
 
 Lock order (GC-L304): ``WeightWatcher._lock`` guards only the watcher's own
 counters; engine locks are taken via ``swap_params``/``maybe_swap`` calls

@@ -10,15 +10,14 @@ offline: a deterministic discrete-event simulator
 *decisions* are made by the real serving plane's policy code
 (:mod:`sparkflow_tpu.serving.policies`, plus the real ``CircuitBreaker``,
 ``TokenBucket``, ``CanaryController``, and ``RetryPolicy`` on a virtual
-clock) while transport + compute are priced by a bench-fitted
+clock) while transport + compute are priced by a small linear
 :class:`~sparkflow_tpu.sim.costmodel.CostModel`. Calibration
 (:mod:`~sparkflow_tpu.sim.calibrate`) pins sim-vs-real agreement on the
 same trace; determinism is byte-exact (same trace + seed => identical
 event-log sha256).
 
 See ``docs/sim.md``; ``make sim-smoke`` runs a 1000-replica x 1M-request
-what-if end to end; ``bench.py --sim`` records scale + calibration
-numbers in ``BENCH_NOTES.md``.
+what-if end to end.
 """
 
 from .core import (FleetSimulator, ReplicaSpec, SimAutoscaler, SimReplica,

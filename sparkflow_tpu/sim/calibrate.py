@@ -11,8 +11,7 @@ models, so this module closes the loop: replay the *same trace* against
    from the real run's own median latency (:meth:`CostModel.fit_predict`),
 
 then compare tail latency and per-replica dispatch counts. The test suite
-(``tests/test_sim.py``) asserts the agreement factors; ``bench.py --sim``
-records them in ``BENCH_NOTES.md``.
+(``tests/test_sim.py``) asserts the agreement factors.
 
 Fitting on the median and *checking* on the p95 + per-replica split is
 deliberate: the median is one scalar (rig speed), while the tail and the

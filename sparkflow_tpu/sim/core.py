@@ -12,7 +12,7 @@ dispatch is on, and the real
 :class:`~sparkflow_tpu.resilience.retry.RetryPolicy` backoff schedule.
 Only *transport and compute* are simulated: instead of HTTP and a TPU,
 each replica prices its work with a :class:`~sparkflow_tpu.sim.costmodel.
-CostModel` fitted from bench measurements. That separation is the whole
+CostModel` (a CPU rig's timings). That separation is the whole
 design — a policy bug found here is a policy bug in production code, not
 in a reimplementation.
 

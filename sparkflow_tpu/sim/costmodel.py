@@ -1,8 +1,10 @@
-"""Replica cost models for the fleet simulator, fitted from measurements.
+"""Replica cost models for the fleet simulator.
 
 The simulator never runs a model — it *prices* each request against a
-:class:`CostModel` whose coefficients come from real benchmarks
-(``bench.py`` runs recorded in ``BENCH_NOTES.md``). Keeping the model
+:class:`CostModel` whose coefficients are timings of a CPU rig, taken in
+earlier rounds at toy widths. They are not a model of the chip: nothing
+here has been fitted to a TPU, and a simulated latency is not a prediction
+of a served one. Keeping the model
 explicitly tiny (a handful of linear coefficients) is deliberate: the
 point of the simulator is routing/policy dynamics at fleet scale, and for
 those what matters is the *relative* cost structure (prefill scales with
@@ -11,19 +13,19 @@ concurrency, KV pages scale with total tokens), not cycle accuracy.
 :mod:`sparkflow_tpu.sim.calibrate` closes the loop by replaying the same
 trace against a real fleet and pinning sim-vs-real agreement.
 
-Default coefficients (``CostModel.from_bench_notes()``) trace to
-``BENCH_NOTES.md`` entries measured on this repo's CPU rig:
+Default coefficients (``CostModel.from_bench_notes()``), kept because the
+tests price fleets with them and only their ratios matter there:
 
-- ``token_latency_p50_ms = 2.58`` (continuous-batching decode bench) —
-  per-token decode step time at low concurrency.
-- ``ttft_cold_ms = 10.9`` at ``prompt_len = 104`` (prefix-cache bench,
-  cold path) — prefill throughput ~= 104 / (10.9 - overhead) tokens/ms.
-- chunked-prefill bench: inter-token p95 rises from 2.58 p50 to
-  ``p95_chunked_ms = 6.92`` when prefill and a full decode batch share
-  the device — the ``decode_slowdown`` contention coefficient.
-- quantized-KV bench: int8 pools hold ``3.76x`` pages per byte vs the
-  float pool — why heterogeneous ``kv_bytes_per_page`` fleets exist at
-  all (see the byte-headroom pick rule in ``serving/policies.py``).
+- ``2.58`` ms a decoded token at low concurrency.
+- ``10.9`` ms to a cold first token at ``prompt_len = 104``: prefill
+  throughput ~= 104 / (10.9 - overhead) tokens/ms.
+- the gap between tokens rising from 2.58 to ``6.92`` ms when a prefill
+  and a full decode batch share the device: the ``decode_slowdown``
+  contention coefficient.
+- an int8 pool holding ``3.76x`` the pages per byte of an f32 pool (a
+  byte count, not a timing): why heterogeneous ``kv_bytes_per_page``
+  fleets exist at all (see the byte-headroom pick rule in
+  ``serving/policies.py``).
 """
 
 from __future__ import annotations
@@ -51,9 +53,9 @@ class CostModel:
     decode_slowdown : float
         Linear contention coefficient: with ``active`` of ``slots``
         decode lanes busy, the per-token time scales by
-        ``1 + decode_slowdown * active / slots``. Fitted from the
-        chunked-prefill bench's p50 -> p95 spread (6.92 / 2.58 at a full
-        batch => slowdown ~= 1.7).
+        ``1 + decode_slowdown * active / slots``. From the CPU rig's
+        gap between tokens with and without a prefill in the batch
+        (6.92 / 2.58 at a full batch => slowdown ~= 1.7).
     predict_ms : float
         Flat service time for the predict (non-autoregressive) plane;
         the same contention factor applies.
@@ -73,7 +75,7 @@ class CostModel:
 
     @staticmethod
     def from_bench_notes() -> "CostModel":
-        """The BENCH_NOTES.md-fitted defaults (see module docstring)."""
+        """The CPU rig's defaults (see module docstring)."""
         return CostModel()
 
     def scaled(self, factor: float) -> "CostModel":

@@ -1,10 +1,10 @@
-"""FLOPs and MFU accounting for benchmark output.
+"""FLOPs and MFU accounting for ``Trainer.fit(trace_spans=True)``'s step
+stats.
 
-The reference publishes no performance numbers at all (SURVEY.md §6), so its
-benchmarks could only ever be throughput-relative. Model-FLOPs utilization
-anchors the ladder to the hardware roofline instead: every benchmark entry
-reports ``tflops_per_sec`` and ``mfu`` alongside examples/sec, so a
-throughput number that looks big but wastes the MXU is visible as such.
+Model-FLOPs utilization anchors a throughput to the hardware roofline, so a
+number that looks big but wastes the MXU is visible as such. The benchmark
+keeps its own copy of these counts (``chipbench/counts.py``), so that a
+change here cannot move ``mfu.train``.
 
 Two FLOPs sources, used deliberately:
 

@@ -1,4 +1,4 @@
-"""FLOPs/MFU accounting (utils.flops) — the bench ladder's roofline math."""
+"""FLOPs/MFU accounting (utils.flops): the roofline math behind step stats."""
 
 import jax
 import jax.numpy as jnp

@@ -137,8 +137,9 @@ def test_force_xla_attention_skips_pallas(monkeypatch):
 
 
 def test_last_attention_path_instrumentation():
-    """Benchmarks assert the perf path via last_attention_path(); pin that
-    the recorder distinguishes pallas / blockwise / reference routing."""
+    """chip_smoke.py and test_tpu_compile.py assert the perf path via
+    last_attention_path(); pin that the recorder distinguishes pallas /
+    blockwise / reference routing."""
     import jax.numpy as jnp
     from sparkflow_tpu.ops import attention as A
 

@@ -15,7 +15,7 @@ The contracts pinned here:
   factors;
 - **the improvement** — the inflight-debited byte-headroom generate rule
   beats the legacy rule on tail latency in the heterogeneous what-if
-  that motivated it (``bench.py --sim`` confirms on a real fleet).
+  that motivated it.
 """
 
 import pytest
@@ -247,7 +247,6 @@ def test_calibration_pins_sim_vs_real_agreement():
 def test_scale_1000_replicas_1m_requests():
     # the headline claim: fleet-scale what-ifs are cheap. 1000 replicas x
     # 1M requests, fully accounted, deterministic, bounded wall-clock
-    # (bench.py --sim pins the tighter number with provenance).
     cost = CostModel.from_bench_notes()
     tr = synthetic_trace(1_000_000, seed=7, rate_rps=40000.0,
                          prompt_range=(16, 1024), output_range=(8, 256))

@@ -63,15 +63,17 @@ def _flash_structs(sh, b, h, s, d, dtype, mask):
     return (q, q, q) + ((m,) if mask else ())
 
 
-# GPT-2 small's training shape (B8 H12 S1024 D64, bf16) and the BERT-ish f32
-# shape that once failed the (8, 128) tile check on the row statistics
-FLASH_SHAPES = [(8, 12, 1024, 64, jnp.bfloat16), (4, 12, 512, 64, jnp.float32)]
+# GPT-2 small's training shape (B8 H12 S1024 D64, bf16), the BERT-ish f32
+# shape that once failed the (8, 128) tile check on the row statistics, and
+# the longest context the kernel is offered (one row of 32k)
+FLASH_SHAPES = [(8, 12, 1024, 64, jnp.bfloat16), (4, 12, 512, 64, jnp.float32),
+                (1, 8, 32768, 64, jnp.bfloat16)]
 
 
 @pytest.mark.parametrize("mask", [False, True], ids=["nomask", "kv_mask"])
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("b,h,s,d,dtype", FLASH_SHAPES,
-                         ids=["gpt2-small", "bert-512-f32"])
+                         ids=["gpt2-small", "bert-512-f32", "long-32k"])
 def test_flash_lowers_on_tpu(one_chip, b, h, s, d, dtype, causal, mask):
     """Forward, and forward+backward, through the pallas kernels."""
     structs = _flash_structs(one_chip, b, h, s, d, dtype, mask)

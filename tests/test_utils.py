@@ -30,12 +30,19 @@ def test_csv_loader_roundtrip(tmp_path):
     np.testing.assert_allclose(a, m, atol=1e-5)
 
 
-def test_batch_queue_preserves_rows_and_masks():
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+def test_batch_queue_preserves_rows_and_masks(native, monkeypatch):
+    """Every pushed row comes out exactly once, through the C++ ring and
+    through the python queue it falls back to without a toolchain."""
+    if not native:
+        monkeypatch.setattr("sparkflow_tpu.utils.data.load_library",
+                            lambda: None)
     rs = np.random.RandomState(1)
     M = rs.rand(250, 5).astype(np.float32)
     Y = rs.rand(250, 2).astype(np.float32)
     q = BatchQueue(batch_size=64, row_dim=5, label_dim=2, capacity=3,
                    shuffle=True, seed=7)
+    assert (q._lib is not None) == native
 
     def produce():
         for i in range(0, 250, 90):

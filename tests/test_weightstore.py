@@ -26,8 +26,8 @@ from sparkflow_tpu.analysis import lockgraph, locks, racecheck
 from sparkflow_tpu.graph_utils import build_graph
 from sparkflow_tpu.models.registry import build_registry_spec, model_from_json
 from sparkflow_tpu.resilience import faults
-from sparkflow_tpu.serving import (CanaryController, DecodeEngine,
-                                   InferenceEngine, WeightStore,
+from sparkflow_tpu.serving import (CanaryController, ContinuousBatcher,
+                                   DecodeEngine, InferenceEngine, WeightStore,
                                    WeightStoreError, WeightWatcher)
 from sparkflow_tpu.serving.membership import Replica
 from sparkflow_tpu.trainer import Trainer
@@ -469,6 +469,36 @@ def test_decode_watcher_nudges_deferred_swap(lm, tmp_path):
     assert watcher.poll_once() is False  # no new version, but the nudge...
     assert eng.serving_version() == 1    # ...applies the pending swap
     assert watcher.serving_version() == 1
+
+
+def test_decode_swap_mid_burst_loses_no_request(lm, tmp_path):
+    """A publish mid-burst, taken up by a background watcher: every request
+    delivers its whole budget, the serving version flips exactly once,
+    nothing retraces, and the engine then holds the published tree bitwise."""
+    model, p1, p2 = lm
+    store = WeightStore(str(tmp_path))
+    eng = DecodeEngine(model, p1, num_slots=2, page_size=8, seed=0)
+    watcher = WeightWatcher(store, [eng], poll_interval_s=0.005).start()
+    cb = ContinuousBatcher(eng, max_queue=16)
+    budgets = [4, 3, 5, 3, 4, 3, 6, 3]
+    try:
+        futs = [cb.submit([1 + i, 2, 3], max_new_tokens=b, temperature=0.0)
+                for i, b in enumerate(budgets)]
+        futs[2].result(timeout=120)  # part of the burst is through
+        store.publish(p2)
+        assert [f.result(timeout=120)["num_tokens"] for f in futs] == budgets
+        deadline = 2000  # drained: the next poll lands the swap
+        while eng.serving_version() != 1 and deadline:
+            deadline -= 1
+            threading.Event().wait(0.005)
+    finally:
+        cb.close()
+        watcher.stop()
+    st = eng.stats()
+    assert st["serving_version"] == 1 and st["swaps"] == 1
+    assert not st["pending_swap"] and st["steady_traces"] == 0
+    for got, want in zip(jax.tree.leaves(eng._params), jax.tree.leaves(p2)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- canary health gate -------------------------------------------------------
