@@ -29,6 +29,21 @@ selection; it moves the indexer's three matrices and nothing else, and the
 cross-entropy never moves them), plus ``router_aux_weight`` times each
 layer's balance loss over all experts.
 
+What a block's backward pass keeps and what it makes again (``remat=True``,
+the default). Each block is a ``jax.checkpoint``: its backward pass runs the
+block's forward again (projections, norms, rotary, router, the experts'
+forward: their residuals are the large ones) but for the values the
+checkpoint keeps by name (:data:`KEPT`): the selection, as bits; the
+attention's output and logsumexp; the two row statistics of the indexer
+loss's kernel. With them ``index_select``, ``sparse_attn_fwd`` and
+``index_kl_fwd`` run once a (row, layer) and not twice; each value is the
+very one the backward kernels read, so nothing in a result changes. The
+indexer loss's target (``sparse_attn_probs``) is made again and is meant to
+be: it is float32 ``[S, S]``, 268 MB a layer and row at 8192 tokens, against
+2.25 ms to make it. The rows of a step go through the model one after
+another, but every row's kept values live until that row's backward pass, so
+a step holds ``rows x layers`` such sets. ``remat=False`` keeps everything.
+
 The decode plane does not run this model: its cache would have to hold the
 indexer's keys and its kernels select pages per query (ROADMAP).
 """
@@ -39,6 +54,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import grouped_matmul as gm
 from ..ops import sparse_attention as sa
@@ -70,6 +86,23 @@ def rope(x, theta: float):
 
 def _dense(x, kernel):
     return jnp.matmul(x, kernel.astype(x.dtype))
+
+
+# What a block's checkpoint keeps by name for its backward pass (the module's
+# text says why); ``ops/sparse_attention.py`` puts the names on the values.
+# Bytes for a layer and a row of S tokens, Hq query heads of D:
+# ``S^2 / 8`` (the selection as bits) ``+ 2 S Hq D`` (the output, bf16)
+# ``+ 4 S Hq + 8 S`` (the logsumexp and the KL kernel's lse and mass, f32):
+# 8.4 + 67.1 + 1.05 + 0.07 = 76.6 MB at S 8192, Hq 32, D 128, and 0.61 GB for
+# the 2 rows x 4 layers a step of that size holds (the compiler counts 1.5 GB
+# more scratch: XLA copies each value into and out of the rows' buffers).
+# The selection as int8 would be ``S^2`` (67.1 MB, and 1.07 GB at S 32 768)
+# to spare the cheapest of the three kernels: hence the bits.
+# Not kept: ``selected_probs``' target, float32 ``[S, S]`` (268 MB a layer and
+# row for 2.25 ms of ``sparse_attn_probs``; kept in a narrower type it would
+# be another input to ``index_kl_bwd``, another result), and all XLA makes.
+KEPT = jax.checkpoint_policies.save_only_these_names(
+    sa.SELECTION, sa.ATTN_OUT, sa.ATTN_LSE, sa.KL_LSE, sa.KL_MASS)
 
 
 @register_model("sparse_moe_lm")
@@ -193,6 +226,9 @@ class SparseMoELM(RegistryModel):
             w = _dense(ys, bp["idx_w_kernel"])
             mask = sa.index_select(qi, ki, w, self.indexer_topk,
                                    self.indexer_block)
+            if self.remat:      # kept as bits: everyone reads the bits' copy
+                mask = sa.unpack_selection(checkpoint_name(
+                    sa.pack_selection(mask), sa.SELECTION))
         with jax.named_scope("sparse_attention"):
             att, lse = sa.selected_attention(q, k, v, mask)
         with jax.named_scope("indexer"):
@@ -246,7 +282,8 @@ class SparseMoELM(RegistryModel):
         with jax.named_scope("embed"):
             x = self.cast(jnp.take(params["embed"]["tok"],
                                    ids - self.vocab_held[0], axis=0))
-        block = jax.checkpoint(self._block) if self.remat else self._block
+        block = (jax.checkpoint(self._block, policy=KEPT) if self.remat
+                 else self._block)
         aux = []
         for i in range(self.num_layers):
             x, a = block(params[f"block_{i}"], x)

@@ -41,12 +41,23 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _VMEM_LIMIT = 96 * 1024 * 1024
 _INT_MIN = -2 ** 31
+
+# ``jax.ad_checkpoint.checkpoint_name``s of what the backward kernels read of
+# their forward (``ATTN_*`` in ``_selected_fwd``, ``KL_*`` in
+# ``_index_kl_fwd``; ``SELECTION`` is the caller's to put on the selection or
+# its bits): a ``jax.checkpoint`` around a caller that keeps them with
+# ``save_only_these_names`` runs no forward kernel again
+# (``models/sparse_moe_lm.py``). Under no checkpoint a name does nothing.
+SELECTION = "index_selection"
+ATTN_OUT, ATTN_LSE = "sparse_attn_out", "sparse_attn_lse"
+KL_LSE, KL_MASS = "index_kl_lse", "index_kl_mass"
 
 
 # ---------------------------------------------------------------------------
@@ -153,6 +164,19 @@ def index_select(qi, ki, w, topk: int, block: int = 256):
                    *_index_blocks(qi.shape[1], block))
 
 
+def pack_selection(mask):
+    """A selection ``int8 [B, S, S]`` of 0 and 1 as bits, ``uint8 [B, ceil(S
+    / 8), S]`` (eight queries a byte, the keys' axis as it was): an eighth of
+    the bytes for whoever keeps it from a forward pass to its backward."""
+    return jnp.packbits(mask.astype(jnp.uint8), axis=1)
+
+
+def unpack_selection(packed):
+    """:func:`pack_selection` undone, to the bit."""
+    return jnp.unpackbits(packed, axis=1, count=packed.shape[2]).astype(
+        jnp.int8)
+
+
 def index_select_reference(qi, ki, w, topk: int):
     """Plain ``jnp``: all scores at once and ``lax.top_k``'s indices."""
     def row(qi_r, ki_r, w_r):
@@ -173,8 +197,10 @@ def indexer_loss(qi, ki, w, mask, target, block: int = 256):
     row of tokens ``[B]``. ``target [B, S, S]`` (rows summing to one over the
     selection) carries no gradient; ``qi``, ``ki``, ``w`` do: kernel
     ``index_kl_fwd`` and, backward, ``index_kl_bwd_dq`` and
-    ``index_kl_bwd_dk``, which make the scores' tiles again. ``block``
-    queries a grid step, as in :func:`index_select`."""
+    ``index_kl_bwd_dk``, which make the scores' tiles again from the
+    forward's logsumexp and mass of each query (named :data:`KL_LSE` and
+    :data:`KL_MASS` for a checkpoint to keep). ``block`` queries a grid step,
+    as in :func:`index_select`."""
     kl = _index_kl(*_index_layout(qi, ki, w), mask.astype(jnp.int8),
                    jax.lax.stop_gradient(target).astype(jnp.float32),
                    *_index_blocks(qi.shape[1], block))
@@ -445,6 +471,9 @@ def _selected(q, k, v, mask, scale, block_q, block_k, interpret):
 
 def _selected_fwd(q, k, v, mask, scale, block_q, block_k, interpret):
     out, lse = _forward(q, k, v, mask, scale, block_q, block_k, interpret)
+    # the names sit on the values the backward kernels read: a checkpoint
+    # that keeps them by name does not run ``sparse_attn_fwd`` again
+    out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return (out, lse), (q, k, v, mask, out, lse)
 
 
@@ -476,7 +505,9 @@ def selected_attention(q, k, v, mask, sm_scale: Optional[float] = None,
     for each query, ``k, v [B, Hkv, S, D]`` shared by ``Hq / Hkv`` query
     heads each. Returns ``(out, lse [B, Hq, S])``; the logsumexp carries no
     gradient (:func:`selected_probs` reads it). Every query has to select at
-    least one key."""
+    least one key. The backward kernels read ``out`` and ``lse`` again: the
+    forward of the ``custom_vjp`` names them :data:`ATTN_OUT` and
+    :data:`ATTN_LSE` for a checkpoint to keep."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -845,6 +876,8 @@ def _index_kl(q, k, w, mask, target, block_q, block_k, interpret):
 def _index_kl_fwd(q, k, w, mask, target, block_q, block_k, interpret):
     kl, lse, mass = _kl_forward(q, k, w, mask, target, block_q, block_k,
                                 interpret)
+    # as in ``_selected_fwd``: kept by name, ``index_kl_fwd`` runs once
+    lse, mass = checkpoint_name(lse, KL_LSE), checkpoint_name(mass, KL_MASS)
     return kl, (q, k, w, mask, target, lse, mass)
 
 

@@ -160,6 +160,89 @@ def test_the_indexers_loss_moves_the_indexer_and_nothing_else(both, part):
                 assert not live, (group, name)
 
 
+# -- what a block's backward keeps and what it makes again ---------------------
+
+
+def _kernel_calls(jaxpr, counts):
+    """Count the ``pallas_call``s of ``jaxpr`` by name, through every
+    sub-jaxpr an equation holds (``scan``, ``checkpoint``, ``custom_vjp``)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            counts[name] = counts.get(name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)           # a ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    _kernel_calls(sub, counts)
+    return counts
+
+
+@pytest.fixture(scope="module")
+def kernels_of_a_gradient():
+    """The kernels in the gradient of the loss with ``remat=True``, a
+    layer."""
+    cfg = toy_cfg()
+    params, ids = ref.init_params(cfg, 3), ids_for(0)
+    model = toy_model(cfg, remat=True)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.mean(
+        model.loss_and_metrics(p, {"input_ids": ids})[0])))(params)
+    return {name: n / cfg["num_hidden_layers"]
+            for name, n in _kernel_calls(jaxpr.jaxpr, {}).items()}
+
+
+# the target of the indexer's loss is made again, and is meant to be
+PASSES_A_LAYER = dict(
+    index_select=1, sparse_attn_fwd=1, index_kl_fwd=1, sparse_attn_probs=2,
+    sparse_attn_bwd_dq=1, sparse_attn_bwd_dkv=1, index_kl_bwd_dq=1,
+    index_kl_bwd_dk=1, expert_gmm=9, expert_tgmm=3)
+
+
+@pytest.mark.parametrize("kernel", sorted(PASSES_A_LAYER))
+def test_a_blocks_backward_runs_each_attention_kernel_once(
+        kernels_of_a_gradient, kernel):
+    """The checkpoint around a block keeps the selection (as bits), the
+    attention's output and logsumexp and the KL kernel's row statistics: the
+    backward pass runs ``index_select``, ``sparse_attn_fwd`` and
+    ``index_kl_fwd`` no second time (with no names kept each ran twice a
+    layer). The experts' forward is still made again (``expert_gmm``: three
+    products forward, again, and three backward beside ``expert_tgmm``'s
+    three)."""
+    assert kernels_of_a_gradient[kernel] == PASSES_A_LAYER[kernel]
+
+
+@pytest.fixture(scope="module")
+def kept_and_remade():
+    """Loss and gradients with ``remat=True`` and with ``remat=False``."""
+    cfg = toy_cfg()
+    params, ids = ref.init_params(cfg, 3), ids_for(0)
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        for remat in (True, False):
+            model = toy_model(cfg, remat=remat)
+            out[remat] = jax.value_and_grad(lambda p: jnp.mean(
+                model.loss_vector(p, {"input_ids": ids})))(params)
+    return out
+
+
+@pytest.mark.parametrize("leaf", ["loss"] + LEAVES)
+def test_keeping_by_name_changes_no_leafs_gradient(kept_and_remade, leaf):
+    """What the checkpoint keeps is what it would make again, to the bit: the
+    loss is equal with and without it, and so are the gradients but for
+    three norm scales, which XLA reduces in another order inside the
+    checkpoint (1e-8 apart, CPU): every leaf within the tolerance of the
+    test against the reference."""
+    (loss, grads), (want_loss, want) = (kept_and_remade[True],
+                                        kept_and_remade[False])
+    if leaf == "loss":
+        np.testing.assert_array_equal(loss, want_loss)
+        return
+    group, name = leaf.split("/")
+    assert float(jnp.max(jnp.abs(want[group][name]))) > 1e-4
+    np.testing.assert_allclose(grads[group][name], want[group][name],
+                               atol=2e-6, rtol=1e-4)
+
+
 # -- the shares add up --------------------------------------------------------
 
 

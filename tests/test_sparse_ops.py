@@ -116,6 +116,19 @@ def test_select_block_holds_minus_zero_and_zero_for_one_score():
     np.testing.assert_array_equal(np.sort(idx, axis=-1)[0], [2, 3, 4, 7, 8])
 
 
+@pytest.mark.parametrize("seq", [8, 13, 32, 256])
+def test_a_selection_comes_back_from_its_bits(seq):
+    """An eighth of the bytes (the queries padded to a multiple of eight),
+    and every 0 and 1 where it was."""
+    mask = jnp.asarray(np.random.default_rng(seq).random((2, seq, seq)) < 0.3,
+                       jnp.int8)
+    packed = sa.pack_selection(mask)
+    assert packed.shape == (2, -(-seq // 8), seq) and packed.dtype == jnp.uint8
+    back = jax.jit(sa.unpack_selection)(packed)
+    assert back.dtype == jnp.int8
+    np.testing.assert_array_equal(back, mask)
+
+
 def _attention_inputs(seed, hq=4, hkv=2, seq=S, d=8, keep=0.4):
     r = np.random.default_rng(seed)
     q = jnp.asarray(r.normal(size=(2, hq, seq, d)), jnp.float32)
