@@ -18,7 +18,10 @@ the reference examples), ``transformer_classifier`` / ``transformer_lm`` (BERT
 ImageNet residual network, stateless norm), ``rnn_classifier`` / ``rnn_lm``
 (LSTM/GRU via lax.scan, fused gate matmuls), ``sparse_moe_lm`` (RMSNorm,
 rotary, grouped query heads, a learned top-k key selection and dropless
-SiLU-gated experts of which a share may be held; training path only).
+SiLU-gated experts of which a share may be held; training path only),
+``block_diffusion_lm`` (the same decoder trained by block diffusion: a clean
+and a noised copy of every row under one block-structured attention mask, a
+masked-token loss; training path only).
 """
 
 from .registry import model_from_json, register_model, build_registry_spec
@@ -26,12 +29,13 @@ from . import presets
 from .transformer import TransformerClassifier, TransformerLM
 from .moe import MoETransformerLM
 from .sparse_moe_lm import SparseMoELM
+from .block_diffusion_lm import BlockDiffusionLM, noise_rows
 from .resnet import ResNet
 from .rnn import RNNClassifier, RNNLM
 
 __all__ = [
     "model_from_json", "register_model", "build_registry_spec", "presets",
     "TransformerClassifier", "TransformerLM", "MoETransformerLM",
-    "SparseMoELM", "ResNet",
+    "SparseMoELM", "BlockDiffusionLM", "noise_rows", "ResNet",
     "RNNClassifier", "RNNLM",
 ]

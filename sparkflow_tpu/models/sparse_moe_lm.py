@@ -3,7 +3,8 @@ query heads with a per-head q/k norm, a learned selection of keys (an
 indexer that scores every (query, key) pair and keeps each query's ``topk``)
 and SiLU-gated experts that drop no token, of which this model may hold a
 share. No bias anywhere, an untied head. The block is written once
-(:meth:`SparseMoELM._block`).
+(:class:`MoEDecoder`: everything of it but which keys a query sees, which
+``block_diffusion_lm.py`` answers by a rule and this file by an indexer).
 
 A layer, for a row of tokens (pre-norm, residual):
 
@@ -69,12 +70,15 @@ def rms_norm(x, scale, eps):
     return y.astype(x.dtype)
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, positions=None):
     """Rotary positions over the whole last axis of ``x [B, S, ..., D]``
-    (rotate-half), position = index along axis 1; computed in float32."""
+    (rotate-half); the position of index ``i`` along axis 1 is
+    ``positions[i]``, ``i`` itself by default; computed in float32."""
     s, d = x.shape[1], x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
     ang = ang.reshape((1, s) + (1,) * (x.ndim - 3) + (d // 2,))
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
@@ -105,13 +109,212 @@ KEPT = jax.checkpoint_policies.save_only_these_names(
     sa.SELECTION, sa.ATTN_OUT, sa.ATTN_LSE, sa.KL_LSE, sa.KL_MASS)
 
 
+class MoEDecoder(RegistryModel):
+    """What the families of this file and of ``block_diffusion_lm.py``
+    share, written once: the block's norms, projections, per-head q/k norm
+    and rotary positions (:meth:`_qkv`), its router and experts
+    (:meth:`_experts`), the residual wiring (:meth:`_block`), the stack of
+    checkpointed blocks (:meth:`_encode`), the head and a row's weighted
+    cross-entropy a stretch at a time (:meth:`_weighted_nll`). A family says
+    which keys a query sees (:meth:`_attend`), where an index of the row is
+    (:meth:`_positions`), what a block's checkpoint keeps (``KEPT``), how an
+    id finds its embedding row (:meth:`_embed_index`) and what a row's loss
+    is."""
+
+    TENSORS = ("input_ids", "logits", "pred")
+    KEPT = None                 # a ``jax.checkpoint`` policy; None keeps nothing
+
+    def __init__(self, vocab_size: int, hidden: int, num_layers: int,
+                 num_heads: int, num_kv_heads: int, head_dim: int,
+                 num_experts: int, experts_per_token: int, expert_dim: int,
+                 experts_held: Optional[Sequence[int]],
+                 vocab_held: Optional[Sequence[int]], rope_theta: float,
+                 rms_eps: float, norm_topk_prob: bool,
+                 router_aux_weight: float, max_len: int, head_block: int,
+                 dropout: float, remat: bool, compute_dtype):
+        if dropout:
+            raise ValueError(f"{self.model_name} has no dropout")
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads do not divide over "
+                             f"{num_kv_heads} key/value heads")
+        self.vocab_size, self.hidden = vocab_size, hidden
+        self.num_layers, self.num_heads = num_layers, num_heads
+        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
+        self.num_experts, self.experts_per_token = num_experts, experts_per_token
+        self.expert_dim = expert_dim
+        self.experts_held = tuple(experts_held or (0, num_experts))
+        self.vocab_held = tuple(vocab_held or (0, vocab_size))
+        for name, (lo, hi), whole in (
+                ("experts_held", self.experts_held, num_experts),
+                ("vocab_held", self.vocab_held, vocab_size)):
+            if not 0 <= lo < hi <= whole:
+                raise ValueError(f"{name}={lo, hi} is no range of {whole}")
+        self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
+        self.norm_topk_prob = norm_topk_prob
+        self.router_aux_weight = router_aux_weight
+        self.max_len, self.remat = max_len, remat
+        self.head_block = head_block
+        super().__init__(compute_dtype)
+        self.graphdef = _Names(self.TENSORS)
+
+    # -- specs ---------------------------------------------------------------
+
+    @property
+    def held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def vocab_here(self) -> int:
+        return self.vocab_held[1] - self.vocab_held[0]
+
+    embed_rows = vocab_here      # a family may hold rows beyond the slice
+
+    def input_specs(self):
+        return {"input_ids": ((None, self.max_len), "int32")}
+
+    def _attention_specs(self):
+        """A family's own matrices of the attention layer, after ``W_o``."""
+        return {}
+
+    def _block_specs(self):
+        h, d, m = self.hidden, self.head_dim, self.expert_dim
+        n = "normal(0.02)"
+        return {
+            "ln1_scale": ((h,), "ones"),
+            "q_kernel": ((h, self.num_heads * d), n),
+            "k_kernel": ((h, self.num_kv_heads * d), n),
+            "v_kernel": ((h, self.num_kv_heads * d), n),
+            "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"),
+            "o_kernel": ((self.num_heads * d, h), n),
+            **self._attention_specs(),
+            "ln2_scale": ((h,), "ones"),
+            "router": ((h, self.num_experts), n),
+            "experts_w1": ((self.held, h, m), n),
+            "experts_w3": ((self.held, h, m), n),
+            "experts_w2": ((self.held, m, h), n),
+        }
+
+    def param_specs(self):
+        h = self.hidden
+        specs = {"embed": {"tok": ((self.embed_rows, h), "normal(0.02)")}}
+        for i in range(self.num_layers):
+            specs[f"block_{i}"] = self._block_specs()
+        specs["final_ln"] = {"scale": ((h,), "ones")}
+        specs["lm_head"] = {"kernel": ((h, self.vocab_here), "normal(0.02)")}
+        return specs
+
+    # -- the block, once -----------------------------------------------------
+
+    def _positions(self, s: int):
+        """The position of each of a row's ``s`` indices; ``None``: its
+        index."""
+        return None
+
+    def _qkv(self, bp, y):
+        """``y = RMSNorm(x) [B, S, h]`` -> ``q [B, Hq, S, D]``, ``k, v [B,
+        Hkv, S, D]``: the projections, RMSNorm over each head of ``q`` and
+        ``k``, rotary positions over the whole head."""
+        b, s, _ = y.shape
+        heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
+        pos = self._positions(s)
+        q = heads(_dense(y, bp["q_kernel"]), self.num_heads)
+        k = heads(_dense(y, bp["k_kernel"]), self.num_kv_heads)
+        v = heads(_dense(y, bp["v_kernel"]), self.num_kv_heads)
+        q = rope(rms_norm(q, bp["q_norm"], self.rms_eps), self.rope_theta, pos)
+        k = rope(rms_norm(k, bp["k_norm"], self.rms_eps), self.rope_theta, pos)
+        return tuple(jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+
+    def _attend(self, bp, y):
+        """The attention on ``y = RMSNorm(x) [B, S, h]``: its output before
+        ``W_o`` ``[B, S, Hq D]`` and the family's own entries of the block's
+        ``aux``."""
+        raise NotImplementedError
+
+    def _experts(self, bp, y):
+        """The expert layer on ``y = RMSNorm(x) [B, S, h]``: the held
+        experts' part of the layer's output, each row's balance loss ``[B]``
+        and each held expert's load ``[held]``."""
+        b, s, h = y.shape
+        with jax.named_scope("router"):
+            logits = jnp.matmul(y.reshape(b * s, h).astype(jnp.float32),
+                                bp["router"],
+                                precision=jax.lax.Precision.HIGHEST)
+            probs, gates, experts = gm.route_top_k(
+                logits, self.experts_per_token, self.norm_topk_prob)
+            balance = jax.vmap(gm.balance_loss)(
+                probs.reshape(b, s, -1), experts.reshape(b, s, -1))
+        with jax.named_scope("experts"):
+            out, load = gm.dropless_experts(
+                y.reshape(b * s, h), gates, experts, bp["experts_w1"],
+                bp["experts_w3"], bp["experts_w2"], self.experts_held[0])
+        return out.reshape(b, s, h), balance, load
+
+    def _block(self, bp, x):
+        """One layer on ``x [B, S, h]`` -> ``(x, aux)``; ``aux`` holds each
+        row's balance loss, the layer's expert load and what the family's
+        attention adds."""
+        att, aux = self._attend(
+            bp, rms_norm(x, bp["ln1_scale"], self.rms_eps))
+        x = x + _dense(att, bp["o_kernel"])
+        out, balance, load = self._experts(
+            bp, rms_norm(x, bp["ln2_scale"], self.rms_eps))
+        return x + out, dict(aux, balance=balance, expert_load=load)
+
+    def _head(self, params, x):
+        """Final norm and the head over the vocabulary held: float32
+        logits."""
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_ln"]["scale"], self.rms_eps)
+            return jnp.matmul(x, params["lm_head"]["kernel"].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
+
+    def _embed_index(self, ids):
+        """The embedding row of each id."""
+        return ids - self.vocab_held[0]
+
+    def _encode(self, params, ids):
+        with jax.named_scope("embed"):
+            x = self.cast(jnp.take(params["embed"]["tok"],
+                                   self._embed_index(ids), axis=0))
+        block = (jax.checkpoint(self._block, policy=self.KEPT) if self.remat
+                 else self._block)
+        aux = []
+        for i in range(self.num_layers):
+            x, a = block(params[f"block_{i}"], x)
+            aux.append(a)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *aux)
+
+    def _weighted_nll(self, params, x, tgt, weight):
+        """``sum_i weight[i] * cross-entropy(head(x[i]), tgt[i])`` of one
+        row: ``x [S, h]`` (before the final norm), ``tgt [S]`` columns of the
+        head. The head's float32 logits are made and reduced a stretch of the
+        row at a time (and again in the backward pass): a whole row's are ``S
+        x vocab`` floats, three times over."""
+        s = tgt.shape[0]
+        c = self.head_block if s % self.head_block == 0 else s
+
+        @jax.checkpoint
+        def stretch(a):
+            xs, t, w = a
+            logits = self._head(params, xs)
+            picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+            return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * w)
+
+        split = lambda a: a.reshape((s // c, c) + a.shape[1:])
+        return jnp.sum(jax.lax.map(
+            stretch, (split(x), split(tgt), split(weight))))
+
+    def _loss(self, params, feeds, train, rng):
+        return self.loss_and_metrics(params, feeds, train, rng)[0]
+
+
 @register_model("sparse_moe_lm")
-class SparseMoELM(RegistryModel):
+class SparseMoELM(MoEDecoder):
     """See the module's text. ``experts_held = (first, stop)`` and
     ``vocab_held = (first, stop)`` give the share this model holds; the
     default is everything."""
 
-    TENSORS = ("input_ids", "logits", "pred")
+    KEPT = staticmethod(KEPT)    # a function: not to be bound as a method
     decode_unsupported = (
         "sparse_moe_lm trains only: the decode plane has no cache for the "
         "indexer's keys and no per-query selection in its paged kernels")
@@ -132,91 +335,31 @@ class SparseMoELM(RegistryModel):
                  head_block: int = 2048,
                  dropout: float = 0.0, remat: bool = True,
                  compute_dtype=None):
-        if dropout:
-            raise ValueError("sparse_moe_lm has no dropout")
-        if num_heads % num_kv_heads:
-            raise ValueError(f"{num_heads} query heads do not divide over "
-                             f"{num_kv_heads} key/value heads")
-        self.vocab_size, self.hidden = vocab_size, hidden
-        self.num_layers, self.num_heads = num_layers, num_heads
-        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
-        self.num_experts, self.experts_per_token = num_experts, experts_per_token
-        self.expert_dim = expert_dim
-        self.experts_held = tuple(experts_held or (0, num_experts))
-        self.vocab_held = tuple(vocab_held or (0, vocab_size))
-        for name, (lo, hi), whole in (
-                ("experts_held", self.experts_held, num_experts),
-                ("vocab_held", self.vocab_held, vocab_size)):
-            if not 0 <= lo < hi <= whole:
-                raise ValueError(f"{name}={lo, hi} is no range of {whole}")
         self.indexer_heads, self.indexer_dim = indexer_heads, indexer_dim
         self.indexer_topk, self.indexer_block = indexer_topk, indexer_block
-        self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
-        self.norm_topk_prob = norm_topk_prob
-        self.router_aux_weight = router_aux_weight
         self.indexer_loss_weight = indexer_loss_weight
-        self.max_len, self.remat = max_len, remat
-        self.head_block = head_block
-        super().__init__(compute_dtype)
-        self.graphdef = _Names(self.TENSORS)
+        super().__init__(vocab_size, hidden, num_layers, num_heads,
+                         num_kv_heads, head_dim, num_experts,
+                         experts_per_token, expert_dim, experts_held,
+                         vocab_held, rope_theta, rms_eps, norm_topk_prob,
+                         router_aux_weight, max_len, head_block, dropout,
+                         remat, compute_dtype)
 
-    # -- specs ---------------------------------------------------------------
-
-    @property
-    def held(self) -> int:
-        return self.experts_held[1] - self.experts_held[0]
-
-    @property
-    def vocab_here(self) -> int:
-        return self.vocab_held[1] - self.vocab_held[0]
-
-    def input_specs(self):
-        return {"input_ids": ((None, self.max_len), "int32")}
-
-    def _block_specs(self):
-        h, d, m = self.hidden, self.head_dim, self.expert_dim
-        n = "normal(0.02)"
+    def _attention_specs(self):
+        h, n = self.hidden, "normal(0.02)"
         return {
-            "ln1_scale": ((h,), "ones"),
-            "q_kernel": ((h, self.num_heads * d), n),
-            "k_kernel": ((h, self.num_kv_heads * d), n),
-            "v_kernel": ((h, self.num_kv_heads * d), n),
-            "q_norm": ((d,), "ones"), "k_norm": ((d,), "ones"),
-            "o_kernel": ((self.num_heads * d, h), n),
             "idx_q_kernel": ((h, self.indexer_heads * self.indexer_dim), n),
             "idx_k_kernel": ((h, self.indexer_dim), n),
             "idx_w_kernel": ((h, self.indexer_heads), n),
-            "ln2_scale": ((h,), "ones"),
-            "router": ((h, self.num_experts), n),
-            "experts_w1": ((self.held, h, m), n),
-            "experts_w3": ((self.held, h, m), n),
-            "experts_w2": ((self.held, m, h), n),
         }
-
-    def param_specs(self):
-        h = self.hidden
-        specs = {"embed": {"tok": ((self.vocab_here, h), "normal(0.02)")}}
-        for i in range(self.num_layers):
-            specs[f"block_{i}"] = self._block_specs()
-        specs["final_ln"] = {"scale": ((h,), "ones")}
-        specs["lm_head"] = {"kernel": ((h, self.vocab_here), "normal(0.02)")}
-        return specs
-
-    # -- the block, once -----------------------------------------------------
 
     def _attend(self, bp, y):
         """Steps 1-3 on ``y = RMSNorm(x) [B, S, h]``: the attention's output
-        before ``W_o``, each row's indexer loss ``[B]`` and the keys a query
-        selected (mean)."""
+        before ``W_o``, and in ``aux`` each row's indexer loss ``[B]`` and
+        the keys a query selected (mean)."""
         b, s, _ = y.shape
-        nq, nkv, d = self.num_heads, self.num_kv_heads, self.head_dim
         heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
-        q = heads(_dense(y, bp["q_kernel"]), nq)
-        k = heads(_dense(y, bp["k_kernel"]), nkv)
-        v = heads(_dense(y, bp["v_kernel"]), nkv)
-        q = rope(rms_norm(q, bp["q_norm"], self.rms_eps), self.rope_theta)
-        k = rope(rms_norm(k, bp["k_norm"], self.rms_eps), self.rope_theta)
-        q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+        q, k, v = self._qkv(bp, y)
 
         with jax.named_scope("indexer"):
             ys = jax.lax.stop_gradient(y)
@@ -235,60 +378,11 @@ class SparseMoELM(RegistryModel):
             target = sa.selected_probs(q, k, lse, mask)
             kl = sa.indexer_loss(qi, ki, w, mask, target, self.indexer_block)
             picked = jnp.mean(jnp.sum(mask.astype(jnp.float32), axis=-1))
-        att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, nq * d)
-        return att, kl, picked
-
-    def _experts(self, bp, y):
-        """Step 4 on ``y = RMSNorm(x) [B, S, h]``: the held experts' part of
-        the layer's output, each row's balance loss ``[B]`` and each held
-        expert's load ``[held]``."""
-        b, s, h = y.shape
-        with jax.named_scope("router"):
-            logits = jnp.matmul(y.reshape(b * s, h).astype(jnp.float32),
-                                bp["router"],
-                                precision=jax.lax.Precision.HIGHEST)
-            probs, gates, experts = gm.route_top_k(
-                logits, self.experts_per_token, self.norm_topk_prob)
-            balance = jax.vmap(gm.balance_loss)(
-                probs.reshape(b, s, -1), experts.reshape(b, s, -1))
-        with jax.named_scope("experts"):
-            out, load = gm.dropless_experts(
-                y.reshape(b * s, h), gates, experts, bp["experts_w1"],
-                bp["experts_w3"], bp["experts_w2"], self.experts_held[0])
-        return out.reshape(b, s, h), balance, load
-
-    def _block(self, bp, x):
-        """One layer on ``x [B, S, h]`` -> ``(x, aux)``; ``aux`` holds each
-        row's indexer and balance loss and the layer's counters."""
-        att, kl, picked = self._attend(
-            bp, rms_norm(x, bp["ln1_scale"], self.rms_eps))
-        x = x + _dense(att, bp["o_kernel"])
-        out, balance, load = self._experts(
-            bp, rms_norm(x, bp["ln2_scale"], self.rms_eps))
-        return x + out, dict(indexer=kl, balance=balance,
-                             selected_keys=picked, expert_load=load)
+        att = jnp.transpose(att, (0, 2, 1, 3)).reshape(
+            b, s, self.num_heads * self.head_dim)
+        return att, dict(indexer=kl, selected_keys=picked)
 
     # -- forward and loss ----------------------------------------------------
-
-    def _head(self, params, x):
-        """Final norm and the head over the vocabulary held: float32
-        logits."""
-        with jax.named_scope("lm_head"):
-            x = rms_norm(x, params["final_ln"]["scale"], self.rms_eps)
-            return jnp.matmul(x, params["lm_head"]["kernel"].astype(x.dtype),
-                              preferred_element_type=jnp.float32)
-
-    def _encode(self, params, ids):
-        with jax.named_scope("embed"):
-            x = self.cast(jnp.take(params["embed"]["tok"],
-                                   ids - self.vocab_held[0], axis=0))
-        block = (jax.checkpoint(self._block, policy=KEPT) if self.remat
-                 else self._block)
-        aux = []
-        for i in range(self.num_layers):
-            x, a = block(params[f"block_{i}"], x)
-            aux.append(a)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *aux)
 
     def _forward(self, params, feeds, train, rng):
         ids = feeds["input_ids"].astype(jnp.int32)
@@ -299,24 +393,11 @@ class SparseMoELM(RegistryModel):
 
     def _row_nll(self, params, x, ids):
         """Mean next-token cross-entropy of one row: ``x [S, h]`` (before the
-        final norm), ``ids [S]``. The head's float32 logits are made and
-        reduced a stretch of the row at a time (and again in the backward
-        pass): a whole row's are ``S x vocab`` floats, three times over."""
+        final norm), ``ids [S]``."""
         s = ids.shape[0]
-        c = self.head_block if s % self.head_block == 0 else s
         tgt = jnp.concatenate([ids[1:], ids[:1]]) - self.vocab_held[0]
         live = (jnp.arange(s) < s - 1).astype(jnp.float32)
-
-        @jax.checkpoint
-        def stretch(a):
-            xs, t, w = a
-            logits = self._head(params, xs)
-            picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-            return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * w)
-
-        split = lambda a: a.reshape((s // c, c) + a.shape[1:])
-        return jnp.sum(jax.lax.map(
-            stretch, (split(x), split(tgt), split(live)))) / (s - 1)
+        return self._weighted_nll(params, x, tgt, live) / (s - 1)
 
     def loss_and_metrics(self, params, feeds, train=True, rng=None):
         """Each row's loss ``[B]`` and the step's counters: per layer the
@@ -342,6 +423,3 @@ class SparseMoELM(RegistryModel):
         return loss, dict(expert_load=jnp.sum(load, axis=0),
                           selected_keys=jnp.mean(picked, axis=0),
                           pairs_routed=jnp.full((), pairs, jnp.int32))
-
-    def _loss(self, params, feeds, train, rng):
-        return self.loss_and_metrics(params, feeds, train, rng)[0]

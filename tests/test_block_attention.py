@@ -1,0 +1,163 @@
+"""``ops/block_attention.py``: the block-diffusion mask as a rule of the two
+indices, the tiles the kernels visit, and the kernels (interpreted on the
+CPU) against the plain ``jnp`` reference beside them, forward and both
+backward kernels."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparkflow_tpu.ops import block_attention as ba
+
+# (L, B, tile): tiles smaller than L, so that skipped tiles occur; 48 / 16
+# has a half row of three tiles, (32, 8) a block as wide as a tile (a noised
+# diagonal tile with every pair visible)
+SHAPES = [(32, 4, 8), (64, 8, 16), (48, 4, 16), (32, 8, 8)]
+
+
+def _mask_by_loops(length, block):
+    """The rule, spelt out pair by pair."""
+    m = np.zeros((2 * length, 2 * length), bool)
+    for p in range(2 * length):
+        for s in range(2 * length):
+            bp, bs = (p % length) // block, (s % length) // block
+            if p < length:
+                m[p, s] = s < length and bs <= bp
+            else:
+                m[p, s] = (s < length and bs < bp) or (s >= length
+                                                       and bs == bp)
+    return m
+
+
+@pytest.mark.parametrize("length,block,tile", SHAPES)
+def test_the_rule_is_the_issues_and_leaves_a_quarter_of_the_square(
+        length, block, tile):
+    mask = np.asarray(ba.visible(length, block))
+    np.testing.assert_array_equal(mask, _mask_by_loops(length, block))
+    assert mask.sum() == ba.visible_pairs(length, block)
+    assert mask.sum() == length * length + length * block
+    assert mask.diagonal().all()                   # every query sees itself
+    assert not mask[:length, length:].any()        # no clean query a noised key
+
+
+@pytest.mark.parametrize("order", ["qk", "kq"])
+@pytest.mark.parametrize("length,block,tile", SHAPES + [(32, 4, 4)])
+def test_the_kernels_visit_the_tiles_the_rule_leaves_and_no_other(
+        length, block, tile, order):
+    """The grid of a kernel is its schedule: each tile with a visible pair
+    once, none that the rule empties, and every run (a query tile's keys, or
+    a key tile's queries) in one piece with its ends flagged."""
+    mask = _mask_by_loops(length, block)
+    n = 2 * length // tile
+    by_rule = {(qi, ki) for qi in range(n) for ki in range(n)
+               if mask[qi * tile:(qi + 1) * tile,
+                       ki * tile:(ki + 1) * tile].any()}
+    qt, kt, first, last = ba.tile_schedule(length, block, tile, tile, order)
+    visited = list(zip(qt.tolist(), kt.tolist()))
+    assert len(visited) == len(by_rule) and set(visited) == by_rule
+    if tile % block == 0:
+        assert len(by_rule) < n * (n + 1) // 2     # fewer than causality's
+    runs = qt if order == "qk" else kt
+    starts = [i for i in range(len(runs)) if first[i]]
+    assert [runs[i] for i in starts] == sorted(set(runs.tolist()))
+    assert last.tolist() == first.tolist()[1:] + [1]
+    # a noised query tile: the clean tiles before its own and the one
+    # noised tile on its diagonal
+    if order == "qk" and tile % block == 0 and tile > block:
+        half = length // tile
+        for j in range(half):
+            keys = [k for q, k in visited if q == half + j]
+            assert keys == list(range(j + 1)) + [half + j]
+
+
+def _inputs(length, seed=0, hq=4, hkv=2, d=8, rows=2):
+    r = np.random.default_rng(seed)
+    mk = lambda h: jnp.asarray(r.normal(size=(rows, h, 2 * length, d)),
+                               jnp.float32)
+    return mk(hq), mk(hkv), mk(hkv), mk(hq)
+
+
+@pytest.fixture(scope="module", params=SHAPES,
+                ids=lambda s: "L%d-B%d-T%d" % s)
+def both(request):
+    """Outputs and gradients of the kernels and of the reference, once a
+    shape."""
+    length, block, tile = request.param
+    q, k, v, w = _inputs(length)
+
+    def run(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * w), (out, lse)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got = run(lambda q, k, v: ba.block_attention(
+            q, k, v, length, block, block_q=tile, block_k=tile))
+        want = run(lambda q, k, v: ba.block_attention_reference(
+            q, k, v, length, block))
+    return got, want
+
+
+# float32 on the CPU: the kernels sum a query's keys tile by tile under a
+# running maximum, the reference all at once; 1e-5 is some ten roundings of
+# values of order one
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv"])
+def test_kernels_match_the_reference_forward_and_backward(both, what):
+    ((_, (out, lse)), grads), ((_, (w_out, w_lse)), w_grads) = both
+    got = dict(out=out, lse=lse, dq=grads[0], dk=grads[1], dv=grads[2])[what]
+    want = dict(out=w_out, lse=w_lse, dq=w_grads[0], dk=w_grads[1],
+                dv=w_grads[2])[what]
+    assert float(jnp.max(jnp.abs(want))) > 1e-2
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(8, 16), (16, 8), (32, 32)])
+def test_query_and_key_tiles_need_not_be_equal(block_q, block_k):
+    q, k, v, _ = _inputs(32, seed=1)
+    with jax.default_matmul_precision("highest"):
+        out, lse = ba.block_attention(q, k, v, 32, 4, block_q=block_q,
+                                      block_k=block_k)
+        want, want_lse = ba.block_attention_reference(q, k, v, 32, 4)
+    np.testing.assert_allclose(out, want, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, atol=1e-5)
+
+
+def test_no_mask_operand_reaches_the_kernels():
+    """The mask is made in the kernel: the ``pallas_call``s take the four
+    tables of the schedule, ``q``, ``k``, ``v`` (and backward ``dO`` and the
+    row statistics) and nothing of ``[S, S]``."""
+    length = 32
+    q, k, v, _ = _inputs(length)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        ba.block_attention(q, k, v, length, 4, block_q=8, block_k=8)[0]),
+        argnums=(0, 1, 2)))(q, k, v)
+    calls = {}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] = [v.aval.shape for v in eqn.invars]
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (
+                        value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(calls) == ["block_attn_bwd_dkv", "block_attn_bwd_dq",
+                             "block_attn_fwd"]
+    for shapes in calls.values():
+        assert not any(s[-2:] == (2 * length, 2 * length) for s in shapes)
+
+
+@pytest.mark.parametrize("bad", [dict(length=30, block=4),
+                                 dict(length=24, block=3),
+                                 dict(length=32, block=4, block_q=12)])
+def test_shapes_that_are_no_whole_blocks_or_tiles_are_refused(bad):
+    q, k, v, _ = _inputs(bad["length"] if bad["length"] % 2 == 0 else 32)
+    with pytest.raises(ValueError, match="blocks of|do not divide"):
+        ba.block_attention(q, k, v, **bad)

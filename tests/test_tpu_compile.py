@@ -274,3 +274,54 @@ def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
                        r"custom_call_target=\"tpu_custom_call\"",
                        sparse_texts[name], re.M)
     assert any(name in c for c in calls), calls
+
+
+# -- the block-diffusion mask's attention ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def block_text(one_chip):
+    """Compiled text of the kernels ``block_diffusion_lm`` runs, at the
+    widths of ``chipbench/configs/sdar-30b-a3b-ep8.json``: one row of 4096
+    tokens as 8192 positions, blocks of 4, 32 query heads over 4 KV heads of
+    128, forward and both backward kernels."""
+    from sparkflow_tpu.ops import block_attention as B
+
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+
+    def attend(q, k, v):
+        return jax.grad(lambda q, k, v: B.block_attention(
+            q, k, v, 4096, 4, interpret=False)[0].astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return _compile(attend, sd((1, 32, 8192, 128)), sd((1, 4, 8192, 128)),
+                    sd((1, 4, 8192, 128)))
+
+
+@pytest.mark.parametrize("name", ["block_attn_fwd", "block_attn_bwd_dq",
+                                  "block_attn_bwd_dkv"])
+def test_block_attention_lowers_on_tpu_under_its_names(block_text, name):
+    """Each kernel of ``ops/block_attention.py`` compiles for the chip at the
+    configuration's widths (the mask made in the kernel from the indices, the
+    grid over the schedule's tiles), and its ``name=`` is inside the custom
+    call's instruction name, where the benchmark's readers look for it. No
+    name holds ``sparse_attn`` or ``flash``, which other readers match."""
+    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"",
+                       block_text, re.M)
+    assert any(name in c for c in calls), calls
+    assert not any("sparse_attn" in c or "flash" in c for c in calls), calls
+
+
+def test_another_block_length_and_head_layout_lower_on_tpu(one_chip):
+    """Blocks of 16 in a half row of three tiles, 8 query heads over one KV
+    head: another shift, another group."""
+    from sparkflow_tpu.ops import block_attention as B
+
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    text = _compile(lambda q, k, v: B.block_attention(
+        q, k, v, 1536, 16, interpret=False)[0], sd((1, 8, 3072, 128)),
+        sd((1, 1, 3072, 128)), sd((1, 1, 3072, 128)))
+    assert "block_attn_fwd" in text
