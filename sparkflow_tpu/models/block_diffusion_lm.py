@@ -164,8 +164,10 @@ class BlockDiffusionLM(MoEDecoder):
         """Each row's loss ``[rows]`` and the step's counters: per layer the
         pairs each held expert got (``expert_load [layers, held]``), the
         (position, expert) pairs the step routed in all (``pairs_routed``,
-        over all ``2 L`` positions of every row) and the positions that carry
-        loss (``masked_tokens``). Rows go through the model one after
+        over all ``2 L`` positions of every row), the rows of the experts'
+        buffers in use and in all (``expert_rows_live [layers]``,
+        ``expert_rows_bound``) and the positions that carry loss
+        (``masked_tokens``). Rows go through the model one after
         another, as in ``sparse_moe_lm``."""
         feeds = {k.split(":")[0]: v for k, v in feeds.items()}
         ids = feeds["input_ids"].astype(jnp.int32)
@@ -174,10 +176,10 @@ class BlockDiffusionLM(MoEDecoder):
             x, aux = self._encode(params, r[None])
             nll, masked = self._row_loss(params, x[0], r)
             loss = nll + self.router_aux_weight * jnp.sum(aux["balance"])
-            return loss, (aux["expert_load"], masked)
+            return loss, (aux["expert_load"], aux["expert_rows_live"],
+                          masked)
 
-        loss, (load, masked) = jax.lax.map(row, ids)
-        pairs = ids.shape[0] * ids.shape[1] * self.experts_per_token
+        loss, (load, live, masked) = jax.lax.map(row, ids)
         return loss, dict(expert_load=jnp.sum(load, axis=0),
-                          pairs_routed=jnp.full((), pairs, jnp.int32),
-                          masked_tokens=jnp.sum(masked))
+                          masked_tokens=jnp.sum(masked),
+                          **self._expert_counts(ids, live))

@@ -251,14 +251,27 @@ class MoEDecoder(RegistryModel):
 
     def _block(self, bp, x):
         """One layer on ``x [B, S, h]`` -> ``(x, aux)``; ``aux`` holds each
-        row's balance loss, the layer's expert load and what the family's
-        attention adds."""
+        row's balance loss, the layer's expert load, the rows of its experts'
+        buffer that are in use and what the family's attention adds."""
         att, aux = self._attend(
             bp, rms_norm(x, bp["ln1_scale"], self.rms_eps))
         x = x + _dense(att, bp["o_kernel"])
         out, balance, load = self._experts(
             bp, rms_norm(x, bp["ln2_scale"], self.rms_eps))
-        return x + out, dict(aux, balance=balance, expert_load=load)
+        return x + out, dict(aux, balance=balance, expert_load=load,
+                             expert_rows_live=gm.rows_live(load))
+
+    def _expert_counts(self, ids, live):
+        """The counters every family returns of a step's rows ``ids [rows,
+        S]``: the pairs routed in all, and of the experts' buffers the rows
+        in use by layer (``live [rows, L]`` summed) and the rows in all."""
+        rows, s = ids.shape
+        k, held = self.experts_per_token, self.held
+        return dict(
+            pairs_routed=jnp.full((), rows * s * k, jnp.int32),
+            expert_rows_live=jnp.sum(live, axis=0),
+            expert_rows_bound=jnp.full(
+                (), rows * gm.rows_bound(s, k, held), jnp.int32))
 
     def _head(self, params, x):
         """Final norm and the head over the vocabulary held: float32
@@ -402,8 +415,10 @@ class SparseMoELM(MoEDecoder):
     def loss_and_metrics(self, params, feeds, train=True, rng=None):
         """Each row's loss ``[B]`` and the step's counters: per layer the
         pairs each held expert got (``expert_load [L, held]``) and the keys
-        a query selected (``selected_keys [L]``), and the (token, expert)
-        pairs the step routed in all (``pairs_routed``). Rows go through the
+        a query selected (``selected_keys [L]``), the rows of the experts'
+        buffers the layer's kernels and row copies visited (``expert_rows_live
+        [L]``, of ``expert_rows_bound``, the buffers' size), and the (token,
+        expert) pairs the step routed in all (``pairs_routed``). Rows go through the
         model one after another: every part of the loss is a row's own, a
         row of 8k tokens fills the chip's matrix unit, and the buffers of a
         dropless layer are sized for the worst routing of the tokens they
@@ -416,10 +431,10 @@ class SparseMoELM(MoEDecoder):
             loss = (self._row_nll(params, x[0], r)
                     + self.indexer_loss_weight * jnp.sum(aux["indexer"])
                     + self.router_aux_weight * jnp.sum(aux["balance"]))
-            return loss, (aux["expert_load"], aux["selected_keys"])
+            return loss, (aux["expert_load"], aux["expert_rows_live"],
+                          aux["selected_keys"])
 
-        loss, (load, picked) = jax.lax.map(row, ids)
-        pairs = ids.shape[0] * ids.shape[1] * self.experts_per_token
+        loss, (load, live, picked) = jax.lax.map(row, ids)
         return loss, dict(expert_load=jnp.sum(load, axis=0),
                           selected_keys=jnp.mean(picked, axis=0),
-                          pairs_routed=jnp.full((), pairs, jnp.int32))
+                          **self._expert_counts(ids, live))

@@ -17,6 +17,7 @@ from sparkflow_tpu.models import (build_registry_spec, model_from_json,
                                   noise_rows)
 from sparkflow_tpu.models.sparse_moe_lm import MoEDecoder, SparseMoELM, rope
 from sparkflow_tpu.ops import block_attention as ba
+from sparkflow_tpu.ops import grouped_matmul as gm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 L, B, VOCAB, ALL, MASK = 32, 4, 48, 96, 90
@@ -99,6 +100,9 @@ def test_logits_and_row_losses_match_the_reference(seed):
     assert float(jnp.min(parts["balance"])) > 0     # the loss has both parts
     assert metrics["expert_load"].shape == (2, 4)
     assert int(metrics["pairs_routed"]) == 2 * 2 * L * 2
+    assert int(metrics["expert_rows_bound"]) == 2 * gm.rows_bound(2 * L, 2, 4)
+    np.testing.assert_array_equal(metrics["expert_rows_live"],
+                                  [2 * 4 * gm.TILE] * 2)
     assert int(metrics["masked_tokens"]) == int((rows[:, L:] == MASK).sum())
 
 
@@ -319,6 +323,9 @@ def test_trainer_fits_it_on_the_fused_path_and_returns_its_counters():
     assert again.losses[-1] < first.losses[0]
     assert first.metrics["expert_load"].shape == (2, 4, 2, 4)
     assert (first.metrics["pairs_routed"] == 2 * 2 * L * 2).all()
+    assert first.metrics["expert_rows_live"].shape == (2, 4, 2)
+    assert (first.metrics["expert_rows_live"].max()
+            <= first.metrics["expert_rows_bound"].min())
     # the positions that carry loss, step by step, as the rows hold them
     per_step = (rows[:, L:] == MASK).reshape(4, -1).sum(axis=-1)
     np.testing.assert_array_equal(first.metrics["masked_tokens"],

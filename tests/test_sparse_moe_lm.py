@@ -95,6 +95,11 @@ def test_logits_and_loss_match_the_reference(seed):
     assert float(jnp.min(parts["indexer"])) > 0     # the loss has all parts
     assert metrics["expert_load"].shape == (2, 4)
     assert int(metrics["pairs_routed"]) == 2 * S * 2
+    # of each layer's buffers (2 rows, the worst routing's size) the rows of
+    # the tiles in use: at this size one tile an expert
+    assert int(metrics["expert_rows_bound"]) == 2 * gm.rows_bound(S, 2, 4)
+    np.testing.assert_array_equal(metrics["expert_rows_live"],
+                                  [2 * 4 * gm.TILE] * 2)
 
 
 LEAVES = [f"block_0/{n}" for n in ref.param_shapes(toy_cfg())["block_0"]] + [
@@ -345,6 +350,9 @@ def test_trainer_fits_it_on_the_fused_path_and_returns_its_counters():
     assert first.metrics["expert_load"].shape == (2, 4, 2, z["e_held"])
     assert first.metrics["selected_keys"].shape == (2, 4, 2)
     assert (first.metrics["pairs_routed"] == 2 * S * 2).all()
+    assert first.metrics["expert_rows_live"].shape == (2, 4, 2)
+    assert (first.metrics["expert_rows_live"].max()
+            <= first.metrics["expert_rows_bound"].min())
     assert "no traced builds" in trainer.recompile_report
 
 

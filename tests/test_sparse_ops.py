@@ -214,8 +214,10 @@ def _routed(seed, n=24, e=8, k=2, first=2, held=4, h=16, m=8):
 def test_grouped_matmul_matches_its_reference(tile):
     x, gates, experts, (w1, _, _), first = _routed(tile)
     lay = gm.group_rows(experts, first, w1.shape[0], tile)
-    xe = gm.dispatch(x, lay.token_of_row, lay.row_of_pair)
+    xe = gm.dispatch(x, lay.token_of_row, lay.row_of_pair, lay.tiles_used,
+                     tile)
     live = gm.live_rows(xe.shape[0], lay.tiles_used, tile)
+    xe = jnp.where(live, xe, 0)
     args = (lay.tile_expert, lay.tiles_used, tile)
     kernel = lambda a, b: jnp.where(live, gm.grouped_matmul(a, b, *args), 0)
     plain = lambda a, b: gm.grouped_matmul_reference(a, b, *args)
@@ -243,3 +245,165 @@ def test_dropless_experts_match_their_reference(first, held):
     np.testing.assert_array_equal(load, want)
     _close_grads(lambda *a: jnp.sum(jnp.sin(kernel(*a))),
                  lambda *a: jnp.sum(jnp.sin(plain(*a))), (x, gates, *w), 5e-6)
+
+
+# -- the experts' rows: tokens -> rows -> tokens over the tiles in use --------
+
+TILE = 8
+SHARES = {"one_tile": (14, 2, -1.5), "a_fifth": (0, 2, 0.0),
+          "all_rows": (0, 16, 0.0)}
+
+
+def _laid_out(share, dtype, n=96, k=2, h=32, seed=0):
+    """A routing over 16 experts with ``held`` of them here from ``first``
+    (``SHARES``: the pairs here fill one tile an expert, about a fifth of
+    the buffer, all of it; the third number leans the router towards or
+    away from the held experts), its layout, tokens ``x``, gates, and a
+    buffer of rows' outputs poisoned past the tiles in use."""
+    first, held, lean = SHARES[share]
+    r = np.random.default_rng(seed)
+    logits = jnp.asarray(r.normal(size=(n, 16)), jnp.float32)
+    logits = logits.at[:, first:first + held].add(lean)
+    _, gates, experts = gm.route_top_k(logits, k)
+    lay = gm.group_rows(experts, first, held, TILE)
+    rows = lay.token_of_row.shape[0]
+    live = gm.live_rows(rows, lay.tiles_used, TILE)
+    x = jnp.asarray(r.normal(size=(n, h)), dtype)
+    out = jnp.where(live, jnp.asarray(r.normal(size=(rows, h)), dtype),
+                    jnp.nan)
+    return lay, live, x, gates, out
+
+
+def _where(lay):
+    return lay.token_of_row, lay.row_of_pair, lay.tiles_used, TILE
+
+
+def _plain_combine(out, gates, lay):
+    picked = gm._take(out, lay.row_of_pair).astype(jnp.float32)
+    return jnp.sum(picked * gates[..., None], axis=1).astype(out.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_rows_from_tokens_are_the_plain_gather_where_defined(share, dtype):
+    lay, live, x, gates, _ = _laid_out(share, dtype)
+    used = int(lay.tiles_used[0]) * TILE
+    assert {"one_tile": used == 2 * TILE,
+            "a_fifth": 0.1 < used / live.shape[0] < 0.3,
+            # every pair is here; the bound keeps a spare tile an expert
+            "all_rows": used >= 0.75 * live.shape[0]
+            and int(lay.load.sum()) == gates.size}[share]
+    got = gm.dispatch(x, *_where(lay))
+    want = gm._take(x, lay.token_of_row)
+    np.testing.assert_array_equal(np.asarray(got[:used], np.float32),
+                                  np.asarray(want[:used], np.float32))
+    # a padding row of a tile in use (an index equal to the length): zeros
+    padding = np.asarray(lay.token_of_row[:used]) == x.shape[0]
+    assert padding.any()
+    assert not np.asarray(got[:used], np.float32)[padding].any()
+    # with each row's gate as a multiplier, and each row's product with
+    # another buffer whose rows past the tiles in use are NaN
+    scale = gm._gate_of_row(gates, lay.row_of_pair, live.shape[0])
+    other = jnp.where(live, 1.5, jnp.nan).astype(dtype) * jnp.ones_like(got)
+    scaled, dots = gm._rows_in(x, lay.token_of_row, lay.tiles_used, TILE,
+                               True, scale=scale, dot_with=other)
+    np.testing.assert_allclose(
+        np.asarray(scaled[:used], np.float32),
+        np.asarray((want.astype(jnp.float32) * scale).astype(dtype)[:used],
+                   np.float32))
+    np.testing.assert_allclose(
+        dots[:used, 0], 1.5 * jnp.sum(want.astype(jnp.float32), -1)[:used],
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_tokens_from_rows_read_the_tiles_in_use_alone(share, dtype):
+    lay, live, x, gates, out = _laid_out(share, dtype, seed=1)
+    clean = jnp.where(live, out, 0)
+    eps = 1e-6 if dtype == jnp.float32 else 1e-2
+    # NaN past the tiles in use: a read there would show in every sum
+    np.testing.assert_allclose(
+        np.asarray(gm.combine(out, gates, *_where(lay)), np.float32),
+        np.asarray(_plain_combine(clean, gates, lay), np.float32),
+        atol=eps, rtol=eps)
+    np.testing.assert_allclose(
+        np.asarray(gm._rows_out(out, lay.token_of_row, lay.tiles_used,
+                                x.shape[0], TILE, True), np.float32),
+        np.asarray(jnp.sum(gm._take(clean, lay.row_of_pair).astype(
+            jnp.float32), axis=1).astype(dtype), np.float32),
+        atol=eps, rtol=eps)
+
+
+def test_an_index_equal_to_the_length_gives_zeros():
+    x = jnp.arange(1.0, 49.0).reshape(12, 4)
+    index = jnp.asarray([3, 12, 0, 12, 12, 11, 12, 5], jnp.int32)
+    used = jnp.ones((1,), jnp.int32)
+    got = gm._rows_in(x, index, used, TILE, True)[0]
+    np.testing.assert_array_equal(got, gm._take(x, index))
+    assert not np.asarray(got)[np.asarray(index) == 12].any()
+    back = gm._rows_out(got + 1.0, index, used, 12, TILE, True)
+    want = np.zeros((12, 4), np.float32)
+    for r, t in enumerate(np.asarray(index)):
+        if t < 12:
+            want[t] += np.asarray(got[r]) + 1.0
+    np.testing.assert_array_equal(back, want)
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_dispatch_and_combine_gradients_match_the_plain_forms(share):
+    lay, live, x, gates, out = _laid_out(share, jnp.float32, seed=2)
+    r = np.random.default_rng(3)
+    tilt_rows = jnp.asarray(r.normal(size=out.shape), jnp.float32)
+    tilt = jnp.asarray(r.normal(size=x.shape), jnp.float32)
+    _close_grads(
+        lambda a: jnp.sum(jnp.where(live, gm.dispatch(a, *_where(lay)), 0)
+                          * tilt_rows),
+        lambda a: jnp.sum(gm._take(a, lay.token_of_row)
+                          * jnp.where(live, tilt_rows, 0)), (x,), 1e-6)
+    clean = jnp.where(live, out, 0)
+    kernel = jax.grad(lambda o, g: jnp.sum(
+        jnp.sin(gm.combine(o, g, *_where(lay))) * tilt), argnums=(0, 1))
+    plain = jax.grad(lambda o, g: jnp.sum(
+        jnp.sin(_plain_combine(o, g, lay)) * tilt), argnums=(0, 1))
+    (d_out, d_gates), (d_out_want, d_gates_want) = (kernel(out, gates),
+                                                    plain(clean, gates))
+    np.testing.assert_allclose(jnp.where(live, d_out, 0), d_out_want,
+                               atol=1e-6)
+    np.testing.assert_allclose(d_gates, d_gates_want, atol=2e-6)
+    assert float(jnp.abs(d_gates_want).max()) > 0.1
+
+
+@pytest.mark.parametrize("first,held", [(0, 128), (0, 16)])
+def test_dropless_experts_of_128_match_their_reference(first, held):
+    """Every expert held (every pair here, every row in use) and one chip's
+    sixteenth-to-an-eighth share: the same kernels, whose work follows
+    ``tiles_used`` and the indices."""
+    r = np.random.default_rng(held)
+    n, k, h, m = 32, 8, 16, 8
+    _, gates, experts = gm.route_top_k(
+        jnp.asarray(r.normal(size=(n, 128)), jnp.float32), k)
+    x = jnp.asarray(r.normal(size=(n, h)), jnp.float32)
+    w = [jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+         for s in ((held, h, m), (held, h, m), (held, m, h))]
+    kernel = lambda x, g, *w: gm.dropless_experts(x, g, experts, *w, first,
+                                                  tile=TILE)[0]
+    plain = lambda x, g, *w: gm.dropless_experts_reference(x, g, experts, *w,
+                                                           first)
+    np.testing.assert_allclose(kernel(x, gates, *w), plain(x, gates, *w),
+                               atol=2e-6)
+    _close_grads(lambda *a: jnp.sum(jnp.sin(kernel(*a))),
+                 lambda *a: jnp.sum(jnp.sin(plain(*a))), (x, gates, *w), 5e-6)
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_rows_live_counts_the_tiles_in_use(share):
+    lay, live, x, _, _ = _laid_out(share, jnp.float32)
+    held = SHARES[share][1]
+    got = int(gm.rows_live(lay.load, TILE))
+    assert got == int(lay.tiles_used[0]) * TILE == int(live.sum())
+    assert got <= gm.rows_bound(x.shape[0], 2, held, TILE) == live.shape[0]
+    # by layer and row, as the models count it
+    np.testing.assert_array_equal(
+        gm.rows_live(jnp.stack([lay.load, 0 * lay.load]), TILE),
+        [got, held * TILE])

@@ -204,7 +204,8 @@ def sparse_texts(one_chip):
     """Compiled text of the kernels ``sparse_moe_lm`` runs, at the widths of
     ``chipbench/configs/keye-vl2-30b-a3b-ep8.json``: one row of 8192 tokens,
     32 query heads over 4 KV heads of 128; 16 experts of 2048 x 768 over the
-    worst case's rows."""
+    worst case's rows (69 632), and the tokens' movement into and out of
+    those rows."""
     from sparkflow_tpu.ops import grouped_matmul as G
     from sparkflow_tpu.ops import sparse_attention as S
 
@@ -236,6 +237,25 @@ def sparse_texts(one_chip):
             argnums=(0, 1))(x, w)
 
     product = _compile(experts, x, w, tiles, used)
+    tokens = sd((s, 2048), jnp.bfloat16)
+    gates = sd((s, 8), jnp.float32)
+    token_of_row = sd((rows,), jnp.int32)
+    row_of_pair = sd((s, 8), jnp.int32)
+
+    def move(tokens, x, gates, token_of_row, row_of_pair, used):
+        """Every form of the two row kernels: the plain copy and its
+        transpose (``dispatch``), the gate-weighted sum and its transpose
+        with the gates' gradient (``combine``)."""
+        where = (token_of_row, row_of_pair, used, G.TILE, False)
+
+        def loss(tokens, x, gates):
+            return (G.dispatch(tokens, *where).astype(jnp.float32).sum()
+                    + G.combine(x, gates, *where).astype(jnp.float32).sum())
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(tokens, x, gates)
+
+    movement = _compile(move, tokens, x, gates, token_of_row, row_of_pair,
+                        used)
     qi = sd((1, s, 16, 64), jnp.bfloat16)
     ki = sd((1, s, 64), jnp.bfloat16)
     wi = sd((1, s, 16), jnp.bfloat16)
@@ -256,13 +276,16 @@ def sparse_texts(one_chip):
     return {"sparse_attn_fwd": attention, "sparse_attn_bwd_dq": attention,
             "sparse_attn_bwd_dkv": attention, "sparse_attn_probs": attention,
             "expert_gmm": product, "expert_tgmm": product,
+            "expert_rows_in": movement, "expert_rows_out": movement,
             "index_select": indexer, "index_kl_fwd": indexer,
             "index_kl_bwd_dq": indexer, "index_kl_bwd_dk": indexer}
 
 
 @pytest.mark.parametrize("name", ["sparse_attn_fwd", "sparse_attn_bwd_dq",
                                   "sparse_attn_bwd_dkv", "sparse_attn_probs",
-                                  "expert_gmm", "expert_tgmm", "index_select",
+                                  "expert_gmm", "expert_tgmm",
+                                  "expert_rows_in", "expert_rows_out",
+                                  "index_select",
                                   "index_kl_fwd", "index_kl_bwd_dq",
                                   "index_kl_bwd_dk"])
 def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
