@@ -21,7 +21,10 @@ rotary, grouped query heads, a learned top-k key selection and dropless
 SiLU-gated experts of which a share may be held; training path only),
 ``block_diffusion_lm`` (the same decoder trained by block diffusion: a clean
 and a noised copy of every row under one block-structured attention mask, a
-masked-token loss; training path only).
+masked-token loss; training path only), ``looped_lm`` (one stack of
+sandwich-norm blocks run several times with the same weights, a head and an
+exit gate after every pass, the expected loss under the exit distribution;
+training path only).
 """
 
 from .registry import model_from_json, register_model, build_registry_spec
@@ -30,12 +33,13 @@ from .transformer import TransformerClassifier, TransformerLM
 from .moe import MoETransformerLM
 from .sparse_moe_lm import SparseMoELM
 from .block_diffusion_lm import BlockDiffusionLM, noise_rows
+from .looped_lm import LoopedLM
 from .resnet import ResNet
 from .rnn import RNNClassifier, RNNLM
 
 __all__ = [
     "model_from_json", "register_model", "build_registry_spec", "presets",
     "TransformerClassifier", "TransformerLM", "MoETransformerLM",
-    "SparseMoELM", "BlockDiffusionLM", "noise_rows", "ResNet",
+    "SparseMoELM", "BlockDiffusionLM", "noise_rows", "LoopedLM", "ResNet",
     "RNNClassifier", "RNNLM",
 ]

@@ -60,36 +60,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import grouped_matmul as gm
 from ..ops import sparse_attention as sa
 from .base import RegistryModel, _Names
+from .lm_ops import dense as _dense
+from .lm_ops import head_logits, rms_norm, rope, weighted_nll
 from .registry import register_model
-
-
-def rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
-                                     keepdims=True) + eps) * scale
-    return y.astype(x.dtype)
-
-
-def rope(x, theta: float, positions=None):
-    """Rotary positions over the whole last axis of ``x [B, S, ..., D]``
-    (rotate-half); the position of index ``i`` along axis 1 is
-    ``positions[i]``, ``i`` itself by default; computed in float32."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    if positions is None:
-        positions = jnp.arange(s, dtype=jnp.float32)
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    ang = ang.reshape((1, s) + (1,) * (x.ndim - 3) + (d // 2,))
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
-    return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-            ).astype(x.dtype)
-
-
-def _dense(x, kernel):
-    return jnp.matmul(x, kernel.astype(x.dtype))
 
 
 # What a block's checkpoint keeps by name for its backward pass (the module's
@@ -115,7 +88,8 @@ class MoEDecoder(RegistryModel):
     and rotary positions (:meth:`_qkv`), its router and experts
     (:meth:`_experts`), the residual wiring (:meth:`_block`), the stack of
     checkpointed blocks (:meth:`_encode`), the head and a row's weighted
-    cross-entropy a stretch at a time (:meth:`_weighted_nll`). A family says
+    cross-entropy a stretch at a time (:meth:`_weighted_nll`; both through
+    ``lm_ops.py``, which ``looped_lm.py`` calls too). A family says
     which keys a query sees (:meth:`_attend`), where an index of the row is
     (:meth:`_positions`), what a block's checkpoint keeps (``KEPT``), how an
     id finds its embedding row (:meth:`_embed_index`) and what a row's loss
@@ -277,9 +251,9 @@ class MoEDecoder(RegistryModel):
         """Final norm and the head over the vocabulary held: float32
         logits."""
         with jax.named_scope("lm_head"):
-            x = rms_norm(x, params["final_ln"]["scale"], self.rms_eps)
-            return jnp.matmul(x, params["lm_head"]["kernel"].astype(x.dtype),
-                              preferred_element_type=jnp.float32)
+            return head_logits(
+                rms_norm(x, params["final_ln"]["scale"], self.rms_eps),
+                params["lm_head"]["kernel"])
 
     def _embed_index(self, ids):
         """The embedding row of each id."""
@@ -300,22 +274,10 @@ class MoEDecoder(RegistryModel):
     def _weighted_nll(self, params, x, tgt, weight):
         """``sum_i weight[i] * cross-entropy(head(x[i]), tgt[i])`` of one
         row: ``x [S, h]`` (before the final norm), ``tgt [S]`` columns of the
-        head. The head's float32 logits are made and reduced a stretch of the
-        row at a time (and again in the backward pass): a whole row's are ``S
-        x vocab`` floats, three times over."""
-        s = tgt.shape[0]
-        c = self.head_block if s % self.head_block == 0 else s
-
-        @jax.checkpoint
-        def stretch(a):
-            xs, t, w = a
-            logits = self._head(params, xs)
-            picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-            return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * w)
-
-        split = lambda a: a.reshape((s // c, c) + a.shape[1:])
-        return jnp.sum(jax.lax.map(
-            stretch, (split(x), split(tgt), split(weight))))
+        head; ``head_block`` positions at a time
+        (:func:`~sparkflow_tpu.models.lm_ops.weighted_nll`)."""
+        return weighted_nll(lambda xs: self._head(params, xs), x, tgt, weight,
+                            self.head_block)
 
     def _loss(self, params, feeds, train, rng):
         return self.loss_and_metrics(params, feeds, train, rng)[0]
