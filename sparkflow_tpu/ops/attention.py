@@ -182,11 +182,15 @@ def record_attention_paths():
         _PATH_LOG.reset(tok)
 
 
-def _note_path(kernel: str, path: str) -> None:
-    _LAST_PATH.set(path)
+def _log_path(kernel: str, path: str) -> None:
     log = _PATH_LOG.get()
     if log is not None:
         log.append(f"{kernel}:{path}")
+
+
+def _note_path(kernel: str, path: str) -> None:
+    _LAST_PATH.set(path)
+    _log_path(kernel, path)
 
 
 _WARNED: set = set()
@@ -383,13 +387,28 @@ def _blockwise_attention(q, k, v, kv_mask, causal, scale, block_k=512):
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash attention backward (dq and dk/dv kernels, flash-style recompute)
+# Pallas flash attention backward (flash-style recompute)
 #
 # Standard recurrence (Dao, FlashAttention-2): with row stats L = logsumexp
 # saved by the forward and D_i = rowsum(dO_i * O_i),
 #   P   = exp(S - L);  dV = P^T dO;  dP = dO V^T
 #   dS  = P * (dP - D);  dQ = scale * dS K;  dK = scale * dS^T Q
 # The S x S matrices exist only block-by-block in VMEM, same as the forward.
+#
+# Two schedules of the same tile arithmetic (_bwd_tile), chosen by shape in
+# _flash_pallas_backward_flat:
+# - fused, ``flash_bwd_dqkv``: one kernel on the grid (bh, ki, qi). A visited
+#   tile's P and dS are made once and feed all three sums: dK/dV accumulate
+#   over qi in [block_k, d] scratch, dQ over ki in a float32 scratch that
+#   holds the head's whole [s, d] (the TPU grid is sequential, so it stays in
+#   VMEM from the head's first key tile to its last).
+# - split, ``flash_bwd_dq`` (grid (bh, qi, ki)) and ``flash_bwd_dkv`` (grid
+#   (bh, ki, qi)): each makes P and dS for itself, 7 products a tile where
+#   the fused kernel has 5; no buffer grows with s, so this is the schedule
+#   of sequences whose dQ does not fit _FUSED_DQ_VMEM_BUDGET.
+# Both add a tile's terms in the same order (dQ over key tiles ascending,
+# dK/dV over query tiles ascending): at equal tiles their results are
+# bit-equal.
 # ---------------------------------------------------------------------------
 
 
@@ -409,6 +428,46 @@ def _bwd_p_block(q, k, lse, sm_scale, causal, qi0, ki0, mask_blk):
     return jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - lse), 0.0)
 
 
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+              qi, ki, sm_scale, causal, block_q, block_k):
+    """One visited tile's P and dS, [block_q, block_k] float32, and dO as
+    the float32 operand the sums take it as."""
+    do = do_ref[0].astype(jnp.float32)
+    p = _bwd_p_block(q_ref[0], k_ref[0], lse_ref[0], sm_scale, causal,
+                     qi * block_q, ki * block_k,
+                     mask_ref[0] if mask_ref is not None else None)
+    dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
+                             (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta_ref[0]), do
+
+
+def _when_visited(causal, qi, ki, block_q, block_k, compute):
+    """Run ``compute`` unless the tile lies entirely above the causal
+    diagonal, where P = 0 and every sum gets nothing."""
+    if causal:
+        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(compute)
+    else:
+        compute()
+
+
+def _dq_term(ds, k_ref, sm_scale):
+    """dQ_i's term of one tile: scale * dS K."""
+    return sm_scale * jax.lax.dot_general(
+        ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _dkv_terms(p, ds, do, q_ref, sm_scale):
+    """dK_j's and dV_j's terms of one tile: scale * dS^T Q and P^T dO."""
+    dv = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dk = sm_scale * jax.lax.dot_general(
+        ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return dk, dv
+
+
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          *rest, sm_scale, causal, block_q, block_k, has_mask):
     if has_mask:
@@ -425,27 +484,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        p = _bwd_p_block(q, k, lse_ref[0], sm_scale, causal,
-                         qi * block_q, ki * block_k,
-                         mask_ref[0] if mask_ref is not None else None)
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        dq_acc[:] += sm_scale * jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _, ds, _ = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             mask_ref, qi, ki, sm_scale, causal, block_q,
+                             block_k)
+        dq_acc[:] += _dq_term(ds, k_ref, sm_scale)
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _when_visited(causal, qi, ki, block_q, block_k, _compute)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -469,36 +513,67 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        p = _bwd_p_block(q, k, lse_ref[0], sm_scale, causal,
-                         qi * block_q, ki * block_k,
-                         mask_ref[0] if mask_ref is not None else None)
-        # dV_j += P^T dO ; dK_j += scale * dS^T Q
-        dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        dk_acc[:] += sm_scale * jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p, ds, do = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                              mask_ref, qi, ki, sm_scale, causal, block_q,
+                              block_k)
+        dk, dv = _dkv_terms(p, ds, do, q_ref, sm_scale)
+        dk_acc[:] += dk
+        dv_acc[:] += dv
 
-    if causal:
-        # q blocks entirely above this k block's diagonal see p = 0
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
+    # q blocks entirely above this k block's diagonal see p = 0
+    _when_visited(causal, qi, ki, block_q, block_k, _compute)
 
     @pl.when(qi == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           *rest, sm_scale, causal, block_q, block_k,
+                           has_mask):
+    if has_mask:
+        mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+        mask_ref = None
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+    # tile qi's rows of the head's dQ: dq_acc and dq_ref hold all s of them
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _compute():
+        p, ds, do = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                              mask_ref, qi, ki, sm_scale, causal, block_q,
+                              block_k)
+        dk, dv = _dkv_terms(p, ds, do, q_ref, sm_scale)
+        dk_acc[:] += dk
+        dv_acc[:] += dv
+        dq_acc[rows, :] += _dq_term(ds, k_ref, sm_scale)
+
+    _when_visited(causal, qi, ki, block_q, block_k, _compute)
+
+    @pl.when(qi == nq - 1)
+    def _finalize_dkv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    # the dQ block's index follows bh alone: it leaves VMEM after the head's
+    # last grid step, by when the last key tile has written every row
+    @pl.when(ki == nk - 1)
+    def _finalize_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd_prep(q, out, lse, g):
@@ -533,8 +608,81 @@ def _flash_pallas_backward(q, k, v, kv_mask, out, lse, g, causal, scale,
             dv.reshape(b, h, sk, d))
 
 
+# What the fused backward may keep in VMEM for one head's dQ: the float32
+# accumulator and the two buffers of its output block, each [s, d] with d
+# padded to the 128 lanes. 16 MiB holds s = 16 384 in bfloat16 and 8 192 in
+# float32 at any d <= 128 (on a v5e the fused kernel is 0.67-0.70 x the pair
+# up to there: PERF.md section 6, PR 37); the kernel's tiles and operands
+# need 10 MB more (_FUSED_VMEM_LIMIT, of the chip's 128 MiB).
+_FUSED_DQ_VMEM_BUDGET = 16 * 1024 * 1024
+_FUSED_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _bwd_is_fused(s: int, d: int, dtype) -> bool:
+    lanes = -(-d // 128) * 128
+    dq_bytes = s * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return dq_bytes <= _FUSED_DQ_VMEM_BUDGET
+
+
 def _flash_pallas_backward_flat(qf, kf, vf, gf, lsef, delta, maskf, h,
                                 causal, scale, block_q, block_k, interpret):
+    """dQ, dK, dV of flat ``[bh, s, d]`` operands: the fused kernel where a
+    head's dQ fits its VMEM budget, the dq and dkv kernels otherwise."""
+    _, s, d = qf.shape
+    fused = _bwd_is_fused(s, d, qf.dtype)
+    _log_path("flash_attention_bwd", "fused" if fused else "split")
+    backward = _flash_bwd_fused_flat if fused else _flash_bwd_split_flat
+    return backward(qf, kf, vf, gf, lsef, delta, maskf, h, causal, scale,
+                    block_q, block_k, interpret)
+
+
+def _bwd_kq_operands(qf, kf, vf, gf, lsef, delta, maskf, h, block_q, block_k):
+    """Input block specs and operands on the grid (bh, ki, qi), and the key
+    tile's spec, which is also dK's and dV's output spec."""
+    d = qf.shape[2]
+    qspec = pl.BlockSpec((1, block_q, d), lambda bh_, ki, qi: (bh_, qi, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda bh_, ki, qi: (bh_, ki, 0))
+    row_q = _row_stat_spec(block_q, "kq")
+    in_specs = [qspec, kspec, kspec, qspec, row_q, row_q]
+    args = [qf, kf, vf, gf, lsef, delta]
+    if maskf is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_k), lambda bh_, ki, qi, _h=h: (bh_ // _h, 0, ki)))
+        args.append(maskf)
+    return in_specs, args, kspec
+
+
+def _flash_bwd_fused_flat(qf, kf, vf, gf, lsef, delta, maskf, h, causal,
+                          scale, block_q, block_k, interpret):
+    bh, s, d = qf.shape
+    sk = kf.shape[1]
+    in_specs, args, kspec = _bwd_kq_operands(qf, kf, vf, gf, lsef, delta,
+                                             maskf, h, block_q, block_k)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dqkv_kernel, sm_scale=scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          has_mask=maskf is not None),
+        name="flash_bwd_dqkv",
+        grid=(bh, sk // block_k, s // block_q),
+        in_specs=in_specs,
+        out_specs=(pl.BlockSpec((1, s, d), lambda bh_, ki, qi: (bh_, 0, 0)),
+                   kspec, kspec),
+        out_shape=(jax.ShapeDtypeStruct((bh, s, d), qf.dtype),
+                   jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
+                   jax.ShapeDtypeStruct((bh, sk, d), vf.dtype)),
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        # dQ sums over ki and dK/dV over qi: only the heads are independent
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+        interpret=interpret,
+    )(*args)
+
+
+def _flash_bwd_split_flat(qf, kf, vf, gf, lsef, delta, maskf, h, causal,
+                          scale, block_q, block_k, interpret):
     bh, s, d = qf.shape
     sk = kf.shape[1]
     has_mask = maskf is not None
@@ -568,19 +716,8 @@ def _flash_pallas_backward_flat(qf, kf, vf, gf, lsef, delta, maskf, h,
         interpret=interpret,
     )(*args_dq)
 
-    kspec = pl.BlockSpec((1, block_k, d), lambda bh_, ki, qi: (bh_, ki, 0))
-    in_specs_kv = [
-        pl.BlockSpec((1, block_q, d), lambda bh_, ki, qi: (bh_, qi, 0)),
-        kspec, kspec,
-        pl.BlockSpec((1, block_q, d), lambda bh_, ki, qi: (bh_, qi, 0)),
-        _row_stat_spec(block_q, "kq"),
-        _row_stat_spec(block_q, "kq"),
-    ]
-    args_kv = [qf, kf, vf, gf, lsef, delta]
-    if has_mask:
-        in_specs_kv.append(pl.BlockSpec(
-            (1, 1, block_k), lambda bh_, ki, qi, _h=h: (bh_ // _h, 0, ki)))
-        args_kv.append(maskf)
+    in_specs_kv, args_kv, kspec = _bwd_kq_operands(
+        qf, kf, vf, gf, lsef, delta, maskf, h, block_q, block_k)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
         name="flash_bwd_dkv",
@@ -647,14 +784,21 @@ def flash_attention(q, k, v, causal: bool = False,
 
     Forward runs the pallas kernel on TPU when the sequence tiles cleanly
     (otherwise the jnp reference path — numerics match to fp tolerance).
-    Backward goes through a custom VJP with its own pallas dq/dk/dv kernels.
+    Backward goes through a custom VJP with its own pallas kernels: one,
+    ``flash_bwd_dqkv``, that makes each probability tile once for dQ, dK and
+    dV where a head's whole dQ fits its VMEM budget (rows up to 16 384 keys
+    in bfloat16), and the ``flash_bwd_dq`` / ``flash_bwd_dkv`` pair past it;
+    the shape decides, and ``record_attention_paths()`` says which.
 
     ``block_q``/``block_k`` default to an auto choice PER DIMENSION AND PATH:
     the forward kernel prefers the largest tiles that divide the sequence
     (up to 1024 — measured ~2x faster than 512x512 at seq 4096 on v5e),
-    while the backward kernels prefer 512 (the dq and dkv grids re-stream
-    more operands per tile, so bigger tiles lose). An explicitly passed
-    value pins that dimension on BOTH paths; the other stays auto.
+    while the backward kernels prefer 512 x 512: four float32 tiles live at
+    once, and under a causal mask a wider key tile computes more of the
+    square above the diagonal. From 4096 keys on the fused kernel takes key
+    tiles of 1024 (a tenth faster at 4096 keys on v5e, a fiftieth slower at
+    2048: PERF.md section 6, PR 37). An explicitly passed value pins that
+    dimension on BOTH paths; the other stays auto.
     """
     b, h, s, d = q.shape
     sk = k.shape[2]
@@ -665,8 +809,10 @@ def flash_attention(q, k, v, causal: bool = False,
         interpret = jax.default_backend() != "tpu"
     # p-tile is block_q*block_k f32: cap the product at 2^20 (4 MB VMEM)
     cap = 1024 if d <= 128 else 512
+    bwd_cap_k = cap if _bwd_is_fused(s, d, q.dtype) and sk >= 4096 else 512
     bwd_block_q = min(block_q, s) if block_q is not None else _auto_block(s, 512)
-    bwd_block_k = min(block_k, sk) if block_k is not None else _auto_block(sk, 512)
+    bwd_block_k = (min(block_k, sk) if block_k is not None
+                   else _auto_block(sk, bwd_cap_k))
     block_q = min(block_q, s) if block_q is not None else _auto_block(s, cap)
     block_k = min(block_k, sk) if block_k is not None else _auto_block(sk, cap)
     # the XLA blockwise path materializes [B,H,S,block_k] f32 score blocks

@@ -193,6 +193,85 @@ def test_flash_bwd_bf16():
                                    np.asarray(b, np.float32), atol=0.15)
 
 
+def _flash_bwd_case(s, sk, d, dtype, causal, mask, block=128):
+    """Flat operands of one backward pass, and the reference's gradients
+    under the same non-uniform cotangent."""
+    from sparkflow_tpu.ops import attention as A
+
+    b, h = 2, 2
+    rs = np.random.RandomState(11)
+    q = jnp.asarray(rs.randn(b, h, s, d), dtype)
+    k, v = (jnp.asarray(rs.randn(b, h, sk, d), dtype) for _ in range(2))
+    g = jnp.asarray(rs.randn(b, h, s, d), dtype)
+    # key 0 stays: a causal row with no key left is the reference's garbage
+    kv_mask = (jnp.asarray((rs.rand(b, sk) > 0.3).astype(np.float32))
+               .at[:, 0].set(1.0) if mask else None)
+    scale = 1.0 / np.sqrt(d)
+    out, lse = A._flash_pallas_forward(q, k, v, kv_mask, causal, scale,
+                                       min(block, s), block, True,
+                                       with_lse=True)
+    qf, gf, lsef, delta = A._flash_bwd_prep(q, out, lse, g)
+    flat = (qf, k.reshape(b * h, sk, d), v.reshape(b * h, sk, d), gf, lsef,
+            delta, None if kv_mask is None else kv_mask[:, None, :], h,
+            causal, scale, min(block, s), block, True)
+    _, vjp = jax.vjp(lambda a, b_, c: attention_reference(
+        a, b_, c, causal, scale, kv_mask=kv_mask), q, k, v)
+    return flat, [r.reshape(b * h, -1, d) for r in vjp(g)]
+
+
+@pytest.mark.parametrize("mask", [False, True], ids=["nomask", "kv_mask"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("s,sk,d,dtype", [
+    (256, 256, 64, jnp.float32), (256, 256, 128, jnp.float32),
+    (256, 256, 64, jnp.bfloat16), (256, 256, 128, jnp.bfloat16),
+    (128, 384, 64, jnp.float32), (384, 256, 128, jnp.bfloat16)],
+    ids=["d64-f32", "d128-f32", "d64-bf16", "d128-bf16", "s128-sk384",
+         "s384-sk256"])
+def test_flash_bwd_fused_bit_equal_to_split(s, sk, d, dtype, causal, mask):
+    """``flash_bwd_dqkv`` makes a tile's P and dS once where ``flash_bwd_dq``
+    and ``flash_bwd_dkv`` each make them: the same arithmetic in the same
+    order of accumulation, so dQ, dK, dV agree to the bit (several key tiles
+    and several query tiles in every case), and both match the reference's
+    gradients to the tolerances the backward is held to elsewhere."""
+    from sparkflow_tpu.ops import attention as A
+
+    flat, want = _flash_bwd_case(s, sk, d, dtype, causal, mask)
+    fused = A._flash_bwd_fused_flat(*flat)
+    split = A._flash_bwd_split_flat(*flat)
+    atol = 2e-3 if dtype == jnp.float32 else 0.15
+    for f, p, w in zip(fused, split, want):
+        assert f.dtype == p.dtype == dtype and f.shape == p.shape
+        np.testing.assert_array_equal(np.asarray(f, np.float32),
+                                      np.asarray(p, np.float32))
+        np.testing.assert_allclose(np.asarray(f, np.float32),
+                                   np.asarray(w, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("b,h,s,d,dtype,path", [
+    (4, 16, 1024, 64, jnp.bfloat16, "fused"),      # train-gpt2m's pass
+    (2, 16, 4096, 128, jnp.bfloat16, "fused"),     # train-ouro-seq4k's
+    (1, 2, 16384, 128, jnp.bfloat16, "fused"),     # the budget to the byte
+    (1, 2, 16384, 128, jnp.float32, "split"),
+    (1, 8, 32768, 64, jnp.bfloat16, "split")],
+    ids=["gpt2m", "ouro-4k", "16k-bf16", "16k-f32", "32k"])
+def test_flash_bwd_schedule_follows_the_heads_dq(b, h, s, d, dtype, path):
+    """The backward takes the fused kernel where one head's dQ (float32
+    accumulator and both buffers of its output block, lanes padded) fits
+    ``_FUSED_DQ_VMEM_BUDGET`` and the dq/dkv pair past it: decided from s, d
+    and the dtype alone, recorded beside the entry point's own path (which
+    ``last_attention_path`` keeps reporting). Traced, not run."""
+    from sparkflow_tpu.ops import attention as A
+
+    x = jax.ShapeDtypeStruct((b, h, s, d), dtype)
+    with A.record_attention_paths() as paths:
+        jax.make_jaxpr(jax.grad(lambda q, k, v: A.flash_attention(
+            q, k, v, causal=True, interpret=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(x, x, x)
+    assert paths == ["flash_attention:pallas", f"flash_attention_bwd:{path}"]
+    assert A.last_attention_path() == "pallas"
+    assert A._bwd_is_fused(s, d, dtype) == (path == "fused")
+
+
 def test_ring_flash_matches_ring_and_reference(dp_mesh):
     """ring_flash_attention (pallas per-visit blocks + lse merge) must equal
     plain ring attention and the dense reference, causal and not, fwd + bwd."""

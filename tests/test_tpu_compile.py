@@ -57,6 +57,12 @@ def _compile(fn, *structs):
     return jax.jit(fn).lower(*structs).compile().as_text()
 
 
+def _custom_calls(text):
+    """Instruction names of the compiled text's pallas kernels."""
+    return re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
+                      r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+
+
 def _flash_structs(sh, b, h, s, d, dtype, mask):
     q = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=sh)
     m = jax.ShapeDtypeStruct((b, s), jnp.float32, sharding=sh)
@@ -88,8 +94,35 @@ def test_flash_lowers_on_tpu(one_chip, b, h, s, d, dtype, causal, mask):
 
     assert "tpu_custom_call" in _compile(fwd, *structs)
     assert A.last_attention_path() == "pallas"
-    # dq and dk/dv kernels, plus the recomputed forward
-    assert _compile(fwd_bwd, *structs).count("tpu_custom_call") >= 3
+    # the forward again, and the fused backward kernel or, where a head's dQ
+    # is past its VMEM budget (the 32k row), the dq and dk/dv kernels
+    fused = A._bwd_is_fused(s, d, dtype)
+    assert fused == (s < 32768)
+    assert (_compile(fwd_bwd, *structs).count("tpu_custom_call")
+            >= (2 if fused else 3))
+
+
+# one backward pass of the two dense cells: train-gpt2m (4 rows of 16 heads of
+# 64 over 1024 keys) and train-ouro-seq4k (2 rows of 16 heads of 128 over 4096)
+@pytest.mark.parametrize("b,h,s,d", [(4, 16, 1024, 64), (2, 16, 4096, 128)],
+                         ids=["train-gpt2m", "train-ouro-seq4k"])
+def test_fused_flash_backward_lowers_at_the_cells_shapes(one_chip, b, h, s, d):
+    """``flash_bwd_dqkv`` compiles for the chip at the cells' shapes (the
+    head's whole dQ in VMEM beside the 512 x 512 tiles), under a name that
+    holds ``flash_bwd_`` for the benchmark's reader, and takes the place of
+    both ``flash_bwd_dq`` and ``flash_bwd_dkv``."""
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *qkv: A.flash_attention(
+            *qkv, causal=True, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    with A.record_attention_paths() as paths:
+        text = _compile(fwd_bwd, *_flash_structs(one_chip, b, h, s, d,
+                                                 jnp.bfloat16, False))
+    assert paths == ["flash_attention:pallas", "flash_attention_bwd:fused"]
+    calls = _custom_calls(text)
+    assert len(calls) == 2 and any("flash_fwd" in c for c in calls), calls
+    assert any("flash_bwd_dqkv" in c for c in calls), calls
 
 
 def _paged_structs(sh, h, d, page, pool_dtype, num_q, q_dtype=jnp.bfloat16,
@@ -167,31 +200,35 @@ def test_paged_gate_is_the_compilers(one_chip, num_q):
 
 @pytest.fixture(scope="module")
 def kernel_texts(one_chip):
-    """Compiled text of flash forward+backward and of both paged kernels."""
+    """Compiled text of flash forward+backward (GPT-2 small's shape, whose
+    backward is the fused kernel, and a 32k row, whose backward is the dq and
+    dkv kernels) and of both paged kernels."""
     def fwd_bwd(q, k, v):
         return jax.grad(lambda *qkv: A.flash_attention(
             *qkv, causal=True, interpret=False).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    flash = _compile(fwd_bwd, *_flash_structs(one_chip, 8, 12, 1024, 64,
-                                              jnp.bfloat16, False))
+    flash, long = (_compile(fwd_bwd, *_flash_structs(one_chip, *shape,
+                                                     jnp.bfloat16, False))
+                   for shape in ((8, 12, 1024, 64), (1, 8, 32768, 64)))
     paged = [_compile(_paged_fn(num_q), *_paged_structs(
         one_chip, 12, 64, 16, jnp.bfloat16, num_q)) for num_q in (None, 5)]
-    return {"flash_fwd": flash, "flash_bwd_dq": flash, "flash_bwd_dkv": flash,
+    return {"flash_fwd": flash, "flash_bwd_dqkv": flash,
+            "flash_bwd_dq_": long, "flash_bwd_dkv": long,
             "paged_decode": paged[0], "paged_verify": paged[1]}
 
 
-@pytest.mark.parametrize("name", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+@pytest.mark.parametrize("name", ["flash_fwd", "flash_bwd_dqkv",
+                                  "flash_bwd_dq_", "flash_bwd_dkv",
                                   "paged_decode", "paged_verify"])
 def test_kernels_keep_their_names(kernel_texts, name):
     """Each ``pallas_call`` carries a ``name=``, and the compiler keeps it
     inside the custom call's instruction name, wrapped in the transforms
     around it (``%jvp_flash_fwd_.1``): a trace's reader matches by
-    *contains* (``chipbench/trace_reads.py``). Unnamed, the three flash
-    kernels were ``jvp__.N`` / ``transpose_jvp___.N``."""
-    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
-                       r"custom_call_target=\"tpu_custom_call\"",
-                       kernel_texts[name], re.M)
+    *contains* (``chipbench/trace_reads.py``). Unnamed, the flash kernels
+    were ``jvp__.N`` / ``transpose_jvp___.N``. (``flash_bwd_dq_``, with the
+    wrapper's underscore, is the dq kernel and not ``flash_bwd_dqkv``.)"""
+    calls = _custom_calls(kernel_texts[name])
     assert calls and all(re.search(r"flash_|paged_", c) for c in calls), calls
     assert any(name in c for c in calls), calls
 
@@ -293,9 +330,7 @@ def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
     compiles for the chip at the configuration's widths, and its ``name=`` is
     inside the custom call's instruction name, where the benchmark's readers
     look for it (``chipbench/trace_reads.py``)."""
-    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
-                       r"custom_call_target=\"tpu_custom_call\"",
-                       sparse_texts[name], re.M)
+    calls = _custom_calls(sparse_texts[name])
     assert any(name in c for c in calls), calls
 
 
@@ -330,9 +365,7 @@ def test_block_attention_lowers_on_tpu_under_its_names(block_text, name):
     grid over the schedule's tiles), and its ``name=`` is inside the custom
     call's instruction name, where the benchmark's readers look for it. No
     name holds ``sparse_attn`` or ``flash``, which other readers match."""
-    calls = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*\bcustom-call\(.*"
-                       r"custom_call_target=\"tpu_custom_call\"",
-                       block_text, re.M)
+    calls = _custom_calls(block_text)
     assert any(name in c for c in calls), calls
     assert not any("sparse_attn" in c or "flash" in c for c in calls), calls
 
