@@ -1,0 +1,9 @@
+"""Device time of the operations traced under the part ``embed`` (ids to
+embedding rows and the rows' gradient), per optimizer step, in ms. ``None`` in
+a run that keeps no scopes. Source: device_trace."""
+
+from chipbench import keye_reads
+
+
+def read(run):
+    return keye_reads.scope_ms_per_step(run, "embed")

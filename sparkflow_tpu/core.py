@@ -78,14 +78,16 @@ def make_loss_fn(model: GraphModel, input_name,
         def loss_fn(params, x, y, mask, rng):
             lv, metrics = model.loss_and_metrics(
                 params, build_feeds(x, y), train=True, rng=rng)
-            return _masked_mean(lv, mask), metrics
+            with jax.named_scope("batch"):
+                return _masked_mean(lv, mask), metrics
 
         loss_fn.has_metrics = True
         return loss_fn
 
     def loss_fn(params, x, y, mask, rng):
         lv = model.loss_vector(params, build_feeds(x, y), train=True, rng=rng)
-        return _masked_mean(lv, mask)
+        with jax.named_scope("batch"):
+            return _masked_mean(lv, mask)
 
     return loss_fn
 
@@ -110,10 +112,17 @@ def make_feeds_builder(input_name, label_name: Optional[str]) -> Callable:
 def _step_body(loss_fn: Callable, optimizer: optax.GradientTransformation) -> Callable:
     """The one optimizer step shared by make_train_step and make_epoch_fn.
 
-    Two ``jax.named_scope``s put every device op of a step under a phase in
-    a profile (``docs/observability.md``): ``loss`` holds the forward pass,
-    and the backward pass too, which JAX marks with a ``transpose(`` component
-    of its own inside the path; ``optimizer`` holds the update.
+    Its ``jax.named_scope``s take their names from the one list of a step's
+    parts, ``utils.tracing.STEP_PARTS`` (``docs/observability.md``, item 3):
+    a *part* never lies inside another part, and every device op that the
+    step and the model write lies under exactly one, so the parts' times in
+    a profile add up to the step's. ``loss`` is a *group* (``STEP_GROUPS``),
+    around the loss's ``value_and_grad``: inside it the model names the
+    parts (``embed``, ``attn_proj``, ``mlp``, ``lm_head``, ... the same
+    names in all four decoder families) and :func:`make_loss_fn` the mean
+    over the rows (``batch``), forward and backward, which JAX marks with a
+    ``transpose(`` component of its own inside the path; ``optimizer``, the
+    update, is a part itself. Nothing of a step lies outside the two.
 
     A ``loss_fn`` with ``has_metrics`` (:func:`make_loss_fn`) returns the
     step's counters beside the loss; the step then gives ``(loss, metrics)``

@@ -129,12 +129,11 @@ class BlockDiffusionLM(MoEDecoder):
         return ba.positions(s // 2)
 
     def _attend(self, bp, y):
-        b, s, _ = y.shape
         q, k, v = self._qkv(bp, y)
         with jax.named_scope("block_attention"):
-            att, _ = ba.block_attention(q, k, v, s // 2, self.block_length)
-        return jnp.transpose(att, (0, 2, 1, 3)).reshape(
-            b, s, self.num_heads * self.head_dim), {}
+            att, _ = ba.block_attention(q, k, v, y.shape[1] // 2,
+                                        self.block_length)
+        return att, {}
 
     # -- forward and loss ----------------------------------------------------
 
@@ -143,10 +142,11 @@ class BlockDiffusionLM(MoEDecoder):
         i`` predicting token ``i``."""
         ids = feeds["input_ids"].astype(jnp.int32)
         x = self._encode(params, ids)[0]
-        logits = self._head(params, x[:, ids.shape[1] // 2:])
-        return {"logits": logits,
-                "pred": (jnp.argmax(logits, axis=-1)
-                         + self.vocab_held[0]).astype(jnp.float32)}
+        with jax.named_scope("lm_head"):
+            logits = self._head(params, x[:, ids.shape[1] // 2:])
+            return {"logits": logits,
+                    "pred": (jnp.argmax(logits, axis=-1)
+                             + self.vocab_held[0]).astype(jnp.float32)}
 
     def _row_loss(self, params, x, ids):
         """The masked-token loss of one row and how many positions carry it:
@@ -170,16 +170,19 @@ class BlockDiffusionLM(MoEDecoder):
         (``masked_tokens``). Rows go through the model one after
         another, as in ``sparse_moe_lm``."""
         feeds = {k.split(":")[0]: v for k, v in feeds.items()}
-        ids = feeds["input_ids"].astype(jnp.int32)
+        with jax.named_scope("batch"):
+            ids = feeds["input_ids"].astype(jnp.int32)
 
         def row(r):
             x, aux = self._encode(params, r[None])
-            nll, masked = self._row_loss(params, x[0], r)
-            loss = nll + self.router_aux_weight * jnp.sum(aux["balance"])
+            with jax.named_scope("lm_head"):    # the row's loss, whole
+                nll, masked = self._row_loss(params, x[0], r)
+                loss = nll + self.router_aux_weight * jnp.sum(aux["balance"])
             return loss, (aux["expert_load"], aux["expert_rows_live"],
                           masked)
 
         loss, (load, live, masked) = jax.lax.map(row, ids)
-        return loss, dict(expert_load=jnp.sum(load, axis=0),
-                          masked_tokens=jnp.sum(masked),
-                          **self._expert_counts(ids, live))
+        with jax.named_scope("batch"):          # the counters over the rows
+            return loss, dict(expert_load=jnp.sum(load, axis=0),
+                              masked_tokens=jnp.sum(masked),
+                              **self._expert_counts(ids, live))

@@ -117,17 +117,23 @@ class LoopedLM(RegistryModel):
         sandwich-norm SiLU-gated MLP."""
         b, s, _ = x.shape
         eps = self.rms_eps
+        # ``attention`` groups the half's two parts: what is dense around
+        # the kernel (``attn_proj``, the other families' name) and its call
         with jax.named_scope("attention"):
-            y = rms_norm(x, bp["ln1_scale"], eps)
-            heads = lambda a: a.reshape(b, s, self.num_heads, self.head_dim)
-            q = rope(heads(dense(y, bp["q_kernel"])), self.rope_theta)
-            k = rope(heads(dense(y, bp["k_kernel"])), self.rope_theta)
-            v = heads(dense(y, bp["v_kernel"]))
-            att = flash_attention(*(jnp.transpose(a, (0, 2, 1, 3))
-                                    for a in (q, k, v)), causal=True)
-            att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
-            x = x + rms_norm(dense(att, bp["o_kernel"]),
-                             bp["ln1_post_scale"], eps)
+            with jax.named_scope("attn_proj"):
+                y = rms_norm(x, bp["ln1_scale"], eps)
+                heads = lambda a: a.reshape(b, s, self.num_heads,
+                                            self.head_dim)
+                q = rope(heads(dense(y, bp["q_kernel"])), self.rope_theta)
+                k = rope(heads(dense(y, bp["k_kernel"])), self.rope_theta)
+                v = heads(dense(y, bp["v_kernel"]))
+                q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+            with jax.named_scope("flash_attention"):
+                att = flash_attention(q, k, v, causal=True)
+            with jax.named_scope("attn_proj"):
+                att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
+                x = x + rms_norm(dense(att, bp["o_kernel"]),
+                                 bp["ln1_post_scale"], eps)
         with jax.named_scope("mlp"):
             y = rms_norm(x, bp["ln2_scale"], eps)
             m = dense(jax.nn.silu(dense(y, bp["gate_kernel"]))
@@ -183,12 +189,15 @@ class LoopedLM(RegistryModel):
         distribution), ``loop_loss [T]`` (every pass's cross-entropy),
         ``exit_entropy`` (the exit distribution's entropy)."""
         feeds = {k.split(":")[0]: v for k, v in feeds.items()}
-        ids = feeds["input_ids"].astype(jnp.int32)
+        with jax.named_scope("batch"):
+            ids = feeds["input_ids"].astype(jnp.int32)
         rows, s = ids.shape
-        # position S - 1 predicts nothing: its target is a filler of weight 0
-        tgt = jnp.concatenate([ids[:, 1:], ids[:, :1]], axis=1)
-        live = jnp.broadcast_to(
-            (jnp.arange(s) < s - 1).astype(jnp.float32), (rows, s))
+        with jax.named_scope("loop_head"):
+            # position S - 1 predicts nothing: its target is a filler of
+            # weight 0
+            tgt = jnp.concatenate([ids[:, 1:], ids[:, :1]], axis=1)
+            live = jnp.broadcast_to(
+                (jnp.arange(s) < s - 1).astype(jnp.float32), (rows, s))
         head = lambda hs: head_logits(hs, params["lm_head"]["kernel"])
 
         def one_pass(carry, t):
@@ -203,7 +212,7 @@ class LoopedLM(RegistryModel):
                 weight = jnp.stack([jnp.exp(log_p) * live, live], axis=-1)
                 # [B, 2]: sum_i p_t(i) CE_t(i) and sum_i CE_t(i)
                 sums = weighted_nll(head, x, tgt, weight, self.head_block)
-            return (x, stay + jax.nn.log_sigmoid(-z)), (log_p, sums)
+                return (x, stay + jax.nn.log_sigmoid(-z)), (log_p, sums)
 
         stay = jnp.zeros((rows, s), jnp.float32)
         _, (log_p, sums) = jax.lax.scan(
@@ -216,7 +225,8 @@ class LoopedLM(RegistryModel):
                     - self.exit_entropy_weight * jnp.sum(entropy, axis=-1)
                     ) / (s - 1)
         n = rows * (s - 1)
-        return loss, dict(
-            exit_mass=jnp.sum(p, axis=(1, 2)) / n,
-            loop_loss=jnp.sum(sums[..., 1], axis=1) / n,
-            exit_entropy=jnp.sum(entropy) / n)
+        with jax.named_scope("batch"):          # the counters over the rows
+            return loss, dict(
+                exit_mass=jnp.sum(p, axis=(1, 2)) / n,
+                loop_loss=jnp.sum(sums[..., 1], axis=1) / n,
+                exit_entropy=jnp.sum(entropy) / n)

@@ -190,18 +190,22 @@ class MoEDecoder(RegistryModel):
         ``k``, rotary positions over the whole head."""
         b, s, _ = y.shape
         heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
-        pos = self._positions(s)
-        q = heads(_dense(y, bp["q_kernel"]), self.num_heads)
-        k = heads(_dense(y, bp["k_kernel"]), self.num_kv_heads)
-        v = heads(_dense(y, bp["v_kernel"]), self.num_kv_heads)
-        q = rope(rms_norm(q, bp["q_norm"], self.rms_eps), self.rope_theta, pos)
-        k = rope(rms_norm(k, bp["k_norm"], self.rms_eps), self.rope_theta, pos)
-        return tuple(jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+        with jax.named_scope("attn_proj"):
+            pos = self._positions(s)
+            q = heads(_dense(y, bp["q_kernel"]), self.num_heads)
+            k = heads(_dense(y, bp["k_kernel"]), self.num_kv_heads)
+            v = heads(_dense(y, bp["v_kernel"]), self.num_kv_heads)
+            q = rope(rms_norm(q, bp["q_norm"], self.rms_eps),
+                     self.rope_theta, pos)
+            k = rope(rms_norm(k, bp["k_norm"], self.rms_eps),
+                     self.rope_theta, pos)
+            return tuple(jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
 
     def _attend(self, bp, y):
-        """The attention on ``y = RMSNorm(x) [B, S, h]``: its output before
-        ``W_o`` ``[B, S, Hq D]`` and the family's own entries of the block's
-        ``aux``."""
+        """The attention on ``y = RMSNorm(x) [B, S, h]``: its output by head
+        ``[B, Hq, S, D]`` and the family's own entries of the block's
+        ``aux``. The family's kernels go under parts of its own
+        (``utils.tracing.STEP_PARTS``); :meth:`_qkv` brings ``attn_proj``."""
         raise NotImplementedError
 
     def _experts(self, bp, y):
@@ -227,13 +231,19 @@ class MoEDecoder(RegistryModel):
         """One layer on ``x [B, S, h]`` -> ``(x, aux)``; ``aux`` holds each
         row's balance loss, the layer's expert load, the rows of its experts'
         buffer that are in use and what the family's attention adds."""
-        att, aux = self._attend(
-            bp, rms_norm(x, bp["ln1_scale"], self.rms_eps))
-        x = x + _dense(att, bp["o_kernel"])
-        out, balance, load = self._experts(
-            bp, rms_norm(x, bp["ln2_scale"], self.rms_eps))
-        return x + out, dict(aux, balance=balance, expert_load=load,
-                             expert_rows_live=gm.rows_live(load))
+        b, s, _ = x.shape
+        with jax.named_scope("attn_proj"):
+            y = rms_norm(x, bp["ln1_scale"], self.rms_eps)
+        att, aux = self._attend(bp, y)
+        with jax.named_scope("attn_proj"):
+            att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
+            x = x + _dense(att, bp["o_kernel"])
+        with jax.named_scope("router"):
+            y = rms_norm(x, bp["ln2_scale"], self.rms_eps)
+        out, balance, load = self._experts(bp, y)
+        with jax.named_scope("router"):
+            return x + out, dict(aux, balance=balance, expert_load=load,
+                                 expert_rows_live=gm.rows_live(load))
 
     def _expert_counts(self, ids, live):
         """The counters every family returns of a step's rows ``ids [rows,
@@ -249,11 +259,11 @@ class MoEDecoder(RegistryModel):
 
     def _head(self, params, x):
         """Final norm and the head over the vocabulary held: float32
-        logits."""
-        with jax.named_scope("lm_head"):
-            return head_logits(
-                rms_norm(x, params["final_ln"]["scale"], self.rms_eps),
-                params["lm_head"]["kernel"])
+        logits. The callers name the part (``lm_head``), which holds what
+        they make of the logits too."""
+        return head_logits(
+            rms_norm(x, params["final_ln"]["scale"], self.rms_eps),
+            params["lm_head"]["kernel"])
 
     def _embed_index(self, ids):
         """The embedding row of each id."""
@@ -269,7 +279,8 @@ class MoEDecoder(RegistryModel):
         for i in range(self.num_layers):
             x, a = block(params[f"block_{i}"], x)
             aux.append(a)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *aux)
+        with jax.named_scope("router"):     # the layers' counters, stacked
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *aux)
 
     def _weighted_nll(self, params, x, tgt, weight):
         """``sum_i weight[i] * cross-entropy(head(x[i]), tgt[i])`` of one
@@ -330,8 +341,8 @@ class SparseMoELM(MoEDecoder):
 
     def _attend(self, bp, y):
         """Steps 1-3 on ``y = RMSNorm(x) [B, S, h]``: the attention's output
-        before ``W_o``, and in ``aux`` each row's indexer loss ``[B]`` and
-        the keys a query selected (mean)."""
+        by head, and in ``aux`` each row's indexer loss ``[B]`` and the keys
+        a query selected (mean)."""
         b, s, _ = y.shape
         heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
         q, k, v = self._qkv(bp, y)
@@ -353,18 +364,18 @@ class SparseMoELM(MoEDecoder):
             target = sa.selected_probs(q, k, lse, mask)
             kl = sa.indexer_loss(qi, ki, w, mask, target, self.indexer_block)
             picked = jnp.mean(jnp.sum(mask.astype(jnp.float32), axis=-1))
-        att = jnp.transpose(att, (0, 2, 1, 3)).reshape(
-            b, s, self.num_heads * self.head_dim)
         return att, dict(indexer=kl, selected_keys=picked)
 
     # -- forward and loss ----------------------------------------------------
 
     def _forward(self, params, feeds, train, rng):
         ids = feeds["input_ids"].astype(jnp.int32)
-        logits = self._head(params, self._encode(params, ids)[0])
-        return {"logits": logits,
-                "pred": (jnp.argmax(logits, axis=-1)
-                         + self.vocab_held[0]).astype(jnp.float32)}
+        x = self._encode(params, ids)[0]
+        with jax.named_scope("lm_head"):
+            logits = self._head(params, x)
+            return {"logits": logits,
+                    "pred": (jnp.argmax(logits, axis=-1)
+                             + self.vocab_held[0]).astype(jnp.float32)}
 
     def _row_nll(self, params, x, ids):
         """Mean next-token cross-entropy of one row: ``x [S, h]`` (before the
@@ -386,17 +397,20 @@ class SparseMoELM(MoEDecoder):
         dropless layer are sized for the worst routing of the tokens they
         serve."""
         feeds = {k.split(":")[0]: v for k, v in feeds.items()}
-        ids = feeds["input_ids"].astype(jnp.int32)
+        with jax.named_scope("batch"):
+            ids = feeds["input_ids"].astype(jnp.int32)
 
         def row(r):
             x, aux = self._encode(params, r[None])
-            loss = (self._row_nll(params, x[0], r)
-                    + self.indexer_loss_weight * jnp.sum(aux["indexer"])
-                    + self.router_aux_weight * jnp.sum(aux["balance"]))
+            with jax.named_scope("lm_head"):    # the row's loss, whole
+                loss = (self._row_nll(params, x[0], r)
+                        + self.indexer_loss_weight * jnp.sum(aux["indexer"])
+                        + self.router_aux_weight * jnp.sum(aux["balance"]))
             return loss, (aux["expert_load"], aux["expert_rows_live"],
                           aux["selected_keys"])
 
         loss, (load, live, picked) = jax.lax.map(row, ids)
-        return loss, dict(expert_load=jnp.sum(load, axis=0),
-                          selected_keys=jnp.mean(picked, axis=0),
-                          **self._expert_counts(ids, live))
+        with jax.named_scope("batch"):          # the counters over the rows
+            return loss, dict(expert_load=jnp.sum(load, axis=0),
+                              selected_keys=jnp.mean(picked, axis=0),
+                              **self._expert_counts(ids, live))

@@ -169,24 +169,31 @@ class _TransformerBase(RegistryModel):
         dense blocks have no expert bank."""
         del ep_axis
         b, s, h = x.shape
-        # one scope per block kind, not per layer: a profile sums the 2 x L
-        # halves of the stack under two names (docs/observability.md)
+        # one scope per block kind, not per layer: a profile sums the halves
+        # of the stack under a few names (docs/observability.md).
+        # ``attention`` groups the half's two parts: what is dense around
+        # the kernel (``attn_proj``, the other families' name) and its call
         with jax.named_scope("attention"):
-            y = _layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
-            qkv = self._proj(bp, "qkv_", y)
-            heads = qkv.shape[-1] // (3 * self.head_dim)
-            qkv = qkv.reshape(b, s, 3, heads, self.head_dim)
-            # ONE relayout for all three tensors ([B,S,3,h,d] -> [3,B,h,S,d]),
-            # not three sliced transposes — TPU relayouts are real copies and
-            # this is on the per-block hot path (same math, layout only)
-            qkv = jnp.transpose(qkv, (2, 0, 3, 1, 4))
-            q, k, v = qkv[0], qkv[1], qkv[2]
-            att = self._attention(q, k, v, mask, causal)
-            att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
-            att, rng = self._dropout(self._proj(bp, "o_", att), train, rng)
-            if tp_axis is not None:
-                att = jax.lax.psum(att, tp_axis)
-            x = x + att
+            with jax.named_scope("attn_proj"):
+                y = _layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
+                qkv = self._proj(bp, "qkv_", y)
+                heads = qkv.shape[-1] // (3 * self.head_dim)
+                qkv = qkv.reshape(b, s, 3, heads, self.head_dim)
+                # ONE relayout for all three tensors ([B,S,3,h,d] ->
+                # [3,B,h,S,d]), not three sliced transposes — TPU relayouts
+                # are real copies and this is on the per-block hot path
+                # (same math, layout only)
+                qkv = jnp.transpose(qkv, (2, 0, 3, 1, 4))
+                q, k, v = qkv[0], qkv[1], qkv[2]
+            with jax.named_scope("flash_attention"):
+                att = self._attention(q, k, v, mask, causal)
+            with jax.named_scope("attn_proj"):
+                att = jnp.transpose(att, (0, 2, 1, 3)).reshape(b, s, -1)
+                att, rng = self._dropout(self._proj(bp, "o_", att), train,
+                                         rng)
+                if tp_axis is not None:
+                    att = jax.lax.psum(att, tp_axis)
+                x = x + att
         with jax.named_scope("mlp"):
             y = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
             y = jax.nn.gelu(self._proj(bp, "fc1_", y))
@@ -271,10 +278,10 @@ class _TransformerBase(RegistryModel):
     def _encode(self, params, feeds, causal, train, rng):
         """Returns ``(encoded, mask, aux)`` — aux is the summed per-block
         auxiliary loss, threaded functionally (no mutable instance state)."""
-        ids = feeds["input_ids"].astype(jnp.int32)
         mask = feeds.get("attention_mask")
-        b, s = ids.shape
         with jax.named_scope("embed"):
+            ids = feeds["input_ids"].astype(jnp.int32)
+            b, s = ids.shape
             x = jnp.take(params["embed"]["tok"], ids, axis=0)
             if self.sp_axis is not None:
                 # inside shard_map each device holds a sequence SHARD: use
@@ -296,8 +303,10 @@ class _TransformerBase(RegistryModel):
         for i in range(self.num_layers):
             x, rng, aux = block(params[f"block_{i}"], x, mask, causal, train, rng)
             aux_total = aux_total + aux
-        return _layer_norm(x, params["final_ln"]["scale"],
-                           params["final_ln"]["bias"]), mask, aux_total
+        with jax.named_scope("lm_head"):    # the final norm: the head's part
+            x = _layer_norm(x, params["final_ln"]["scale"],
+                            params["final_ln"]["bias"])
+        return x, mask, aux_total
 
 
 @register_model("transformer_classifier")
@@ -361,8 +370,8 @@ class TransformerLM(_TransformerBase):
         with jax.named_scope("lm_head"):
             logits = jnp.matmul(x.astype(jnp.float32),
                                 params["embed"]["tok"].T.astype(jnp.float32))
-        return {"logits": logits,
-                "pred": jnp.argmax(logits, axis=-1).astype(jnp.float32)}
+            return {"logits": logits,
+                    "pred": jnp.argmax(logits, axis=-1).astype(jnp.float32)}
 
     # -- autoregressive decode ----------------------------------------------
     #
@@ -629,12 +638,14 @@ class TransformerLM(_TransformerBase):
         return self.head_last(params, x, lengths), cache
 
     def _loss(self, params, feeds, train, rng):
-        ids = feeds["input_ids"].astype(jnp.int32)
         logits = self._forward(params, feeds, train, rng)["logits"]
-        logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-        tgt = ids[:, 1:]
-        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        if "attention_mask" in feeds and feeds["attention_mask"] is not None:
-            w = feeds["attention_mask"][:, 1:].astype(jnp.float32)
-            return jnp.sum(nll * w, axis=-1) / jnp.maximum(jnp.sum(w, axis=-1), 1e-6)
-        return jnp.mean(nll, axis=-1)
+        with jax.named_scope("lm_head"):    # the cross-entropy of the logits
+            logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+            tgt = feeds["input_ids"].astype(jnp.int32)[:, 1:]
+            nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+            if ("attention_mask" in feeds
+                    and feeds["attention_mask"] is not None):
+                w = feeds["attention_mask"][:, 1:].astype(jnp.float32)
+                return (jnp.sum(nll * w, axis=-1)
+                        / jnp.maximum(jnp.sum(w, axis=-1), 1e-6))
+            return jnp.mean(nll, axis=-1)
