@@ -16,14 +16,18 @@ visible: a quarter of it, half of its causal triangle.
 
 :func:`block_attention` is softmax attention of grouped query heads (``Hq``
 query heads over ``Hkv`` key/value heads) under that rule: pallas kernels
-``block_attn_fwd``, ``block_attn_bwd_dq``, ``block_attn_bwd_dkv``. A kernel's
-grid runs over the tiles the rule leaves something in and over no other
-(:func:`tile_schedule`: for a clean query tile the clean key tiles up to its
-own, for a noised one also the noised tile on its diagonal), so an empty tile
-is neither fetched nor computed; the mask of a visited tile is made in the
-kernel from the tile's two index ranges. One grid step holds the query heads
-of one KV head, as in ``ops/sparse_attention.py``, whose tile arithmetic
-(``_p_tile``) the backward kernels share.
+``block_attn_fwd`` and ``block_attn_bwd_dqkv``. A kernel's grid runs over the
+tiles the rule leaves something in and over no other (:func:`tile_schedule`:
+for a clean query tile the clean key tiles up to its own, for a noised one
+also the noised tile on its diagonal), so an empty tile is neither fetched
+nor computed; the mask of a visited tile is made in the kernel from the
+tile's two index ranges. One grid step holds the query heads of one KV head,
+as in ``ops/sparse_attention.py``, whose backward tile (``_bwd_tile``: a
+head's probabilities made once, its terms of dQ, dK and dV from them) and
+whose choice of the backward (``_bwd_is_fused``: one kernel with the KV
+head's whole dK and dV in VMEM where they fit, the pair ``block_attn_bwd_dq``,
+``block_attn_bwd_dkv`` past that) this file shares;
+``record_attention_paths()`` holds ``block_attention_bwd:fused`` or ``:split``.
 
 :func:`visible` and :func:`block_attention_reference` are the rule and the
 attention in plain ``jnp``; on a CPU the kernels run interpreted.
@@ -42,7 +46,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .sparse_attention import NEG_INF, _block, _p_tile, _params
+from .attention import _log_path
+from .sparse_attention import (NEG_INF, _block, _bwd_is_fused, _bwd_tile,
+                               _chunk, _params, _row_spec)
 
 # ``checkpoint_name``s of what the backward kernels read of the forward: a
 # ``jax.checkpoint`` that keeps them runs ``block_attn_fwd`` no second time
@@ -196,23 +202,15 @@ def _fwd_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
 
 def _bwd_dq_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *, sm_scale,
-                   group, rule):
+                   rule):
     t = pl.program_id(1)
 
     @pl.when(first_ref[t] == 1)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k, v = k_ref[0], v_ref[0]
-    sel = rule(qt_ref[t], kt_ref[t])
-    for g in range(group):
-        p = _p_tile(q_ref[0, g], k, lse_ref[0, g], sel, sm_scale)
-        dp = jax.lax.dot_general(do_ref[0, g], v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, g])
-        acc_ref[g] += sm_scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+              rule(qt_ref[t], kt_ref[t]), sm_scale, dq_acc=acc_ref)
 
     @pl.when(last_ref[t] == 1)
     def _finalize():
@@ -221,7 +219,7 @@ def _bwd_dq_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
 
 def _bwd_dkv_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-                    dv_acc, *, sm_scale, group, rule):
+                    dv_acc, *, sm_scale, rule):
     t = pl.program_id(1)
 
     @pl.when(first_ref[t] == 1)
@@ -229,23 +227,41 @@ def _bwd_dkv_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    k, v = k_ref[0], v_ref[0]
-    sel = rule(qt_ref[t], kt_ref[t])
-    for g in range(group):
-        q, do = q_ref[0, g], do_ref[0, g]
-        p = _p_tile(q, k, lse_ref[0, g], sel, sm_scale)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, g])
-        dk_acc[...] += sm_scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+              rule(qt_ref[t], kt_ref[t]), sm_scale, dk_acc=dk_acc,
+              dv_acc=dv_acc)
 
     @pl.when(last_ref[t] == 1)
     def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_dqkv_kernel(qt_ref, kt_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
+                     do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                     dq_acc, dk_acc, dv_acc, *, sm_scale, rule):
+    t, visits = pl.program_id(1), pl.num_programs(1)
+
+    @pl.when(first_ref[t] == 1)
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    # dk_acc and dv_acc hold the KV head's whole row, all visits long
+    @pl.when(t == 0)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+              rule(qt_ref[t], kt_ref[t]), sm_scale, dq_acc, dk_acc, dv_acc,
+              _chunk(kt_ref[t], k_ref.shape[1]))
+
+    @pl.when(last_ref[t] == 1)
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(t == visits - 1)
+    def _finalize_dkv():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -285,11 +301,11 @@ def _layout(q, k, v):
             v.reshape(b * hkv, s, d))
 
 
-def _static(cfg, group):
+def _static(cfg):
     """The kernels' keyword arguments from ``cfg = (length, block, scale,
     block_q, block_k, interpret)``."""
     length, block, scale, block_q, block_k, _ = cfg
-    return dict(sm_scale=scale, group=group, rule=functools.partial(
+    return dict(sm_scale=scale, rule=functools.partial(
         _rule_tile, block_q=block_q, block_k=block_k, length=length,
         block=block))
 
@@ -301,7 +317,7 @@ def _forward(q, k, v, cfg):
     bh, group = qf.shape[:2]
     qspec, kspec, stat = _specs(group, block_q, block_k, d)
     out, lse = _call(
-        functools.partial(_fwd_kernel, **_static(cfg, group)),
+        functools.partial(_fwd_kernel, group=group, **_static(cfg)),
         "block_attn_fwd", tile_schedule(length, block, block_q, block_k),
         [qspec, kspec, kspec], (qspec, stat),
         (jax.ShapeDtypeStruct(qf.shape, q.dtype),
@@ -314,7 +330,9 @@ def _forward(q, k, v, cfg):
 
 
 def _backward(q, k, v, out, lse, g, cfg):
-    length, block, _, block_q, block_k, interpret = cfg
+    """dQ, dK, dV: one kernel that visits each tile once where a KV head's
+    dK and dV fit ``ops/sparse_attention.py``'s budget for them, the dq and
+    dkv kernels past it."""
     s, d = q.shape[2:]
     qf, kf, vf = _layout(q, k, v)
     bh, group = qf.shape[:2]
@@ -322,23 +340,51 @@ def _backward(q, k, v, out, lse, g, cfg):
                     axis=-1).reshape(bh, group, s, 1)
     operands = (qf, kf, vf, g.astype(q.dtype).reshape(qf.shape),
                 lse.reshape(bh, group, s, 1), delta)
-    static = _static(cfg, group)
+    fused = _bwd_is_fused(s, d, k.dtype)
+    _log_path("block_attention_bwd", "fused" if fused else "split")
+    dq, dk, dv = (_bwd_fused if fused else _bwd_split)(operands, cfg)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+def _bwd_fused(operands, cfg):
+    length, block, _, block_q, block_k, interpret = cfg
+    qf, kf, vf = operands[:3]
+    group, s, d = qf.shape[1:]
+    qspec, kspec, stat = _specs(group, block_q, block_k, d)
+    row = _row_spec(s, d)
+    return _call(
+        functools.partial(_bwd_dqkv_kernel, **_static(cfg)),
+        "block_attn_bwd_dqkv", tile_schedule(length, block, block_q, block_k),
+        [qspec, kspec, kspec, qspec, stat, stat], (qspec, row, row),
+        (jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+         jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+         jax.ShapeDtypeStruct(vf.shape, vf.dtype)),
+        [pltpu.VMEM((group, block_q, d), jnp.float32),
+         pltpu.VMEM((s, d), jnp.float32), pltpu.VMEM((s, d), jnp.float32)],
+        interpret, operands)
+
+
+def _bwd_split(operands, cfg):
+    length, block, _, block_q, block_k, interpret = cfg
+    qf, kf, vf = operands[:3]
+    group, _, d = qf.shape[1:]
+    static = _static(cfg)
     qspec, kspec, stat = _specs(group, block_q, block_k, d)
     ins = [qspec, kspec, kspec, qspec, stat, stat]
     dq = _call(
         functools.partial(_bwd_dq_kernel, **static), "block_attn_bwd_dq",
         tile_schedule(length, block, block_q, block_k), ins, qspec,
-        jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        jax.ShapeDtypeStruct(qf.shape, qf.dtype),
         [pltpu.VMEM((group, block_q, d), jnp.float32)], interpret, operands)
     dk, dv = _call(
         functools.partial(_bwd_dkv_kernel, **static), "block_attn_bwd_dkv",
         tile_schedule(length, block, block_q, block_k, "kq"), ins,
         (kspec, kspec),
-        (jax.ShapeDtypeStruct(kf.shape, k.dtype),
-         jax.ShapeDtypeStruct(vf.shape, v.dtype)),
+        (jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+         jax.ShapeDtypeStruct(vf.shape, vf.dtype)),
         [pltpu.VMEM((block_k, d), jnp.float32),
          pltpu.VMEM((block_k, d), jnp.float32)], interpret, operands)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -14,11 +14,18 @@ attention runs over those keys only.
   selection once. Tiles above the diagonal are neither fetched nor computed.
 - :func:`selected_attention`: softmax attention of grouped query heads
   (``Hq`` query heads over ``Hkv`` key/value heads) under that mask: pallas
-  kernels ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv``
-  that mask tiles (a selection of scattered keys leaves hardly a tile empty:
-  at 8 FLOP a gathered byte a per-query gather would be bound by memory at
-  3 % of the MXU, a masked tile pays 2.3 x the selected pairs' operations at
-  the MXU's own rate) and skip the tiles above the diagonal.
+  kernels ``sparse_attn_fwd`` and ``sparse_attn_bwd_dqkv`` that mask tiles (a
+  selection of scattered keys leaves hardly a tile empty: at 8 FLOP a
+  gathered byte a per-query gather would be bound by memory at 3 % of the
+  MXU, a masked tile pays 2.3 x the selected pairs' operations at the MXU's
+  own rate) and skip the tiles above the diagonal. The backward kernel
+  visits each tile once and makes its probabilities once a head
+  (:func:`_bwd_tile`, which ``ops/block_attention.py`` runs too): dQ in a
+  scratch of the query tile, dK and dV in float32 scratches of the KV head's
+  whole row. Where those do not fit VMEM (:func:`_bwd_is_fused`, by the
+  shape alone) the pair ``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv`` runs
+  the same tile function twice; ``record_attention_paths()`` of
+  ``ops/attention.py`` holds ``sparse_attention_bwd:fused`` or ``:split``.
 - :func:`selected_probs`: the attention's probabilities summed over the
   heads and normalised to one (kernel ``sparse_attn_probs``), the target of
   the indexer's loss.
@@ -44,6 +51,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .attention import _log_path
 
 NEG_INF = -1e30
 _VMEM_LIMIT = 96 * 1024 * 1024
@@ -362,8 +371,43 @@ def _forward(q, k, v, mask, scale, block_q, block_k, interpret):
     return out.reshape(b, hq, s, d), lse.reshape(b, hq, s)
 
 
+def _bwd_tile(q_ref, k, v, do_ref, lse_ref, delta_ref, sel, sm_scale,
+              dq_acc=None, dk_acc=None, dv_acc=None, rows=slice(None)):
+    """One visited tile of the backward pass, the heads of the group in
+    turn: a head's probabilities ``p``, ``dp = dO V^T`` and ``ds = p (dp -
+    delta)`` are made once, and from them its terms of dQ (``scale dS K``,
+    added to ``dq_acc[g]``), of dV (``P^T dO``) and of dK (``scale dS^T Q``),
+    float32. The group's terms of dK and dV are summed first and added to
+    ``rows`` of ``dk_acc`` and ``dv_acc`` once a tile (one pass over the
+    accumulators in place of one a head: 3 % of the fused kernel's time on a
+    v5e). A kernel that keeps one side only leaves the other side's
+    accumulators out."""
+    dk = dv = None
+    for g in range(q_ref.shape[1]):
+        q, do = q_ref[0, g], do_ref[0, g]
+        p = _p_tile(q, k, lse_ref[0, g], sel, sm_scale)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, g])
+        if dq_acc is not None:
+            dq_acc[g] += sm_scale * jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        if dk_acc is not None:
+            dv_g = jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_g = jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dv, dk = (dv_g, dk_g) if g == 0 else (dv + dv_g, dk + dk_g)
+    if dk_acc is not None:
+        dv_acc[rows] += dv
+        dk_acc[rows] += sm_scale * dk
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-                   dq_ref, acc_ref, *, sm_scale, group, block_q, block_k):
+                   dq_ref, acc_ref, *, sm_scale, block_q, block_k):
     qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
 
     @pl.when(ki == 0)
@@ -372,16 +416,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 
     @pl.when(_visible(qi, ki, block_q, block_k))
     def _compute():
-        k, v = k_ref[0], v_ref[0]
-        sel = mask_ref[0] != 0
-        for g in range(group):
-            p = _p_tile(q_ref[0, g], k, lse_ref[0, g], sel, sm_scale)
-            dp = jax.lax.dot_general(do_ref[0, g], v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, g])
-            acc_ref[g] += sm_scale * jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+                  mask_ref[0] != 0, sm_scale, dq_acc=acc_ref)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -389,8 +425,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, group,
-                    block_q, block_k):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block_q,
+                    block_k):
     ki, qi, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
 
     @pl.when(qi == 0)
@@ -400,20 +436,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 
     @pl.when(_visible(qi, ki, block_q, block_k))
     def _compute():
-        k, v = k_ref[0], v_ref[0]
-        sel = mask_ref[0] != 0
-        for g in range(group):
-            q, do = q_ref[0, g], do_ref[0, g]
-            p = _p_tile(q, k, lse_ref[0, g], sel, sm_scale)
-            dv_acc[...] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, g])
-            dk_acc[...] += sm_scale * jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+                  mask_ref[0] != 0, sm_scale, dk_acc=dk_acc, dv_acc=dv_acc)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -421,7 +445,68 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                     *, sm_scale, block_q, block_k):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    # dk_acc and dv_acc hold the KV head's whole row, all query tiles long
+    @pl.when((qi == 0) & (ki == 0))
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_visible(qi, ki, block_q, block_k))
+    def _compute():
+        _bwd_tile(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref,
+                  mask_ref[0] != 0, sm_scale, dq_acc, dk_acc, dv_acc,
+                  _chunk(ki, block_k))
+
+    @pl.when(ki == nk - 1)
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+    # the blocks of dK and dV follow bh alone: they leave VMEM after the KV
+    # head's last grid step
+    @pl.when((qi == nq - 1) & (ki == nk - 1))
+    def _finalize_dkv():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# What the fused backward may keep in VMEM for one KV head's dK and dV: the
+# two float32 accumulators and the two buffers of each output block, each
+# ``[s, d]`` with ``d`` padded to the 128 lanes. 32 MiB holds 8 192 positions
+# in bfloat16 (16 MiB) and in float32 (24 MiB) and 16 384 in bfloat16 at any
+# ``d <= 128``. With the group's tiles, statistics and dQ the v5e's compiler
+# allocates, at a group of 8 and tiles of 512 x 512: 34.8 MB for 8 192
+# positions in bfloat16 (both MoE cells), under 50 MB in float32, under 52 MB
+# for 16 384 in bfloat16 (of ``_VMEM_LIMIT``, of the chip's 128 MiB).
+_FUSED_DKV_VMEM_BUDGET = 32 * 1024 * 1024
+
+
+def _bwd_is_fused(s: int, d: int, dtype) -> bool:
+    """Does a KV head's row of dK and dV fit the fused backward's budget
+    (``ops/block_attention.py`` asks too)."""
+    lanes = -(-d // 128) * 128
+    return (2 * s * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+            <= _FUSED_DKV_VMEM_BUDGET)
+
+
+def _row_spec(s, d):
+    """A KV head's whole ``[s, d]`` of a ``k``-like ``[BH, S, D]``."""
+    return pl.BlockSpec((1, s, d), lambda bh, *_: (bh, 0, 0))
+
+
 def _backward(q, k, v, mask, out, lse, g, scale, block_q, block_k, interpret):
+    """dQ, dK, dV: one kernel that visits each tile once where a KV head's
+    dK and dV fit :data:`_FUSED_DKV_VMEM_BUDGET`, the dq and dkv kernels
+    past it."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
@@ -431,16 +516,51 @@ def _backward(q, k, v, mask, out, lse, g, scale, block_q, block_k, interpret):
     lsef = lse.reshape(b * hkv, group, s, 1)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(b * hkv, group, s, 1)
-    common = dict(sm_scale=scale, group=group, block_q=block_q,
-                  block_k=block_k)
+    fused = _bwd_is_fused(s, d, k.dtype)
+    _log_path("sparse_attention_bwd", "fused" if fused else "split")
+    dq, dk, dv = (_bwd_fused if fused else _bwd_split)(
+        qf, kf, vf, gf, lsef, delta, mask, hkv, scale, block_q, block_k,
+        interpret)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+def _bwd_fused(qf, kf, vf, gf, lsef, delta, mask, hkv, scale, block_q,
+               block_k, interpret):
+    bh, group, s, d = qf.shape
+    qspec, kspec, stat, mspec = _specs(group, block_q, block_k, d, hkv)
+    row = _row_spec(s, d)
+    return pl.pallas_call(
+        functools.partial(_bwd_dqkv_kernel, sm_scale=scale, block_q=block_q,
+                          block_k=block_k),
+        name="sparse_attn_bwd_dqkv",
+        grid=(bh, s // block_q, s // block_k),
+        in_specs=[qspec, kspec, kspec, qspec, stat, stat, mspec],
+        out_specs=(qspec, row, row),
+        out_shape=(jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+                   jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+                   jax.ShapeDtypeStruct(vf.shape, vf.dtype)),
+        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32),
+                        pltpu.VMEM((s, d), jnp.float32),
+                        pltpu.VMEM((s, d), jnp.float32)],
+        # dK and dV sum over qi: only the (row, KV head) pairs are independent
+        compiler_params=_params(interpret, "parallel", "arbitrary",
+                                "arbitrary"),
+        interpret=interpret,
+    )(qf, kf, vf, gf, lsef, delta, mask)
+
+
+def _bwd_split(qf, kf, vf, gf, lsef, delta, mask, hkv, scale, block_q,
+               block_k, interpret):
+    bh, group, s, d = qf.shape
+    common = dict(sm_scale=scale, block_q=block_q, block_k=block_k)
     qspec, kspec, stat, mspec = _specs(group, block_q, block_k, d, hkv)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         name="sparse_attn_bwd_dq",
-        grid=(b * hkv, s // block_q, s // block_k),
+        grid=(bh, s // block_q, s // block_k),
         in_specs=[qspec, kspec, kspec, qspec, stat, stat, mspec],
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(shape_q, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
         scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32)],
         compiler_params=_params(interpret, "parallel", "parallel",
                                 "arbitrary"),
@@ -450,18 +570,18 @@ def _backward(q, k, v, mask, out, lse, g, scale, block_q, block_k, interpret):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         name="sparse_attn_bwd_dkv",
-        grid=(b * hkv, s // block_k, s // block_q),
+        grid=(bh, s // block_k, s // block_q),
         in_specs=[qspec, kspec, kspec, qspec, stat, stat, mspec],
         out_specs=(kspec, kspec),
-        out_shape=(jax.ShapeDtypeStruct(kf.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vf.shape, v.dtype)),
+        out_shape=(jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+                   jax.ShapeDtypeStruct(vf.shape, vf.dtype)),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_params(interpret, "parallel", "parallel",
                                 "arbitrary"),
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, delta, mask)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

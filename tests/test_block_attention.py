@@ -1,14 +1,17 @@
 """``ops/block_attention.py``: the block-diffusion mask as a rule of the two
 indices, the tiles the kernels visit, and the kernels (interpreted on the
-CPU) against the plain ``jnp`` reference beside them, forward and both
-backward kernels."""
+CPU) against the plain ``jnp`` reference beside them, forward and backward:
+the one backward kernel a row's dK and dV fit the VMEM budget for, and the
+dq and dkv pair past it."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sparkflow_tpu.ops import attention as A
 from sparkflow_tpu.ops import block_attention as ba
+from sparkflow_tpu.ops import sparse_attention as sa
 
 # (L, B, tile): tiles smaller than L, so that skipped tiles occur; 48 / 16
 # has a half row of three tiles, (32, 8) a block as wide as a tile (a noised
@@ -78,12 +81,20 @@ def _inputs(length, seed=0, hq=4, hkv=2, d=8, rows=2):
     return mk(hq), mk(hkv), mk(hkv), mk(hq)
 
 
-@pytest.fixture(scope="module", params=SHAPES,
-                ids=lambda s: "L%d-B%d-T%d" % s)
+@pytest.fixture
+def split_side(monkeypatch):
+    """A budget no row's dK and dV fit (``ops/sparse_attention.py`` holds
+    it for both files): the backward is the dq and dkv pair."""
+    monkeypatch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+
+
+@pytest.fixture(scope="module",
+                params=[s + (p,) for s in SHAPES for p in ("fused", "split")],
+                ids=lambda s: "L%d-B%d-T%d-%s" % s)
 def both(request):
     """Outputs and gradients of the kernels and of the reference, once a
-    shape."""
-    length, block, tile = request.param
+    shape and a side of the backward's budget."""
+    length, block, tile, path = request.param
     q, k, v, w = _inputs(length)
 
     def run(fn):
@@ -93,11 +104,16 @@ def both(request):
         return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
             q, k, v)
 
-    with jax.default_matmul_precision("highest"):
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.default_matmul_precision("highest"), \
+            A.record_attention_paths() as paths:
+        if path == "split":
+            patch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
         got = run(lambda q, k, v: ba.block_attention(
             q, k, v, length, block, block_q=tile, block_k=tile))
         want = run(lambda q, k, v: ba.block_attention_reference(
             q, k, v, length, block))
+    assert paths == [f"block_attention_bwd:{path}"]
     return got, want
 
 
@@ -125,15 +141,9 @@ def test_query_and_key_tiles_need_not_be_equal(block_q, block_k):
     np.testing.assert_allclose(lse, want_lse, atol=1e-5)
 
 
-def test_no_mask_operand_reaches_the_kernels():
-    """The mask is made in the kernel: the ``pallas_call``s take the four
-    tables of the schedule, ``q``, ``k``, ``v`` (and backward ``dO`` and the
-    row statistics) and nothing of ``[S, S]``."""
-    length = 32
-    q, k, v, _ = _inputs(length)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
-        ba.block_attention(q, k, v, length, 4, block_q=8, block_k=8)[0]),
-        argnums=(0, 1, 2)))(q, k, v)
+def _kernel_operands(fn, *args):
+    """The operands' shapes of each ``pallas_call`` in ``fn``'s jaxpr, by
+    the kernel's name."""
     calls = {}
 
     def walk(j):
@@ -147,11 +157,85 @@ def test_no_mask_operand_reaches_the_kernels():
                     if hasattr(sub, "eqns"):
                         walk(sub)
 
-    walk(jaxpr.jaxpr)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return calls
+
+
+def _grad_of_the_sum(length, tile=8):
+    return jax.grad(lambda q, k, v: jnp.sum(
+        ba.block_attention(q, k, v, length, 4, block_q=tile,
+                           block_k=tile)[0]), argnums=(0, 1, 2))
+
+
+def test_no_mask_operand_reaches_the_kernels():
+    """The mask is made in the kernel: the ``pallas_call``s take the four
+    tables of the schedule, ``q``, ``k``, ``v`` (and backward ``dO`` and the
+    row statistics) and nothing of ``[S, S]``. The backward is one kernel
+    where a row's dK and dV fit the budget, as here."""
+    length = 32
+    with A.record_attention_paths() as paths:
+        calls = _kernel_operands(_grad_of_the_sum(length),
+                                 *_inputs(length)[:3])
+    assert paths == ["block_attention_bwd:fused"]
+    assert sorted(calls) == ["block_attn_bwd_dqkv", "block_attn_fwd"]
+    for shapes in calls.values():
+        assert not any(s[-2:] == (2 * length, 2 * length) for s in shapes)
+
+
+def test_past_the_budget_the_backward_is_the_pair_and_takes_no_mask_either(
+        split_side):
+    length = 32
+    with A.record_attention_paths() as paths:
+        calls = _kernel_operands(_grad_of_the_sum(length),
+                                 *_inputs(length)[:3])
+    assert paths == ["block_attention_bwd:split"]
     assert sorted(calls) == ["block_attn_bwd_dkv", "block_attn_bwd_dq",
                              "block_attn_fwd"]
     for shapes in calls.values():
         assert not any(s[-2:] == (2 * length, 2 * length) for s in shapes)
+
+
+# (L, B, tile): a half row of one tile (a noised query tile sees its own
+# noised tile alone, or with the clean one); of four, in blocks narrower
+# than a tile (the last noised tile sees every clean tile but its own's
+# later blocks) and as wide (a clean tile's row of the schedule is short, a
+# noised tile's diagonal whole)
+BWD_SHAPES = [(16, 4, 16), (64, 4, 16), (64, 16, 16)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("length,block,tile", BWD_SHAPES)
+def test_fused_backward_is_the_pairs_to_the_bit(length, block, tile, group,
+                                                dtype):
+    """dQ, dK, dV of ``block_attn_bwd_dqkv`` equal the dq and dkv kernels'
+    in every bit (the tile function is one, ``ops/sparse_attention._bwd_tile``,
+    and the ``"qk"`` schedule brings a key tile its query tiles in the
+    ``"kq"`` schedule's order), and are the float32 reference's gradients to
+    the operands' rounding."""
+    q, k, v, w = (a.astype(dtype) for a in _inputs(
+        length, seed=group, hq=2 * group, hkv=2))
+    out, lse = ba.block_attention(q, k, v, length, block, block_q=tile,
+                                  block_k=tile)
+    bh, s, d = 2 * 2, 2 * length, q.shape[-1]
+    delta = jnp.sum(w.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(bh, group, s, 1)
+    operands = ba._layout(q, k, v) + (
+        w.reshape(bh, group, s, d), lse.reshape(bh, group, s, 1), delta)
+    cfg = (length, block, 1.0 / np.sqrt(d), tile, tile, True)
+    fused, pair = ba._bwd_fused(operands, cfg), ba._bwd_split(operands, cfg)
+    want = jax.grad(lambda *a: jnp.sum(ba.block_attention_reference(
+        *a, length, block)[0] * w.astype(jnp.float32)), argnums=(0, 1, 2))(
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+    for got, other, ref in zip(fused, pair, want):
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(got, other)
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 1e-2
+        np.testing.assert_allclose(
+            got.reshape(ref.shape).astype(jnp.float32), ref,
+            atol=(1e-6 if dtype == jnp.float32 else 2e-2) * max(scale, 5.0))
 
 
 @pytest.mark.parametrize("bad", [dict(length=30, block=4),

@@ -16,8 +16,10 @@ import pytest
 from sparkflow_tpu.models import (build_registry_spec, model_from_json,
                                   noise_rows)
 from sparkflow_tpu.models.sparse_moe_lm import MoEDecoder, SparseMoELM, rope
+from sparkflow_tpu.ops import attention as A
 from sparkflow_tpu.ops import block_attention as ba
 from sparkflow_tpu.ops import grouped_matmul as gm
+from sparkflow_tpu.ops import sparse_attention as sa
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 L, B, VOCAB, ALL, MASK = 32, 4, 48, 96, 90
@@ -145,18 +147,74 @@ def _kernel_calls(jaxpr, counts):
     return counts
 
 
-@pytest.mark.parametrize("remat", [True, False])
-def test_a_blocks_backward_runs_the_forward_kernel_once(remat):
-    """The block's checkpoint keeps the attention's output and logsumexp by
-    name: ``block_attn_fwd`` runs once a layer, not twice."""
+def _kernels_of_the_loss_gradient(remat):
     cfg = toy_cfg()
     params, rows = ref.init_params(cfg, 3), rows_for(0)
     model = toy_model(cfg, remat=remat)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.mean(
         model.loss_and_metrics(p, {"input_ids": rows})[0])))(params)
-    calls = _kernel_calls(jaxpr.jaxpr, {})
+    return cfg, _kernel_calls(jaxpr.jaxpr, {})
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_a_blocks_backward_runs_the_forward_kernel_once(remat):
+    """The block's checkpoint keeps the attention's output and logsumexp by
+    name: ``block_attn_fwd`` runs once a layer, not twice; the backward is
+    the one kernel ``block_attn_bwd_dqkv`` and the pair is in no block's
+    gradient (a row of the cell's size is on that side of the budget too)."""
+    with A.record_attention_paths() as paths:
+        cfg, calls = _kernels_of_the_loss_gradient(remat)
+    for name in ("block_attn_fwd", "block_attn_bwd_dqkv"):
+        assert calls[name] == cfg["num_hidden_layers"], calls
+    assert not {"block_attn_bwd_dq", "block_attn_bwd_dkv"} & set(calls)
+    # (a checkpointed block is traced once for all layers)
+    assert set(paths) == {"block_attention_bwd:fused"}
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_past_the_budget_a_blocks_backward_is_the_pair_once_a_layer(
+        remat, monkeypatch):
+    monkeypatch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+    cfg, calls = _kernels_of_the_loss_gradient(remat)
     for name in ("block_attn_fwd", "block_attn_bwd_dq", "block_attn_bwd_dkv"):
         assert calls[name] == cfg["num_hidden_layers"], calls
+    assert "block_attn_bwd_dqkv" not in calls
+
+
+@pytest.fixture(scope="module")
+def a_blocks_gradients():
+    """The gradients of one checkpointed block (``jax.checkpoint`` with the
+    family's ``KEPT``) in its weights and its input, with the backward as
+    one kernel and as the pair."""
+    cfg = toy_cfg()
+    model = toy_model(cfg, remat=True)
+    bp = ref.init_params(cfg, 3)["block_0"]
+    r = np.random.default_rng(4)
+    x = jnp.asarray(r.normal(size=(2, 2 * L, 32)), jnp.float32)
+    tilt = jnp.asarray(r.normal(size=x.shape), jnp.float32)
+
+    def grad():
+        # a checkpoint of its own: one is traced once for given shapes
+        block = jax.checkpoint(model._block, policy=model.KEPT)
+        return jax.grad(lambda bp, x: jnp.sum(block(bp, x)[0] * tilt),
+                        argnums=(0, 1))(bp, x)
+
+    with pytest.MonkeyPatch.context() as patch, \
+            A.record_attention_paths() as paths:
+        fused = grad()
+        patch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+        pair = grad()
+    assert paths == ["block_attention_bwd:fused", "block_attention_bwd:split"]
+    return dict(fused[0], x=fused[1]), dict(pair[0], x=pair[1])
+
+
+@pytest.mark.parametrize("leaf", ["x"] + list(ref.param_shapes(toy_cfg())[
+    "block_0"]))
+def test_a_checkpointed_blocks_gradient_is_the_pairs_to_the_bit(
+        a_blocks_gradients, leaf):
+    fused, pair = a_blocks_gradients
+    assert float(jnp.max(jnp.abs(pair[leaf]))) > 1e-5
+    np.testing.assert_array_equal(fused[leaf], pair[leaf])
 
 
 # -- the mask by its meaning ----------------------------------------------------

@@ -199,8 +199,8 @@ def kernels_of_a_gradient():
 # the target of the indexer's loss is made again, and is meant to be
 PASSES_A_LAYER = dict(
     index_select=1, sparse_attn_fwd=1, index_kl_fwd=1, sparse_attn_probs=2,
-    sparse_attn_bwd_dq=1, sparse_attn_bwd_dkv=1, index_kl_bwd_dq=1,
-    index_kl_bwd_dk=1, expert_gmm=9, expert_tgmm=3)
+    sparse_attn_bwd_dqkv=1, index_kl_bwd_dq=1, index_kl_bwd_dk=1,
+    expert_gmm=9, expert_tgmm=3)
 
 
 @pytest.mark.parametrize("kernel", sorted(PASSES_A_LAYER))
@@ -210,10 +210,63 @@ def test_a_blocks_backward_runs_each_attention_kernel_once(
     attention's output and logsumexp and the KL kernel's row statistics: the
     backward pass runs ``index_select``, ``sparse_attn_fwd`` and
     ``index_kl_fwd`` no second time (with no names kept each ran twice a
-    layer). The experts' forward is still made again (``expert_gmm``: three
-    products forward, again, and three backward beside ``expert_tgmm``'s
-    three)."""
+    layer), and the attention's backward is the one kernel
+    ``sparse_attn_bwd_dqkv``. The experts' forward is still made again
+    (``expert_gmm``: three products forward, again, and three backward beside
+    ``expert_tgmm``'s three)."""
     assert kernels_of_a_gradient[kernel] == PASSES_A_LAYER[kernel]
+
+
+@pytest.mark.parametrize("kernel", ["sparse_attn_bwd_dq",
+                                    "sparse_attn_bwd_dkv"])
+def test_the_pair_is_in_no_blocks_gradient_where_the_row_fits(
+        kernels_of_a_gradient, kernel):
+    """A row whose dK and dV fit the fused backward's budget (a toy row, and
+    the cell's 8192 positions of 128 in bfloat16 at half of it) runs neither
+    kernel of the pair."""
+    assert kernel not in kernels_of_a_gradient
+    assert sa._bwd_is_fused(8192, 128, jnp.bfloat16)
+
+
+@pytest.fixture(scope="module")
+def a_blocks_gradients():
+    """The gradients of one checkpointed block (``jax.checkpoint`` with the
+    family's ``KEPT``) in its weights and its input, with the attention's
+    backward as one kernel and as the pair."""
+    cfg = toy_cfg()
+    model = toy_model(cfg, remat=True)
+    bp = ref.init_params(cfg, 3)["block_0"]
+    r = np.random.default_rng(4)
+    x = jnp.asarray(r.normal(size=(2, S, 32)), jnp.float32)
+    tilt = jnp.asarray(r.normal(size=x.shape), jnp.float32)
+
+    def grad():
+        # a checkpoint of its own: one is traced once for given shapes
+        block = jax.checkpoint(model._block, policy=model.KEPT)
+
+        def loss(bp, x):
+            out, aux = block(bp, x)
+            return jnp.sum(out * tilt) + jnp.sum(aux["indexer"])
+
+        return jax.grad(loss, argnums=(0, 1))(bp, x)
+
+    with pytest.MonkeyPatch.context() as patch, \
+            A.record_attention_paths() as paths:
+        fused = grad()
+        patch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+        pair = grad()
+    assert paths == ["sparse_attention_bwd:fused",
+                     "sparse_attention_bwd:split"]
+    return dict(fused[0], x=fused[1]), dict(pair[0], x=pair[1])
+
+
+@pytest.mark.parametrize("leaf", ["x"] + list(ref.param_shapes(toy_cfg())[
+    "block_0"]))
+def test_a_checkpointed_blocks_gradient_is_the_pairs_to_the_bit(
+        a_blocks_gradients, leaf):
+    fused, pair = a_blocks_gradients
+    assert float(jnp.max(jnp.abs(pair[leaf]))) > 1e-7
+    np.testing.assert_array_equal(fused[leaf], pair[leaf])
 
 
 @pytest.fixture(scope="module")

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sparkflow_tpu.ops import attention as A
 from sparkflow_tpu.ops import grouped_matmul as gm
 from sparkflow_tpu.ops import sparse_attention as sa
 
@@ -139,8 +140,18 @@ def _attention_inputs(seed, hq=4, hkv=2, seq=S, d=8, keep=0.4):
     return q, k, v, jnp.asarray(mask, jnp.int8)
 
 
+@pytest.fixture(params=["fused", "split"])
+def bwd_path(request, monkeypatch):
+    """Both sides of ``_bwd_is_fused``: the budget as it is, where every toy
+    row fits and the backward is one kernel, and a budget no row fits, where
+    it is the dq and dkv pair."""
+    if request.param == "split":
+        monkeypatch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+    return request.param
+
+
 @pytest.mark.parametrize("hq,hkv,block", [(4, 2, None), (4, 4, 16), (8, 1, 8)])
-def test_selected_attention_matches_its_reference(hq, hkv, block):
+def test_selected_attention_matches_its_reference(hq, hkv, block, bwd_path):
     q, k, v, mask = _attention_inputs(hq * 10 + hkv, hq, hkv)
     kernel = lambda q, k, v: sa.selected_attention(
         q, k, v, mask, block_q=block, block_k=block)
@@ -150,8 +161,120 @@ def test_selected_attention_matches_its_reference(hq, hkv, block):
     np.testing.assert_allclose(lse, want_lse, atol=2e-6)
     tilt = jnp.asarray(np.random.default_rng(0).normal(size=out.shape),
                        jnp.float32)
-    _close_grads(lambda *a: jnp.sum(kernel(*a)[0] * tilt),
-                 lambda *a: jnp.sum(plain(*a)[0] * tilt), (q, k, v), 5e-6)
+    with A.record_attention_paths() as paths:
+        _close_grads(lambda *a: jnp.sum(kernel(*a)[0] * tilt),
+                     lambda *a: jnp.sum(plain(*a)[0] * tilt), (q, k, v), 5e-6)
+    assert paths == [f"sparse_attention_bwd:{bwd_path}"]
+
+
+# -- the selected-key attention's backward: one kernel, or the pair ------------
+
+
+def _flat_backward_operands(q, k, v, mask, tile, seed=0):
+    """What ``_bwd_fused`` and ``_bwd_split`` take: the forward's layout,
+    its output's ``delta`` against a random ``dO``, and the rest of their
+    arguments."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    out, lse = sa.selected_attention(q, k, v, mask, block_q=tile,
+                                     block_k=tile)
+    g = jnp.asarray(np.random.default_rng(seed).normal(size=q.shape), q.dtype)
+    shape_q = (b * hkv, hq // hkv, s, d)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(shape_q[:3] + (1,))
+    flat = (q.reshape(shape_q), k.reshape(b * hkv, s, d),
+            v.reshape(b * hkv, s, d), g.reshape(shape_q),
+            lse.reshape(shape_q[:3] + (1,)), delta, mask, hkv,
+            1.0 / np.sqrt(d), tile, tile, True)
+    return flat, g
+
+
+# which keys a query selects: two in five of those before it; all of them
+# (a row's last query tile has something in every key tile); its own and the
+# row's first alone (the tiles between are visited and hold nothing)
+KEEPS = {"some": 0.4, "all": 1.0, "ends": 0.0}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("seq,tile,keep", [
+    (16, 16, "some"), (64, 16, "some"), (64, 16, "all"), (64, 16, "ends")])
+def test_fused_backward_is_the_pairs_to_the_bit(seq, tile, keep, group,
+                                                dtype):
+    """dQ, dK, dV of ``sparse_attn_bwd_dqkv`` equal the dq and dkv kernels'
+    in every bit (one tile function, which sums a group's terms of dK and dV
+    before it adds them to the accumulator, in either kernel; a key tile's
+    query tiles come in the same order on both grids), and both are the
+    float32 reference's gradients to the operands' rounding."""
+    q, k, v, mask = _attention_inputs(seq + group, 2 * group, 2, seq,
+                                      keep=KEEPS[keep])
+    mask = mask.at[:, :, 0].set(1)
+    flat, g = _flat_backward_operands(*(a.astype(dtype) for a in (q, k, v)),
+                                      mask, tile)
+    fused, pair = sa._bwd_fused(*flat), sa._bwd_split(*flat)
+    want = jax.grad(lambda *a: jnp.sum(sa.selected_attention_reference(
+        *a, mask)[0] * g.astype(jnp.float32)), argnums=(0, 1, 2))(
+            *(a.astype(dtype).astype(jnp.float32) for a in (q, k, v)))
+    for got, other, ref in zip(fused, pair, want):
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(got, other)
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 1e-2
+        np.testing.assert_allclose(
+            got.reshape(ref.shape).astype(jnp.float32), ref,
+            atol=(1e-6 if dtype == jnp.float32 else 2e-2) * max(scale, 5.0))
+
+
+def _kernels_of(fn, *args):
+    """The names of the ``pallas_call``s in ``fn``'s jaxpr, sorted."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (
+                        value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return sorted(names)
+
+
+def test_the_backward_is_one_kernel_where_the_row_fits_and_the_pair_past_it(
+        bwd_path):
+    q, k, v, mask = _attention_inputs(3)
+    grad = jax.grad(lambda q, k, v: jnp.sum(sa.selected_attention(
+        q, k, v, mask)[0]), argnums=(0, 1, 2))
+    with A.record_attention_paths() as paths:
+        kernels = _kernels_of(grad, q, k, v)
+    assert paths == [f"sparse_attention_bwd:{bwd_path}"]
+    assert kernels == dict(
+        fused=["sparse_attn_bwd_dqkv", "sparse_attn_fwd"],
+        split=["sparse_attn_bwd_dkv", "sparse_attn_bwd_dq",
+               "sparse_attn_fwd"])[bwd_path]
+
+
+@pytest.mark.parametrize("s,d,dtype,fused", [
+    (8192, 128, jnp.bfloat16, True),      # both MoE cells: 16 MiB of the 32
+    (8192, 128, jnp.float32, True),       # 24 MiB
+    (8192, 64, jnp.bfloat16, True),       # a head of 64 pads to the 128 lanes
+    (16384, 128, jnp.bfloat16, True),     # at the budget
+    (16384, 128, jnp.float32, False),     # 48 MiB
+    (32768, 128, jnp.bfloat16, False)])   # 64 MiB
+def test_the_fused_backwards_budget_is_the_rows_accumulators_and_outputs(
+        s, d, dtype, fused):
+    """The predicate both files ask: two float32 accumulators and two
+    double-buffered output blocks of a KV head's ``[s, d]``, ``d`` padded to
+    the lanes, against 32 MiB."""
+    assert sa._bwd_is_fused(s, d, dtype) == fused
+    lanes = -(-d // 128) * 128
+    held = 2 * s * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    assert (held <= 32 * 1024 * 1024) == fused
 
 
 @pytest.mark.parametrize("block", [None, 16])

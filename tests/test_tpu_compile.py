@@ -240,9 +240,10 @@ def test_kernels_keep_their_names(kernel_texts, name):
 def sparse_texts(one_chip):
     """Compiled text of the kernels ``sparse_moe_lm`` runs, at the widths of
     ``chipbench/configs/keye-vl2-30b-a3b-ep8.json``: one row of 8192 tokens,
-    32 query heads over 4 KV heads of 128; 16 experts of 2048 x 768 over the
-    worst case's rows (69 632), and the tokens' movement into and out of
-    those rows."""
+    32 query heads over 4 KV heads of 128 (the attention's backward is the
+    one kernel there; the dq and dkv pair at a row of 32 768, past the fused
+    kernel's VMEM budget); 16 experts of 2048 x 768 over the worst case's
+    rows (69 632), and the tokens' movement into and out of those rows."""
     from sparkflow_tpu.ops import grouped_matmul as G
     from sparkflow_tpu.ops import sparse_attention as S
 
@@ -261,7 +262,17 @@ def sparse_texts(one_chip):
                                              has_aux=True)(q, k, v)
         return grads, S.selected_probs(q, k, lse, mask, interpret=False)
 
-    attention = _compile(attend, q, kv, kv, mask)
+    with A.record_attention_paths() as paths:
+        attention = _compile(attend, q, kv, kv, mask)
+        long = 4 * s
+        pair = _compile(
+            lambda q, k, v, mask: jax.grad(lambda q, k, v: S.selected_attention(
+                q, k, v, mask, interpret=False)[0].astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v),
+            sd((1, 32, long, 128), jnp.bfloat16),
+            sd((1, 4, long, 128), jnp.bfloat16),
+            sd((1, 4, long, 128), jnp.bfloat16), sd((1, long, long), jnp.int8))
+    assert paths == ["sparse_attention_bwd:fused", "sparse_attention_bwd:split"]
     rows = G.rows_bound(s, 8, 16)
     x = sd((rows, 2048), jnp.bfloat16)
     w = sd((16, 2048, 768), jnp.bfloat16)
@@ -310,15 +321,17 @@ def sparse_texts(one_chip):
         return sel, grads
 
     indexer = _compile(index, qi, ki, wi, target)
-    return {"sparse_attn_fwd": attention, "sparse_attn_bwd_dq": attention,
-            "sparse_attn_bwd_dkv": attention, "sparse_attn_probs": attention,
+    return {"sparse_attn_fwd": attention, "sparse_attn_bwd_dqkv": attention,
+            "sparse_attn_bwd_dq_": pair, "sparse_attn_bwd_dkv": pair,
+            "sparse_attn_probs": attention,
             "expert_gmm": product, "expert_tgmm": product,
             "expert_rows_in": movement, "expert_rows_out": movement,
             "index_select": indexer, "index_kl_fwd": indexer,
             "index_kl_bwd_dq": indexer, "index_kl_bwd_dk": indexer}
 
 
-@pytest.mark.parametrize("name", ["sparse_attn_fwd", "sparse_attn_bwd_dq",
+@pytest.mark.parametrize("name", ["sparse_attn_fwd", "sparse_attn_bwd_dqkv",
+                                  "sparse_attn_bwd_dq_",
                                   "sparse_attn_bwd_dkv", "sparse_attn_probs",
                                   "expert_gmm", "expert_tgmm",
                                   "expert_rows_in", "expert_rows_out",
@@ -329,7 +342,9 @@ def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
     """Each kernel of ``ops/sparse_attention.py`` and ``ops/grouped_matmul.py``
     compiles for the chip at the configuration's widths, and its ``name=`` is
     inside the custom call's instruction name, where the benchmark's readers
-    look for it (``chipbench/trace_reads.py``)."""
+    look for it (``chipbench/trace_reads.py``). (``sparse_attn_bwd_dq_``,
+    with the wrapper's underscore, is the dq kernel and not
+    ``sparse_attn_bwd_dqkv``.)"""
     calls = _custom_calls(sparse_texts[name])
     assert any(name in c for c in calls), calls
 
@@ -338,11 +353,12 @@ def test_sparse_kernels_lower_on_tpu_under_their_names(sparse_texts, name):
 
 
 @pytest.fixture(scope="module")
-def block_text(one_chip):
+def block_texts(one_chip):
     """Compiled text of the kernels ``block_diffusion_lm`` runs, at the
     widths of ``chipbench/configs/sdar-30b-a3b-ep8.json``: one row of 4096
     tokens as 8192 positions, blocks of 4, 32 query heads over 4 KV heads of
-    128, forward and both backward kernels."""
+    128, forward and the one backward kernel; the dq and dkv pair at a row of
+    32 768 positions, past the fused kernel's VMEM budget."""
     from sparkflow_tpu.ops import block_attention as B
 
     sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
@@ -350,24 +366,42 @@ def block_text(one_chip):
 
     def attend(q, k, v):
         return jax.grad(lambda q, k, v: B.block_attention(
-            q, k, v, 4096, 4, interpret=False)[0].astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
+            q, k, v, q.shape[2] // 2, 4, interpret=False)[0].astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    return _compile(attend, sd((1, 32, 8192, 128)), sd((1, 4, 8192, 128)),
-                    sd((1, 4, 8192, 128)))
+    with A.record_attention_paths() as paths:
+        cell, long = (_compile(attend, sd((1, 32, s, 128)),
+                               sd((1, 4, s, 128)), sd((1, 4, s, 128)))
+                      for s in (8192, 32768))
+    assert paths == ["block_attention_bwd:fused", "block_attention_bwd:split"]
+    return {"block_attn_fwd": cell, "block_attn_bwd_dqkv": cell,
+            "block_attn_bwd_dq_": long, "block_attn_bwd_dkv": long}
 
 
-@pytest.mark.parametrize("name", ["block_attn_fwd", "block_attn_bwd_dq",
-                                  "block_attn_bwd_dkv"])
-def test_block_attention_lowers_on_tpu_under_its_names(block_text, name):
+@pytest.mark.parametrize("name", ["block_attn_fwd", "block_attn_bwd_dqkv",
+                                  "block_attn_bwd_dq_", "block_attn_bwd_dkv"])
+def test_block_attention_lowers_on_tpu_under_its_names(block_texts, name):
     """Each kernel of ``ops/block_attention.py`` compiles for the chip at the
     configuration's widths (the mask made in the kernel from the indices, the
     grid over the schedule's tiles), and its ``name=`` is inside the custom
     call's instruction name, where the benchmark's readers look for it. No
     name holds ``sparse_attn`` or ``flash``, which other readers match."""
-    calls = _custom_calls(block_text)
+    calls = _custom_calls(block_texts[name])
     assert any(name in c for c in calls), calls
     assert not any("sparse_attn" in c or "flash" in c for c in calls), calls
+
+
+@pytest.mark.parametrize("family", ["sparse", "block"])
+def test_the_cells_side_of_the_fused_backwards_budget_holds_no_pair(
+        sparse_texts, block_texts, family):
+    """At both MoE cells' shapes the backward is the one kernel: neither
+    kernel of the pair is in the compiled program."""
+    text = dict(sparse=sparse_texts["sparse_attn_fwd"],
+                block=block_texts["block_attn_fwd"])[family]
+    calls = _custom_calls(text)
+    assert any(f"{family}_attn_bwd_dqkv" in c for c in calls), calls
+    assert not any(f"{family}_attn_bwd_dq_" in c
+                   or f"{family}_attn_bwd_dkv" in c for c in calls), calls
 
 
 def test_another_block_length_and_head_layout_lower_on_tpu(one_chip):
