@@ -1,12 +1,22 @@
 """What the decoder families (``sparse_moe_lm.py``, ``block_diffusion_lm.py``,
 ``looped_lm.py``) compute alike, written once: RMSNorm, rotary positions, a
-bias-free projection, the head's float32 logits and a row's weighted
-cross-entropy a stretch at a time."""
+bias-free projection, ``q`` and ``k`` on their way from it to the attention
+kernels, the head's float32 logits and a row's weighted cross-entropy a
+stretch at a time.
+
+:func:`heads` makes a block's ``q`` and ``k`` (per-head RMSNorm where the
+family has one, rotary positions, the head-major layout: one kernel,
+``ops/head_rotary.py``). :func:`rms_norm` and :func:`rope` are the same
+arithmetic in ``jnp``: they remain for the norms over the hidden width, for
+keye's indexer (heads of 64 that are not transposed) and as what the tests
+hold :func:`heads` to."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.head_rotary import head_rotary
 
 
 def rms_norm(x, scale, eps):
@@ -16,16 +26,23 @@ def rms_norm(x, scale, eps):
     return y.astype(x.dtype)
 
 
+def _angles(s: int, d: int, theta: float, positions):
+    """The rotation angles of a head of ``d`` at each of ``s`` indices,
+    float32 ``[s, d // 2]``; index ``i`` is at ``positions[i]``, ``i`` itself
+    by default."""
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    return positions.astype(jnp.float32)[:, None] * inv[None, :]
+
+
 def rope(x, theta: float, positions=None):
     """Rotary positions over the whole last axis of ``x [B, S, ..., D]``
     (rotate-half); the position of index ``i`` along axis 1 is
     ``positions[i]``, ``i`` itself by default; computed in float32."""
     s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    if positions is None:
-        positions = jnp.arange(s, dtype=jnp.float32)
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    ang = ang.reshape((1, s) + (1,) * (x.ndim - 3) + (d // 2,))
+    ang = _angles(s, d, theta, positions).reshape(
+        (1, s) + (1,) * (x.ndim - 3) + (d // 2,))
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
     x32 = x.astype(jnp.float32)
@@ -36,6 +53,19 @@ def rope(x, theta: float, positions=None):
 
 def dense(x, kernel):
     return jnp.matmul(x, kernel.astype(x.dtype))
+
+
+def heads(x, n: int, scale, eps: float, theta: float, positions=None):
+    """A projection's output ``x [B, S, n * D]`` as the attention kernels
+    read it, ``[B, n, S, D]``: ``transpose(rope(rms_norm(x.reshape(B, S, n,
+    D), scale, eps), theta, positions), (0, 2, 1, 3))`` in one pass over
+    ``x`` (:func:`~sparkflow_tpu.ops.head_rotary.head_rotary`; ``scale``
+    ``None``: no norm). The positions' cosines and sines are two float32 ``[S,
+    D]`` tables made here, the rotate-half's sign in the sines."""
+    ang = _angles(x.shape[1], x.shape[-1] // n, theta, positions)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return head_rotary(x, n, jnp.concatenate([cos, cos], axis=-1),
+                       jnp.concatenate([-sin, sin], axis=-1), scale, eps)
 
 
 def head_logits(h, kernel):

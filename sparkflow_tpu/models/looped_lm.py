@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
 from .base import RegistryModel
-from .lm_ops import dense, head_logits, rms_norm, rope, weighted_nll
+from .lm_ops import dense, head_logits, heads, rms_norm, weighted_nll
 from .registry import register_model
 
 
@@ -114,7 +114,9 @@ class LoopedLM(RegistryModel):
 
     def _block(self, bp, x):
         """One layer on ``x [B, S, h]``: sandwich-norm attention, then a
-        sandwich-norm SiLU-gated MLP."""
+        sandwich-norm SiLU-gated MLP. ``q`` and ``k`` reach the kernel's
+        layout through ``lm_ops.heads`` (rotary positions alone: no per-head
+        norm); the four hidden-width norms are ``lm_ops.rms_norm``."""
         b, s, _ = x.shape
         eps = self.rms_eps
         # ``attention`` groups the half's two parts: what is dense around
@@ -122,12 +124,12 @@ class LoopedLM(RegistryModel):
         with jax.named_scope("attention"):
             with jax.named_scope("attn_proj"):
                 y = rms_norm(x, bp["ln1_scale"], eps)
-                heads = lambda a: a.reshape(b, s, self.num_heads,
-                                            self.head_dim)
-                q = rope(heads(dense(y, bp["q_kernel"])), self.rope_theta)
-                k = rope(heads(dense(y, bp["k_kernel"])), self.rope_theta)
-                v = heads(dense(y, bp["v_kernel"]))
-                q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+                q = heads(dense(y, bp["q_kernel"]), self.num_heads, None,
+                          eps, self.rope_theta)
+                k = heads(dense(y, bp["k_kernel"]), self.num_heads, None,
+                          eps, self.rope_theta)
+                v = dense(y, bp["v_kernel"]).reshape(b, s, self.num_heads, -1)
+                v = jnp.transpose(v, (0, 2, 1, 3))
             with jax.named_scope("flash_attention"):
                 att = flash_attention(q, k, v, causal=True)
             with jax.named_scope("attn_proj"):

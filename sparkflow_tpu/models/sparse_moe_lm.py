@@ -61,7 +61,7 @@ from ..ops import grouped_matmul as gm
 from ..ops import sparse_attention as sa
 from .base import RegistryModel, _Names
 from .lm_ops import dense as _dense
-from .lm_ops import head_logits, rms_norm, rope, weighted_nll
+from .lm_ops import head_logits, heads, rms_norm, rope, weighted_nll
 from .registry import register_model
 
 
@@ -85,7 +85,8 @@ KEPT = jax.checkpoint_policies.save_only_these_names(
 class MoEDecoder(RegistryModel):
     """What the families of this file and of ``block_diffusion_lm.py``
     share, written once: the block's norms, projections, per-head q/k norm
-    and rotary positions (:meth:`_qkv`), its router and experts
+    and rotary positions (:meth:`_qkv`; ``lm_ops.heads`` makes ``q`` and
+    ``k``, ``lm_ops.rms_norm`` the hidden-width norms), its router and experts
     (:meth:`_experts`), the residual wiring (:meth:`_block`), the stack of
     checkpointed blocks (:meth:`_encode`), the head and a row's weighted
     cross-entropy a stretch at a time (:meth:`_weighted_nll`; both through
@@ -187,19 +188,19 @@ class MoEDecoder(RegistryModel):
     def _qkv(self, bp, y):
         """``y = RMSNorm(x) [B, S, h]`` -> ``q [B, Hq, S, D]``, ``k, v [B,
         Hkv, S, D]``: the projections, RMSNorm over each head of ``q`` and
-        ``k``, rotary positions over the whole head."""
+        ``k``, rotary positions over the whole head. ``q`` and ``k`` go from
+        the product to the kernels' layout through
+        :func:`~sparkflow_tpu.models.lm_ops.heads`, one pass each; ``v`` has
+        neither norm nor rotation and is transposed."""
         b, s, _ = y.shape
-        heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
         with jax.named_scope("attn_proj"):
             pos = self._positions(s)
-            q = heads(_dense(y, bp["q_kernel"]), self.num_heads)
-            k = heads(_dense(y, bp["k_kernel"]), self.num_kv_heads)
-            v = heads(_dense(y, bp["v_kernel"]), self.num_kv_heads)
-            q = rope(rms_norm(q, bp["q_norm"], self.rms_eps),
-                     self.rope_theta, pos)
-            k = rope(rms_norm(k, bp["k_norm"], self.rms_eps),
-                     self.rope_theta, pos)
-            return tuple(jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+            q = heads(_dense(y, bp["q_kernel"]), self.num_heads,
+                      bp["q_norm"], self.rms_eps, self.rope_theta, pos)
+            k = heads(_dense(y, bp["k_kernel"]), self.num_kv_heads,
+                      bp["k_norm"], self.rms_eps, self.rope_theta, pos)
+            v = _dense(y, bp["v_kernel"]).reshape(b, s, self.num_kv_heads, -1)
+            return q, k, jnp.transpose(v, (0, 2, 1, 3))
 
     def _attend(self, bp, y):
         """The attention on ``y = RMSNorm(x) [B, S, h]``: its output by head
@@ -344,13 +345,14 @@ class SparseMoELM(MoEDecoder):
         by head, and in ``aux`` each row's indexer loss ``[B]`` and the keys
         a query selected (mean)."""
         b, s, _ = y.shape
-        heads = lambda a, n: a.reshape(b, s, n, a.shape[-1] // n)
         q, k, v = self._qkv(bp, y)
 
         with jax.named_scope("indexer"):
             ys = jax.lax.stop_gradient(y)
-            qi = rope(heads(_dense(ys, bp["idx_q_kernel"]),
-                            self.indexer_heads), self.rope_theta)
+            # heads of 64 in the index kernels' own layout: ``rope``, not
+            # ``heads``
+            qi = rope(_dense(ys, bp["idx_q_kernel"]).reshape(
+                b, s, self.indexer_heads, -1), self.rope_theta)
             ki = rope(_dense(ys, bp["idx_k_kernel"]), self.rope_theta)
             w = _dense(ys, bp["idx_w_kernel"])
             mask = sa.index_select(qi, ki, w, self.indexer_topk,

@@ -144,6 +144,29 @@ def test_the_parts_of_a_family_are_there_forward_and_backward(step):
     assert ("optimizer", True) not in every
 
 
+@pytest.mark.parametrize("kernel", ["head_rotary_fwd", "head_rotary_bwd"])
+def test_the_heads_kernels_lie_under_attn_proj_and_no_second_part(step,
+                                                                  kernel):
+    """``q`` and ``k`` on their way to the attention kernels
+    (``ops/head_rotary.py``) are ``attn_proj``'s in the three families that
+    rotate their heads: the forward kernel in the forward pass and, made
+    again, inside the backward; its transpose in the backward alone. The
+    GPT-2 family has learned positions and neither kernel."""
+    family, jaxpr = step
+    found = [(scopes, "transpose" in transforms)
+             for scopes, transforms, eqn in equations(jaxpr)
+             if eqn.primitive.name == "pallas_call"
+             and eqn.params["name"] == kernel]
+    if family == "transformer_lm":
+        assert found == []
+        return
+    assert found and all(
+        [n for n in scopes if n in STEP_PARTS] == ["attn_proj"]
+        for scopes, _ in found), found
+    backward = {back for _, back in found}
+    assert backward == ({True} if kernel.endswith("bwd") else {False, True})
+
+
 def test_a_subpart_lies_inside_its_part_and_nowhere_else(step):
     for scopes, _, eqn in equations(step[1]):
         for name in set(scopes) & set(STEP_SUBPARTS):

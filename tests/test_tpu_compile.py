@@ -19,6 +19,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -415,3 +416,106 @@ def test_another_block_length_and_head_layout_lower_on_tpu(one_chip):
         q, k, v, 1536, 16, interpret=False)[0], sd((1, 8, 3072, 128)),
         sd((1, 1, 3072, 128)), sd((1, 1, 3072, 128)))
     assert "block_attn_fwd" in text
+
+
+# -- q and k on their way to the attention kernels ------------------------------
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """``ops/head_rotary.py`` has no ``interpret`` argument: it asks the
+    backend, a CPU here, so the test answers for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _outputs(text):
+    """``(instruction, the text of its result's shape)`` of the compiled
+    text's entry computation: what the program writes to memory (a fusion's
+    inner instructions write nothing)."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    return re.findall(r"^\s*(?:ROOT )?%?(\S+) = (.*?)\s[\w\-]+\(", entry,
+                      re.M)
+
+
+def _float32_rows(text, positions, elements):
+    """The float32 arrays the program writes that have a dimension of
+    ``positions`` and ``elements`` elements or more."""
+    found = []
+    for name, shape in _outputs(text):
+        for dims in re.findall(r"f32\[([\d,]+)\]", shape):
+            dims = [int(n) for n in dims.split(",")]
+            if positions in dims and np.prod(dims) >= elements:
+                found.append((name, dims))
+    return found
+
+
+# the cells' projections: keye's and sdar's q and k (a row of 8192 positions,
+# 32 and 4 heads of 128), ouro's q and k (two rows of 4096, 16 heads)
+HEADS = [(1, 8192, 32), (1, 8192, 4), (2, 4096, 16)]
+
+
+@pytest.mark.parametrize("norm", [True, False], ids=["norm", "no_norm"])
+@pytest.mark.parametrize("b, s, h", HEADS,
+                         ids=["moe-q", "moe-k", "train-ouro-seq4k"])
+def test_head_rotary_lowers_on_tpu_under_its_names(one_chip, on_a_tpu, b, s,
+                                                   h, norm):
+    """``head_rotary_fwd`` and ``head_rotary_bwd`` compile for the chip at
+    the cells' shapes, with the per-head norm and without, and each
+    ``name=`` is inside its custom call's instruction name, where the
+    benchmark's reader looks for ``head_rotary_``."""
+    from sparkflow_tpu.models import lm_ops
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+
+    def fwd_bwd(x, scale):
+        return jax.grad(lambda x, scale: jnp.sum(jnp.square(lm_ops.heads(
+            x, h, scale if norm else None, 1e-6, 1e6).astype(jnp.float32))),
+            argnums=(0, 1) if norm else 0)(x, scale)
+
+    with A.record_attention_paths() as paths:
+        calls = _custom_calls(_compile(fwd_bwd, sd((b, s, h * 128),
+                                                   jnp.bfloat16),
+                                       sd((128,), jnp.float32)))
+    assert paths == ["head_rotary:pallas"]
+    assert len(calls) == 2 and any("head_rotary_fwd" in c for c in calls)
+    assert any("head_rotary_bwd" in c for c in calls), calls
+
+
+def test_the_moe_blocks_q_and_k_are_written_once_after_their_product(
+        one_chip, on_a_tpu):
+    """The compiled forward of ``MoEDecoder._qkv`` at the MoE cells' widths
+    (a row of 8192, 32 query heads over 4 KV heads of 128): two kernels, and
+    no float32 array over a row's positions as large as ``k`` (``S x 4 x
+    128``; ``q`` is eight of them). Written as ``rope(rms_norm(...))`` and a
+    transpose, XLA wrote the normed ``q`` and its rotate-half as float32
+    ``[1, 8192, 32, 128]`` each before the bfloat16 result and its copy."""
+    from sparkflow_tpu.models.sparse_moe_lm import SparseMoELM
+
+    model = SparseMoELM(vocab_size=1024, num_layers=1, num_experts=16,
+                        compute_dtype="bfloat16")
+    bp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, (shape, _) in model._block_specs().items()}
+    y = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+    text = _compile(model._qkv, bp, y)
+    assert sum("head_rotary_fwd" in c for c in _custom_calls(text)) == 2
+    assert _float32_rows(text, 8192, 8192 * 4 * 128) == []
+
+
+def test_ouros_block_writes_no_float32_heads(one_chip, on_a_tpu):
+    """The compiled forward of ``LoopedLM._block`` at ouro's widths (two rows
+    of 4096, 16 heads of 128, hidden 2048): ``q`` and ``k`` through the
+    kernel, and no float32 array over the rows' positions as large as one of
+    them (both products wrote float32 ``[2, 4096, 2048]`` for the rotation,
+    and the rotate-halves were float32 arrays too)."""
+    from sparkflow_tpu.models.looped_lm import LoopedLM
+
+    model = LoopedLM(vocab_size=1024, compute_dtype="bfloat16")
+    bp = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+          for name, (shape, _) in model.param_specs()["block_0"].items()}
+    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    text = _compile(model._block, bp, x)
+    calls = _custom_calls(text)
+    assert sum("head_rotary_fwd" in c for c in calls) == 2, calls
+    assert any("flash_fwd" in c for c in calls), calls
+    assert _float32_rows(text, 4096, 2 * 4096 * 16 * 128) == []
