@@ -28,6 +28,9 @@ whose choice of the backward (``_bwd_is_fused``: one kernel with the KV
 head's whole dK and dV in VMEM where they fit, the pair ``block_attn_bwd_dq``,
 ``block_attn_bwd_dkv`` past that) this file shares;
 ``record_attention_paths()`` holds ``block_attention_bwd:fused`` or ``:split``.
+The forward's key tile is its own too (``_tiles``: 512 x 1 024 against
+the backward's 512 x 512 where the half row allows), and the log holds
+``block_attention_fwd:<block_q>x<block_k>``.
 
 :func:`visible` and :func:`block_attention_reference` are the rule and the
 attention in plain ``jnp``; on a CPU the kernels run interpreted.
@@ -47,8 +50,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _log_path
-from .sparse_attention import (NEG_INF, _block, _bwd_is_fused, _bwd_tile,
-                               _chunk, _params, _row_spec)
+from .sparse_attention import (NEG_INF, _bwd_is_fused, _bwd_tile,
+                               _chunk, _params, _row_spec, _tiles)
 
 # ``checkpoint_name``s of what the backward kernels read of the forward: a
 # ``jax.checkpoint`` that keeps them runs ``block_attn_fwd`` no second time
@@ -315,6 +318,7 @@ def _forward(q, k, v, cfg):
     b, hq, s, d = q.shape
     qf, kf, vf = _layout(q, k, v)
     bh, group = qf.shape[:2]
+    _log_path("block_attention_fwd", f"{block_q}x{block_k}")
     qspec, kspec, stat = _specs(group, block_q, block_k, d)
     out, lse = _call(
         functools.partial(_fwd_kernel, group=group, **_static(cfg)),
@@ -387,20 +391,22 @@ def _bwd_split(operands, cfg):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attend(q, k, v, cfg):
-    return _forward(q, k, v, cfg)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attend(q, k, v, fwd_cfg, bwd_cfg):
+    """The forward kernel's ``cfg`` and the backward's: they differ in the
+    key tile."""
+    return _forward(q, k, v, fwd_cfg)
 
 
-def _attend_fwd(q, k, v, cfg):
-    out, lse = _forward(q, k, v, cfg)
+def _attend_fwd(q, k, v, fwd_cfg, bwd_cfg):
+    out, lse = _forward(q, k, v, fwd_cfg)
     # the names sit on the values the backward kernels read
     out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _attend_bwd(cfg, res, g):
-    return _backward(*res, g[0], cfg)
+def _attend_bwd(fwd_cfg, bwd_cfg, res, g):
+    return _backward(*res, g[0], bwd_cfg)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
@@ -416,10 +422,11 @@ def block_attention(q, k, v, length: int, block: int,
     by ``Hq / Hkv`` query heads each; ``length`` and ``block`` (a power of two)
     are static.
     Returns ``(out, lse [B, Hq, 2 length])``; the logsumexp carries no
-    gradient. ``block_q`` and ``block_k`` are the tiles (each has to divide
-    ``length``; by default the largest of 512, 256, 128 that does, else
-    ``length``). The forward of the ``custom_vjp`` names ``out`` and ``lse``
-    :data:`ATTN_OUT` and :data:`ATTN_LSE` for a checkpoint to keep."""
+    gradient. ``block_q`` and ``block_k`` are every kernel's tiles (each has
+    to divide ``length``; by default the largest of 512, 256, 128 that does,
+    else ``length``, and for the forward's keys of 1 024 too). The forward of
+    the ``custom_vjp`` names ``out`` and ``lse`` :data:`ATTN_OUT` and
+    :data:`ATTN_LSE` for a checkpoint to keep."""
     if q.shape[2] != 2 * length or length % block or block & (block - 1):
         raise ValueError(f"a row of {q.shape[2]} positions is not a clean and "
                          f"a noised copy of {length} tokens in blocks of "
@@ -428,6 +435,7 @@ def block_attention(q, k, v, length: int, block: int,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _attend(q, k, v, (int(length), int(block), float(scale),
-                             block_q or _block(length),
-                             block_k or _block(length), bool(interpret)))
+    fwd_cfg, bwd_cfg = ((int(length), int(block), float(scale), *tile,
+                         bool(interpret))
+                        for tile in _tiles(length, block_q, block_k))
+    return _attend(q, k, v, fwd_cfg, bwd_cfg)

@@ -26,6 +26,9 @@ attention runs over those keys only.
   shape alone) the pair ``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv`` runs
   the same tile function twice; ``record_attention_paths()`` of
   ``ops/attention.py`` holds ``sparse_attention_bwd:fused`` or ``:split``.
+  The forward walks key tiles twice the backward's where the row allows
+  (:func:`_tiles`: 512 x 1 024 against 512 x 512), and the log holds
+  ``sparse_attention_fwd:<block_q>x<block_k>``.
 - :func:`selected_probs`: the attention's probabilities summed over the
   heads and normalised to one (kernel ``sparse_attn_probs``), the target of
   the indexer's loss.
@@ -349,6 +352,7 @@ def _forward(q, k, v, mask, scale, block_q, block_k, interpret):
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
+    _log_path("sparse_attention_fwd", f"{block_q}x{block_k}")
     qf = q.reshape(b * hkv, group, s, d)
     kf, vf = k.reshape(b * hkv, s, d), v.reshape(b * hkv, s, d)
     qspec, kspec, stat, mspec = _specs(group, block_q, block_k, d, hkv)
@@ -486,7 +490,11 @@ def _bwd_dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ``d <= 128``. With the group's tiles, statistics and dQ the v5e's compiler
 # allocates, at a group of 8 and tiles of 512 x 512: 34.8 MB for 8 192
 # positions in bfloat16 (both MoE cells), under 50 MB in float32, under 52 MB
-# for 16 384 in bfloat16 (of ``_VMEM_LIMIT``, of the chip's 128 MiB).
+# for 16 384 in bfloat16 (of ``_VMEM_LIMIT``, of the chip's 128 MiB). The
+# forward kernels hold no row: at the same shapes and their own tiles of 512 x
+# 1 024 (:func:`_tiles`) the least limit the compiler takes is 36.1 MB for
+# ``sparse_attn_fwd`` and 35.3 MB for ``block_attn_fwd`` (18.3 and 17.9 at
+# 512 x 512, 49.8 and 47.8 at 512 x 2 048), of ``_VMEM_LIMIT``'s 100.7 MB.
 _FUSED_DKV_VMEM_BUDGET = 32 * 1024 * 1024
 
 
@@ -585,22 +593,24 @@ def _bwd_split(qf, kf, vf, gf, lsef, delta, mask, hkv, scale, block_q,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _selected(q, k, v, mask, scale, block_q, block_k, interpret):
-    return _forward(q, k, v, mask, scale, block_q, block_k, interpret)
+def _selected(q, k, v, mask, scale, fwd_tile, bwd_tile, interpret):
+    """``fwd_tile`` and ``bwd_tile``: the ``(block_q, block_k)`` of the
+    forward kernel and of the backward's."""
+    return _forward(q, k, v, mask, scale, *fwd_tile, interpret)
 
 
-def _selected_fwd(q, k, v, mask, scale, block_q, block_k, interpret):
-    out, lse = _forward(q, k, v, mask, scale, block_q, block_k, interpret)
+def _selected_fwd(q, k, v, mask, scale, fwd_tile, bwd_tile, interpret):
+    out, lse = _forward(q, k, v, mask, scale, *fwd_tile, interpret)
     # the names sit on the values the backward kernels read: a checkpoint
     # that keeps them by name does not run ``sparse_attn_fwd`` again
     out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _selected_bwd(scale, block_q, block_k, interpret, res, g):
+def _selected_bwd(scale, fwd_tile, bwd_tile, interpret, res, g):
     q, k, v, mask, out, lse = res
-    dq, dk, dv = _backward(q, k, v, mask, out, lse, g[0], scale, block_q,
-                           block_k, interpret)
+    dq, dk, dv = _backward(q, k, v, mask, out, lse, g[0], scale, *bwd_tile,
+                           interpret)
     return dq, dk, dv, None
 
 
@@ -608,12 +618,30 @@ _selected.defvjp(_selected_fwd, _selected_bwd)
 
 
 def _block(s: int, cap: int = 512) -> int:
-    """The largest of 512, 256, 128 that divides ``s``; a shorter or odd
-    sequence is one block."""
-    for b in (512, 256, 128):
+    """The largest of 1024, 512, 256, 128 up to ``cap`` that divides ``s``;
+    a shorter or odd sequence is one block."""
+    for b in (1024, 512, 256, 128):
         if b <= cap and s % b == 0:
             return b
     return s
+
+
+def _tiles(s: int, block_q: Optional[int] = None,
+           block_k: Optional[int] = None):
+    """The ``(block_q, block_k)`` of a masked attention's forward kernel and
+    of its backward's, for rows of ``s`` keys (``ops/block_attention.py``
+    asks too, of its half row). Tiles a caller names are every kernel's. By
+    default both are :func:`_block` of the row, and the forward's key tile
+    one step wider, up to 1 024: a row of 1 024 or more that 1 024 divides
+    walks 512 x 1 024 forward and 512 x 512 backward. The forward is bound by
+    the online softmax's float32 vector work, done once a visit and head
+    (scale, two selects, max, exp, sum, the rescaling of the group's
+    accumulators), and twice the keys a visit halve the rescalings and the
+    grid steps; the backwards have no running maximum and are at their best
+    at 512 x 512."""
+    block_q = block_q or _block(s)
+    return ((block_q, block_k or _block(s, 1024)),
+            (block_q, block_k or _block(s)))
 
 
 def selected_attention(q, k, v, mask, sm_scale: Optional[float] = None,
@@ -627,13 +655,13 @@ def selected_attention(q, k, v, mask, sm_scale: Optional[float] = None,
     gradient (:func:`selected_probs` reads it). Every query has to select at
     least one key. The backward kernels read ``out`` and ``lse`` again: the
     forward of the ``custom_vjp`` names them :data:`ATTN_OUT` and
-    :data:`ATTN_LSE` for a checkpoint to keep."""
+    :data:`ATTN_LSE` for a checkpoint to keep. ``block_q`` and ``block_k``
+    are every kernel's tiles; by default :func:`_tiles` of the row."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    s = q.shape[2]
     return _selected(q, k, v, mask.astype(jnp.int8), scale,
-                     block_q or _block(s), block_k or _block(s), interpret)
+                     *_tiles(q.shape[2], block_q, block_k), interpret)
 
 
 def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, sm_scale, hkv,

@@ -113,7 +113,8 @@ def both(request):
             q, k, v, length, block, block_q=tile, block_k=tile))
         want = run(lambda q, k, v: ba.block_attention_reference(
             q, k, v, length, block))
-    assert paths == [f"block_attention_bwd:{path}"]
+    assert paths == [f"block_attention_fwd:{tile}x{tile}",
+                     f"block_attention_bwd:{path}"]
     return got, want
 
 
@@ -139,6 +140,166 @@ def test_query_and_key_tiles_need_not_be_equal(block_q, block_k):
         want, want_lse = ba.block_attention_reference(q, k, v, 32, 4)
     np.testing.assert_allclose(out, want, atol=1e-5)
     np.testing.assert_allclose(lse, want_lse, atol=1e-5)
+
+
+def _kernel_grids(fn, *args):
+    """The grid of each ``pallas_call`` at the top of ``fn``'s jaxpr, by the
+    kernel's name (traced; nothing runs)."""
+    return {e.params["name"]: e.params["grid_mapping"].grid
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"}
+
+
+# -- the forward's key tile: twice the backward's where the half row allows ----
+
+
+@pytest.mark.parametrize("order", ["qk", "kq"])
+@pytest.mark.parametrize("length,block,block_q,block_k,visits", [
+    (4096, 4, 512, 1024, 48),     # the sdar cell's forward
+    (4096, 4, 512, 512, 80),      # its backward
+    (4096, 4, 512, 2048, 32),
+    (64, 8, 16, 32, 16), (48, 4, 8, 24, 24)])
+def test_unequal_tiles_hold_every_visible_pairs_tile_once(
+        length, block, block_q, block_k, visits, order):
+    """A key tile wider than the query tile: the schedule is the tiles the
+    rule leaves a pair in, each once, and no other."""
+    mask = np.asarray(ba.visible(length, block))
+    nq, nk = 2 * length // block_q, 2 * length // block_k
+    by_rule = {(int(qi), int(ki)) for qi, ki in zip(*np.nonzero(
+        mask.reshape(nq, block_q, nk, block_k).any(axis=(1, 3))))}
+    qt, kt, first, last = ba.tile_schedule(length, block, block_q, block_k,
+                                           order)
+    visited = list(zip(qt.tolist(), kt.tolist()))
+    assert len(visited) == visits == len(by_rule)
+    assert set(visited) == by_rule
+    runs = (qt if order == "qk" else kt).tolist()
+    assert [runs[i] for i in range(visits) if first[i]] == sorted(set(runs))
+    assert last.tolist() == first.tolist()[1:] + [1]
+
+
+# (L, every kernel's tile by default, the forward's key tile): a half row
+# of two and of three key tiles of 1 024; one 1 024 does not divide; one
+# shorter than 1 024
+DEFAULT_TILES = [(2048, 512, 1024), (3072, 512, 1024), (1536, 512, 512),
+                 (384, 128, 128)]
+
+
+@pytest.mark.parametrize("length,tile,fwd_k", DEFAULT_TILES)
+def test_the_forward_at_its_own_key_tile_is_the_reference_and_the_equal_tiles(
+        length, tile, fwd_k):
+    """By default the forward walks the largest of 1 024, 512, 256, 128 keys
+    that divides the half row and logs its tile; ``out`` and ``lse`` are the
+    reference's, and the forward's at the backward's tiles, to float32's
+    rounding."""
+    q, k, v, _ = _inputs(length, seed=2, hq=2, hkv=1, rows=1)
+    with jax.default_matmul_precision("highest"), \
+            A.record_attention_paths() as paths:
+        out, lse = ba.block_attention(q, k, v, length, 4)
+        want, want_lse = ba.block_attention_reference(q, k, v, length, 4)
+        equal, equal_lse = ba.block_attention(q, k, v, length, 4,
+                                              block_q=tile, block_k=tile)
+    assert paths == [f"block_attention_fwd:{tile}x{fwd_k}",
+                     f"block_attention_fwd:{tile}x{tile}"]
+    for got, ref in ((out, want), (lse, want_lse), (out, equal),
+                     (lse, equal_lse)):
+        np.testing.assert_allclose(got, ref, atol=1e-5)
+
+
+# (L, B, the query tile, the forward's key tile, the backward's, Hq, Hkv)
+WIDE_FORWARDS = [
+    (1024, 4, 512, 1024, 512, 4, 2),  # the cells' tiles, a half row of one
+    (64, 8, 16, 32, 16, 4, 2), (96, 4, 16, 32, 16, 4, 2),
+    (64, 16, 16, 64, 16, 4, 2),
+    # every other query tile's own key tile is half empty (the blocks after
+    # its own): two clean key tiles; a key tile as wide as the half row
+    (512, 4, 128, 256, 128, 8, 1), (512, 4, 128, 256, 128, 4, 2),
+    (512, 8, 256, 512, 256, 8, 1), (512, 8, 256, 512, 256, 4, 2)]
+
+
+# the dq and dkv pair on the cells' tiles, on toy tiles and on a group of 8
+@pytest.mark.parametrize("length,block,block_q,fwd_k,bwd_k,hq,hkv,path", [
+    case + ("fused",) for case in WIDE_FORWARDS] + [
+    WIDE_FORWARDS[i] + ("split",) for i in (0, 1, 4)])
+def test_a_forward_at_twice_the_backwards_key_tile_moves_no_gradient(
+        length, block, block_q, fwd_k, bwd_k, hq, hkv, path, monkeypatch):
+    """``_attend`` with the forward's ``cfg`` at ``block_q x fwd_k`` and the
+    backward's at ``block_q x bwd_k``: ``out`` and ``lse`` are the
+    reference's. The backward's tile did not move: dQ, dK, dV through the
+    ``custom_vjp`` are, to the bit, the backward kernels' at ``block_q x
+    bwd_k`` on the wide forward's ``out`` and ``lse`` (which differ from the
+    equal tiles' in float32's last place, and the gradients with them), and
+    the reference's."""
+    if path == "split":
+        monkeypatch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+    q, k, v, w = _inputs(length, seed=fwd_k, hq=hq, hkv=hkv,
+                         rows=1 if length > 96 else 2)
+    cfg = lambda keys: (length, block, 1.0 / np.sqrt(q.shape[-1]), block_q,
+                        keys, True)
+    wide = lambda *a: ba._attend(*a, cfg(fwd_k), cfg(bwd_k))
+    plain = lambda *a: ba.block_attention_reference(*a, length, block)
+    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a)[0] * w),
+                                argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (out, lse), (want, want_lse) = wide(q, k, v), plain(q, k, v)
+        with A.record_attention_paths() as paths:
+            got = grads(wide)
+            same = ba._backward(q, k, v, out, lse, w, cfg(bwd_k))
+        want_grads = grads(plain)
+    assert paths == [f"block_attention_fwd:{block_q}x{fwd_k}",
+                     f"block_attention_bwd:{path}",
+                     f"block_attention_bwd:{path}"]
+    np.testing.assert_allclose(out, want, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, atol=1e-5)
+    for a, b, ref in zip(got, same, want_grads):
+        assert float(jnp.max(jnp.abs(ref))) > 1e-2
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(a, ref, atol=1e-5)
+
+
+# (L, every kernel's tile by default, the forward's key tile, the visits of
+# the forward's schedule and of the backward's): the sdar cell's half row; one
+# key tile of 1 024; half rows of 512 and 384 as before
+RULE = [(4096, 512, 1024, 48, 80), (1024, 512, 1024, 6, 8),
+        (512, 512, 512, 3, 3), (384, 128, 128, 15, 15)]
+
+
+@pytest.mark.parametrize("length,tile,fwd_k,fwd_visits,bwd_visits", RULE)
+def test_by_default_the_forward_walks_its_own_key_tile_of_the_half_row(
+        length, tile, fwd_k, fwd_visits, bwd_visits):
+    """Traced at the cell's widths (nothing runs): the rule is
+    ``ops/sparse_attention._tiles`` of ``length``, ``block_attn_fwd`` runs
+    over the schedule of its tile and ``block_attn_bwd_dqkv`` over the
+    schedule of 512 x 512, under their names and path entries."""
+    assert sa._tiles(length) == ((tile, fwd_k), (tile, tile))
+    sd = jax.ShapeDtypeStruct
+    q = sd((1, 32, 2 * length, 128), jnp.bfloat16)
+    kv = sd((1, 4, 2 * length, 128), jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(ba.block_attention(
+        q, k, v, length, 4)[0].astype(jnp.float32)), argnums=(0, 1, 2))
+    with A.record_attention_paths() as paths:
+        grids = _kernel_grids(grad, q, kv, kv)
+    assert paths == [f"block_attention_fwd:{tile}x{fwd_k}",
+                     "block_attention_bwd:fused"]
+    assert grids == {"block_attn_fwd": (4, fwd_visits),
+                     "block_attn_bwd_dqkv": (4, bwd_visits)}
+    assert fwd_visits == ba.tile_schedule(length, 4, tile, fwd_k).shape[1]
+    assert bwd_visits == ba.tile_schedule(length, 4, tile, tile).shape[1]
+
+
+def test_an_explicit_tile_is_every_kernels():
+    """``block_q=`` / ``block_k=`` mean what they meant: the forward takes
+    them too, on a half row whose default would be 512 x 1 024."""
+    length = 2048
+    q, k, v, _ = _inputs(length, hq=2, hkv=1, rows=1)
+    grad = jax.grad(lambda q: jnp.sum(ba.block_attention(
+        q, k, v, length, 4, block_q=256, block_k=512)[0]))
+    with A.record_attention_paths() as paths:
+        grids = _kernel_grids(grad, q)
+    assert paths == ["block_attention_fwd:256x512",
+                     "block_attention_bwd:fused"]
+    visits = ba.tile_schedule(length, 4, 256, 512).shape[1]
+    assert grids == {"block_attn_fwd": (1, visits),
+                     "block_attn_bwd_dqkv": (1, visits)}
 
 
 def _kernel_operands(fn, *args):
@@ -176,7 +337,7 @@ def test_no_mask_operand_reaches_the_kernels():
     with A.record_attention_paths() as paths:
         calls = _kernel_operands(_grad_of_the_sum(length),
                                  *_inputs(length)[:3])
-    assert paths == ["block_attention_bwd:fused"]
+    assert paths == ["block_attention_fwd:8x8", "block_attention_bwd:fused"]
     assert sorted(calls) == ["block_attn_bwd_dqkv", "block_attn_fwd"]
     for shapes in calls.values():
         assert not any(s[-2:] == (2 * length, 2 * length) for s in shapes)
@@ -188,7 +349,7 @@ def test_past_the_budget_the_backward_is_the_pair_and_takes_no_mask_either(
     with A.record_attention_paths() as paths:
         calls = _kernel_operands(_grad_of_the_sum(length),
                                  *_inputs(length)[:3])
-    assert paths == ["block_attention_bwd:split"]
+    assert paths == ["block_attention_fwd:8x8", "block_attention_bwd:split"]
     assert sorted(calls) == ["block_attn_bwd_dkv", "block_attn_bwd_dq",
                              "block_attn_fwd"]
     for shapes in calls.values():

@@ -168,7 +168,8 @@ def test_a_blocks_backward_runs_the_forward_kernel_once(remat):
         assert calls[name] == cfg["num_hidden_layers"], calls
     assert not {"block_attn_bwd_dq", "block_attn_bwd_dkv"} & set(calls)
     # (a checkpointed block is traced once for all layers)
-    assert set(paths) == {"block_attention_bwd:fused", "head_rotary:pallas"}
+    assert set(paths) == {f"block_attention_fwd:{L}x{L}",
+                          "block_attention_bwd:fused", "head_rotary:pallas"}
 
 
 @pytest.mark.parametrize("remat", [True, False])
@@ -205,7 +206,7 @@ def a_blocks_gradients():
         patch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
         pair = grad()
     # (the block's ``head_rotary:pallas`` are in the log too)
-    assert [p for p in paths if p.startswith("block_attention")] == [
+    assert [p for p in paths if p.startswith("block_attention_bwd")] == [
         "block_attention_bwd:fused", "block_attention_bwd:split"]
     return dict(fused[0], x=fused[1]), dict(pair[0], x=pair[1])
 

@@ -258,7 +258,7 @@ def a_blocks_gradients():
         patch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
         pair = grad()
     # (the block's ``head_rotary:pallas`` are in the log too)
-    assert [p for p in paths if p.startswith("sparse_attention")] == [
+    assert [p for p in paths if p.startswith("sparse_attention_bwd")] == [
         "sparse_attention_bwd:fused", "sparse_attention_bwd:split"]
     return dict(fused[0], x=fused[1]), dict(pair[0], x=pair[1])
 

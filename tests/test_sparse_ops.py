@@ -164,7 +164,148 @@ def test_selected_attention_matches_its_reference(hq, hkv, block, bwd_path):
     with A.record_attention_paths() as paths:
         _close_grads(lambda *a: jnp.sum(kernel(*a)[0] * tilt),
                      lambda *a: jnp.sum(plain(*a)[0] * tilt), (q, k, v), 5e-6)
-    assert paths == [f"sparse_attention_bwd:{bwd_path}"]
+    tile = block or S
+    assert paths == [f"sparse_attention_fwd:{tile}x{tile}",
+                     f"sparse_attention_bwd:{bwd_path}"]
+
+
+def _kernel_grids(fn, *args):
+    """The grid of each ``pallas_call`` at the top of ``fn``'s jaxpr, by the
+    kernel's name (traced; nothing runs)."""
+    return {e.params["name"]: e.params["grid_mapping"].grid
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"}
+
+
+# -- the forward's key tile: twice the backward's where the row allows ---------
+
+# (row, every kernel's tile by default, the forward's key tile): two and
+# three key tiles of 1 024; a row 1 024 does not divide; a row shorter than
+# 1 024; then the rule alone: a row of one key tile of 1 024, both MoE
+# cells' 8 192, rows of 512 and 384 as before, a row nothing divides
+DEFAULT_TILES = [(2048, 512, 1024), (3072, 512, 1024), (1536, 512, 512),
+                 (768, 256, 256), (1024, 512, 1024), (8192, 512, 1024),
+                 (512, 512, 512), (384, 128, 128), (40, 40, 40)]
+
+
+@pytest.mark.parametrize("seq,tile,fwd_k", DEFAULT_TILES)
+def test_the_forwards_key_tile_is_the_rows_largest_up_to_1024(seq, tile,
+                                                              fwd_k):
+    """One rule from the row's length: the backward's tiles are what every
+    kernel's were, the forward's key tile the largest of 1 024, 512, 256, 128
+    that divides the row. Tiles a caller names are every kernel's."""
+    assert sa._tiles(seq) == ((tile, fwd_k), (tile, tile))
+    assert sa._tiles(seq, 128, 256) == ((128, 256), (128, 256))
+    assert sa._tiles(seq, block_k=128) == ((tile, 128), (tile, 128))
+    assert sa._tiles(seq, block_q=128) == ((128, fwd_k), (128, tile))
+    # every other kernel's default is what it was
+    assert sa._block(8192) == 512 and sa._block(8192, 128) == 128
+    assert sa._index_blocks(8192, 256)[:2] == (256, 512)
+
+
+@pytest.mark.parametrize("seq", [1024, 8192])
+def test_by_default_the_kernels_grids_are_512_x_1024_forward_and_512_x_512_back(
+        seq):
+    """Traced at the cells' widths (nothing runs): ``sparse_attn_fwd`` on
+    ``seq / 512 x seq / 1024`` visits a KV head, ``sparse_attn_bwd_dqkv`` on
+    ``seq / 512`` squared, under their names and path entries."""
+    sd = jax.ShapeDtypeStruct
+    q, kv = sd((1, 32, seq, 128), jnp.bfloat16), sd((1, 4, seq, 128),
+                                                    jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v, mask: jnp.sum(sa.selected_attention(
+        q, k, v, mask)[0].astype(jnp.float32)), argnums=(0, 1, 2))
+    with A.record_attention_paths() as paths:
+        grids = _kernel_grids(grad, q, kv, kv, sd((1, seq, seq), jnp.int8))
+    assert paths == ["sparse_attention_fwd:512x1024",
+                     "sparse_attention_bwd:fused"]
+    assert grids == {"sparse_attn_fwd": (4, seq // 512, seq // 1024),
+                     "sparse_attn_bwd_dqkv": (4, seq // 512, seq // 512)}
+
+
+@pytest.mark.parametrize("seq,tile,fwd_k", DEFAULT_TILES[:4])
+def test_the_forward_at_its_own_key_tile_is_the_reference_and_the_equal_tiles(
+        seq, tile, fwd_k):
+    """By default the forward walks ``_tiles``' keys a visit and logs
+    its tile; ``out`` and ``lse`` are the reference's, and the forward's at
+    the backward's tiles, to float32's rounding (the tile changes the order
+    of the online softmax's rescaling and nothing else)."""
+    q, k, v, mask = _attention_inputs(seq, 2, 1, seq)
+    q, k, v, mask = q[:1], k[:1], v[:1], mask[:1]
+    with A.record_attention_paths() as paths:
+        out, lse = sa.selected_attention(q, k, v, mask)
+    assert paths == [f"sparse_attention_fwd:{tile}x{fwd_k}"]
+    want, want_lse = sa.selected_attention_reference(q, k, v, mask)
+    equal, equal_lse = sa._forward(q, k, v, mask, 1.0 / np.sqrt(q.shape[-1]),
+                                   tile, tile, True)
+    for got, ref in ((out, want), (lse, want_lse), (out, equal),
+                     (lse, equal_lse)):
+        np.testing.assert_allclose(got, ref, atol=2e-6)
+
+
+# (row, the query tile, the forward's key tile, the backward's, Hq, Hkv)
+WIDE_FORWARDS = [
+    (2048, 512, 1024, 512, 4, 2),     # both MoE cells' tiles, four query tiles
+    (64, 16, 32, 16, 4, 2), (96, 16, 32, 16, 4, 2),
+    (64, 16, 64, 16, 4, 2),           # a key tile four times the backward's
+    # every other query tile's last key tile is half above the diagonal
+    (512, 128, 256, 128, 8, 1), (512, 128, 256, 128, 4, 2),
+    (1024, 256, 512, 256, 8, 1), (1024, 256, 512, 256, 4, 2)]
+
+
+# the dq and dkv pair on the cells' tiles, on toy tiles and on a group of 8
+@pytest.mark.parametrize("seq,block_q,fwd_k,bwd_k,hq,hkv,bwd_path", [
+    case + ("fused",) for case in WIDE_FORWARDS] + [
+    WIDE_FORWARDS[i] + ("split",) for i in (0, 1, 4)])
+def test_a_forward_at_twice_the_backwards_key_tile_moves_no_gradient(
+        seq, block_q, fwd_k, bwd_k, hq, hkv, bwd_path, monkeypatch):
+    """``_selected`` with the forward at ``block_q x fwd_k`` and the backward
+    at ``block_q x bwd_k`` (``_visible`` and ``_last_tile`` on a rectangle):
+    ``out`` and ``lse`` are the reference's. The backward's tile did not
+    move: dQ, dK, dV through the ``custom_vjp`` are, to the bit, the backward
+    kernels' at ``block_q x bwd_k`` on the wide forward's ``out`` and ``lse``
+    (these differ from the equal tiles' in float32's last place, another
+    order of the online softmax's sums, and the gradients with them), and
+    the reference's."""
+    if bwd_path == "split":
+        monkeypatch.setattr(sa, "_FUSED_DKV_VMEM_BUDGET", 0)
+    q, k, v, mask = _attention_inputs(seq + fwd_k, hq, hkv, seq)
+    if seq > 64:
+        q, k, v, mask = q[:1], k[:1], v[:1], mask[:1]
+    tilt = jnp.asarray(np.random.default_rng(1).normal(size=q.shape),
+                       jnp.float32)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    wide = lambda *a: sa._selected(*a, mask, scale, (block_q, fwd_k),
+                                   (block_q, bwd_k), True)
+    plain = lambda *a: sa.selected_attention_reference(*a, mask)
+    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a)[0] * tilt),
+                                argnums=(0, 1, 2))(q, k, v)
+    (out, lse), (want, want_lse) = wide(q, k, v), plain(q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-6)
+    with A.record_attention_paths() as paths:
+        got = grads(wide)
+        same = sa._backward(q, k, v, mask, out, lse, tilt, scale, block_q,
+                            bwd_k, True)
+    assert paths == [f"sparse_attention_fwd:{block_q}x{fwd_k}",
+                     f"sparse_attention_bwd:{bwd_path}",
+                     f"sparse_attention_bwd:{bwd_path}"]
+    for a, b, ref in zip(got, same, grads(plain)):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(a, ref, atol=5e-6)
+
+
+def test_an_explicit_tile_is_every_kernels():
+    """``block_q=`` / ``block_k=`` mean what they meant: the forward takes
+    them too, on a row whose default would be 512 x 1 024."""
+    q, k, v, mask = _attention_inputs(7, 2, 1, 2048)
+    grad = jax.grad(lambda q: jnp.sum(sa.selected_attention(
+        q, k[:1], v[:1], mask[:1], block_q=256, block_k=512)[0]))
+    with A.record_attention_paths() as paths:
+        grids = _kernel_grids(grad, q[:1])
+    assert paths == ["sparse_attention_fwd:256x512",
+                     "sparse_attention_bwd:fused"]
+    assert grids == {"sparse_attn_fwd": (1, 8, 4),
+                     "sparse_attn_bwd_dqkv": (1, 8, 4)}
 
 
 # -- the selected-key attention's backward: one kernel, or the pair ------------
@@ -252,7 +393,8 @@ def test_the_backward_is_one_kernel_where_the_row_fits_and_the_pair_past_it(
         q, k, v, mask)[0]), argnums=(0, 1, 2))
     with A.record_attention_paths() as paths:
         kernels = _kernels_of(grad, q, k, v)
-    assert paths == [f"sparse_attention_bwd:{bwd_path}"]
+    assert paths == [f"sparse_attention_fwd:{S}x{S}",
+                     f"sparse_attention_bwd:{bwd_path}"]
     assert kernels == dict(
         fused=["sparse_attn_bwd_dqkv", "sparse_attn_fwd"],
         split=["sparse_attn_bwd_dkv", "sparse_attn_bwd_dq",

@@ -12,8 +12,10 @@ One file, and the topology is described inside a fixture: only one process
 may load the TPU library, and pytest-xdist workers each import every file.
 """
 
+import base64
 import os
 import re
+import struct
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -273,7 +275,10 @@ def sparse_texts(one_chip):
             sd((1, 32, long, 128), jnp.bfloat16),
             sd((1, 4, long, 128), jnp.bfloat16),
             sd((1, 4, long, 128), jnp.bfloat16), sd((1, long, long), jnp.int8))
-    assert paths == ["sparse_attention_bwd:fused", "sparse_attention_bwd:split"]
+    assert paths == ["sparse_attention_fwd:512x1024",
+                     "sparse_attention_bwd:fused",
+                     "sparse_attention_fwd:512x1024",
+                     "sparse_attention_bwd:split"]
     rows = G.rows_bound(s, 8, 16)
     x = sd((rows, 2048), jnp.bfloat16)
     w = sd((16, 2048, 768), jnp.bfloat16)
@@ -374,7 +379,10 @@ def block_texts(one_chip):
         cell, long = (_compile(attend, sd((1, 32, s, 128)),
                                sd((1, 4, s, 128)), sd((1, 4, s, 128)))
                       for s in (8192, 32768))
-    assert paths == ["block_attention_bwd:fused", "block_attention_bwd:split"]
+    assert paths == ["block_attention_fwd:512x1024",
+                     "block_attention_bwd:fused",
+                     "block_attention_fwd:512x1024",
+                     "block_attention_bwd:split"]
     return {"block_attn_fwd": cell, "block_attn_bwd_dqkv": cell,
             "block_attn_bwd_dq_": long, "block_attn_bwd_dkv": long}
 
@@ -403,6 +411,72 @@ def test_the_cells_side_of_the_fused_backwards_budget_holds_no_pair(
     assert any(f"{family}_attn_bwd_dqkv" in c for c in calls), calls
     assert not any(f"{family}_attn_bwd_dq_" in c
                    or f"{family}_attn_bwd_dkv" in c for c in calls), calls
+
+
+def _kernel_windows(text, name):
+    """Does the Mosaic module of the custom call ``name`` in a compiled text
+    hold the int64 array ``shape`` (a block's ``window_bounds``, the grid's
+    ``iteration_bounds``)? The module travels as base64 of MLIR bytecode,
+    which keeps such an array as its little-endian bytes."""
+    line = next(l for l in text.splitlines()
+                if any(name in c for c in _custom_calls(l)))
+    body = base64.b64decode(re.search(r'"body":"([^"]+)"', line).group(1))
+    return lambda *shape: struct.pack(f"<{len(shape)}q", *shape) in body
+
+
+@pytest.mark.parametrize("family", ["sparse", "block"])
+def test_the_forwards_walk_1024_keys_a_visit_at_the_cells_widths(
+        sparse_texts, block_texts, family):
+    """With the default tiles both MoE cells' forward kernels compile for
+    the chip with a key block of ``[1024, 128]`` under a query block of the
+    group's eight ``[512, 128]`` (keye's grid 16 x 8 key tiles a KV head,
+    sdar's the 48 visits of its schedule; the selection's tile int8 ``[512,
+    1024]``), while the one backward kernel keeps 512 keys."""
+    text = dict(sparse=sparse_texts, block=block_texts)[family][
+        f"{family}_attn_fwd"]
+    fwd = _kernel_windows(text, f"{family}_attn_fwd")
+    bwd = _kernel_windows(text, f"{family}_attn_bwd_dqkv")
+    assert fwd(1, 8, 512, 128) and fwd(1, 1024, 128) and not fwd(1, 512, 128)
+    assert bwd(1, 8, 512, 128) and bwd(1, 512, 128) and not bwd(1, 1024, 128)
+    if family == "sparse":
+        assert fwd(4, 16, 8) and fwd(1, 512, 1024)
+        assert bwd(4, 16, 16) and bwd(1, 512, 512)
+    else:
+        assert fwd(4, 48) and bwd(4, 80)
+
+
+@pytest.mark.parametrize("family", ["sparse", "block"])
+def test_the_forwards_at_512_x_1024_stand_well_inside_the_vmem_limit(
+        one_chip, family, monkeypatch):
+    """The v5e's compiler takes ``sparse_attn_fwd`` at the cells' shapes and
+    512 x 1 024 with 36.1 MB of VMEM and ``block_attn_fwd`` with 35.3 (the
+    least limits it accepts; ``ops/sparse_attention.py`` has them beside
+    ``_VMEM_LIMIT``, 96 MiB): both lower under 40 MiB, under their names, and
+    neither under 32."""
+    from sparkflow_tpu.ops import block_attention as B
+    from sparkflow_tpu.ops import sparse_attention as S
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                             sharding=one_chip)
+    s = 8192
+    args = [sd(1, 32, s, 128), sd(1, 4, s, 128), sd(1, 4, s, 128)]
+    if family == "sparse":
+        args.append(jax.ShapeDtypeStruct((1, s, s), jnp.int8,
+                                         sharding=one_chip))
+        fn = lambda q, k, v, m: S.selected_attention(q, k, v, m,
+                                                     interpret=False)
+    else:
+        fn = lambda q, k, v: B.block_attention(q, k, v, s // 2, 4,
+                                               interpret=False)
+    assert S._VMEM_LIMIT == 96 * 1024 * 1024
+    monkeypatch.setattr(S, "_VMEM_LIMIT", 40 * 1024 * 1024)
+    with A.record_attention_paths() as paths:
+        calls = _custom_calls(_compile(fn, *args))
+    assert paths == [f"{family}_attention_fwd:512x1024"]
+    assert len(calls) == 1 and f"{family}_attn_fwd" in calls[0], calls
+    monkeypatch.setattr(S, "_VMEM_LIMIT", 32 * 1024 * 1024)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(lambda *a: fn(*a), *args)
 
 
 def test_another_block_length_and_head_layout_lower_on_tpu(one_chip):
